@@ -16,6 +16,130 @@ import time
 
 import numpy as np
 
+#: the r5 bf16 save list every remat policy in the grid starts from
+BASE_SAVES = "attn_res,attn_lse,attn_q,attn_k,attn_v,rms_rstd"
+#: the r5 default remat policy (both ffn saves in bf16)
+DEFAULT_POLICY = f"names:{BASE_SAVES},resid_mid,ffn_gate,ffn_up"
+
+
+def apply_tpu_defaults():
+    """The tuned TPU settings of the dense training lines, as
+    ``os.environ.setdefault`` (an explicit env still wins). The ONE
+    place they live: bench.py and chip_smoke.py both call this.
+
+    - Pallas rms kernel with saved rstd residual (+3.1% MFU, r3)
+    - flash fwd block 2048 (+0.6%, r4; bwd stays 1024 — uniform 2048
+      bwd overflows scoped VMEM, decoupled q/k blocks measured worse)
+    - r5: factored second-moment AdamW frees the m2 state (~2.6GB at
+      1.3B); the headroom buys BOTH ffn saves at batch 3 — the backward
+      re-runs no FFN matmuls at all (GPT 0.5468 -> 0.5629, LLaMA
+      0.5806 -> 0.638, docs/ROUND5_RESPONSE.md)
+    - r6+: norm->ffn seam megakernel — (silu(gate)*up) @ wd streamed
+      through VMEM, the [tokens, intermediate] product never touches
+      HBM (ops/pallas/swiglu_down, docs/SCAN.md). PTPU_FUSED_FFN=0
+      restores the unfused seam; PTPU_FUSED_SEAMS=1 additionally
+      engages the addrms attn->norm seam.
+
+    The int8 weight-only LM head is not set here: the chunked-CE head
+    turns it on by default WHEN the numeric parity gate passes
+    (fused_cross_entropy.int8_head_enabled; PTPU_INT8_HEAD forces)."""
+    os.environ.setdefault("PTPU_PALLAS_RMS", "1")
+    os.environ.setdefault("PTPU_FA_BLOCK", "2048")
+    os.environ.setdefault("PTPU_ADAM_FACTORED", "1")
+    os.environ.setdefault("PTPU_FUSED_FFN", "1")
+
+
+def quant_policy(policy, q):
+    """``policy`` carrying the scaled-GEMM request ``q`` (docs/QUANT.md):
+    the request rides the names: policy (models/gpt.py _resolve_remat
+    strips + resolves it); other policies can't carry quant entries."""
+    return (f"{policy},quant:{q}"
+            if q and str(policy).startswith("names:") else policy)
+
+
+def tpu_model_config(model_kind):
+    """The two tracked TPU training configurations (bf16, seq 2048)."""
+    from paddle_tpu.models.gpt import GPTConfig
+
+    if model_kind == "llama":
+        # BASELINE.md config-5 variant: LLaMA-7B architecture (h=4096,
+        # GQA, swiglu, rope) depth-scaled to 8 layers so params+Adam
+        # state fit one v5e chip. This line runs REAL sharding_stage=3
+        # (group_sharded_parallel + the ZeRO execution mode,
+        # docs/ZERO.md) over every addressable chip — degree = device
+        # count.
+        return GPTConfig(vocab_size=32000, hidden_size=4096,
+                         num_layers=8, num_heads=32, num_kv_heads=8,
+                         intermediate_size=11008, max_seq_len=2048,
+                         dropout=0.0, dtype="bfloat16", recompute=True)
+    # GPT-3 1.3B (BASELINE.md config 4) — the headline metric
+    return GPTConfig(vocab_size=32000, hidden_size=2048,
+                     num_layers=24, num_heads=16, max_seq_len=2048,
+                     dropout=0.0, dtype="bfloat16", recompute=True)
+
+
+def build_model(cfg, bf16):
+    """Stacked-decoder flagship: lax.scan over layers keeps compile time
+    constant in depth; recompute = jax.checkpoint per block. ``bf16``:
+    AMP O2 build + bf16 parameters (the TPU lines)."""
+    import jax
+
+    import paddle_tpu as paddle
+    from paddle_tpu.models.gpt import GPTForCausalLMPipe
+
+    with paddle.amp.auto_cast(enable=bf16, dtype="bfloat16", level="O2"):
+        model = GPTForCausalLMPipe(cfg)
+    if bf16:
+        for _, p in model.named_parameters():
+            p._data = p._data.astype(jax.numpy.bfloat16)
+    return model
+
+
+def build_optimizer(model, sharded_update=False):
+    """AdamW of the training lines.
+
+    PTPU_ADAM8=1: blockwise-int8 moments (8-bit Adam) — frees ~4GB of
+    optimizer HBM at 1.3B, buying remat headroom (r4; measured LOSING
+    on this chip, defaults off — docs/ROUND4_RESPONSE.md)
+    PTPU_ADAM_FACTORED=1: Adafactor-style factored second moment —
+    frees ~2.6GB (m2) with fp32 math, no quant round-trips (r5)
+    The multi-chip stage-3 line (``sharded_update``) uses PLAIN fp32
+    moments instead: factored/int8 moments compute cross-element
+    statistics that can't run on a 1/degree shard (the zero plan would
+    decline), and full moments divided by the shard degree beat
+    factored's ~half saving from degree 2 up (docs/ZERO.md)."""
+    import paddle_tpu as paddle
+
+    return paddle.optimizer.AdamW(
+        learning_rate=3e-4, parameters=model.parameters(),
+        moment_dtype=(None if sharded_update else
+                      ("int8" if os.environ.get("PTPU_ADAM8", "")
+                       not in ("", "0") else None)),
+        factored=(not sharded_update
+                  and os.environ.get("PTPU_ADAM_FACTORED", "")
+                  not in ("", "0")))
+
+
+def zero3_mesh():
+    """fleet mesh for the LLaMA-arch line: sharding_stage=3 END TO END
+    (docs/ZERO.md) — params resident as dp shards, grads
+    reduce-scattered, the update on 1/degree slots, scan-body
+    just-in-time weight gathers — over every addressable chip. One
+    chip is the degree-1 degenerate of the SAME code path (the zero
+    plan disengages, GSPMD placements are no-ops), not a separate
+    single-chip approximation. Returns (mesh, degree)."""
+    import jax
+
+    from paddle_tpu.distributed import fleet as _fleet
+
+    degree = len(jax.devices())
+    strategy = _fleet.DistributedStrategy()
+    strategy.hybrid_configs = {"dp_degree": 1, "mp_degree": 1,
+                               "pp_degree": 1,
+                               "sharding_degree": degree}
+    _fleet.init(is_collective=True, strategy=strategy)
+    return _fleet.get_fleet_mesh(), degree
+
 
 def _serving_smoke_block():
     """Compact fleet-serving soak for the bench JSON (--serve): replica
@@ -64,12 +188,14 @@ def run_long_context(ckpt=None):
 
     import jax
 
-    on_tpu = jax.default_backend() not in ("cpu",)
     import paddle_tpu as paddle
     import paddle_tpu.telemetry as telemetry
+    from paddle_tpu.device import (chip_peaks, device_record,
+                                   require_accelerator)
     from paddle_tpu.jit import TrainStep
     from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLMPipe
 
+    on_tpu = require_accelerator("bench.py --long-context")
     telemetry.enable()
     telemetry.reset()
     n_dev = len(jax.devices())
@@ -153,12 +279,13 @@ def run_long_context(ckpt=None):
 
     n_params = sum(int(np.prod(p.shape))
                    for _, p in model.named_parameters())
-    peak = 197e12 if on_tpu else 1e12
+    peak = chip_peaks()[0]["bf16_flops"]  # CPU: flagged placeholder
     mfu = 6.0 * n_params * tokens_per_sec / peak
     print(json.dumps({
         "metric": "gpt_long_context_tokens_per_sec_seq32k",
         "value": round(tokens_per_sec, 1),
         "unit": "tokens/sec/chip",
+        "device": device_record(),
         "seq": seq,
         "note": (None if on_tpu and seq >= 32768 else
                  f"reduced-length smoke (seq {seq}, {jax.default_backend()}"
@@ -175,16 +302,17 @@ def run_long_context(ckpt=None):
 def run_model(model_kind, ckpt=None):
     import jax
 
-    backend = jax.default_backend()
-    on_tpu = backend not in ("cpu",)
-
     import paddle_tpu as paddle
     import paddle_tpu.telemetry as telemetry
+    from paddle_tpu.device import (chip_peaks, device_record,
+                                   require_accelerator)
     from paddle_tpu.telemetry import trace as ptrace
     from paddle_tpu.jit import TrainStep
-    from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLMPipe
+    from paddle_tpu.models.gpt import GPTConfig
     import paddle_tpu.nn.functional as F
     from paddle_tpu import quant as _pquant
+
+    on_tpu = require_accelerator("bench.py")
 
     # full-run telemetry: op dispatch, collectives, compile events, and
     # step timing all land in the snapshot attached to the bench JSON, so
@@ -222,47 +350,11 @@ def run_model(model_kind, ckpt=None):
         ts_recorder.start(record_interval)
 
     if on_tpu:
-        # Tuned defaults (measured on v5e; r3 sweep + r4 sweep):
-        # - Pallas rms kernel with saved rstd residual (+3.1% MFU, r3)
-        # - int8 weight-only LM head: no longer force-set here — the
-        #   chunked-CE head turns it on by default WHEN the numeric
-        #   parity gate passes (fused_cross_entropy.int8_head_enabled;
-        #   PTPU_INT8_HEAD still forces either way)
-        # - flash fwd block 2048 (+0.6%, r4; bwd stays 1024 — uniform
-        #   2048 bwd compile-OOMs, decoupled q/k blocks measured worse)
-        os.environ.setdefault("PTPU_PALLAS_RMS", "1")
-        os.environ.setdefault("PTPU_FA_BLOCK", "2048")
-        # r5: factored second-moment AdamW frees the m2 state (~2.6GB at
-        # 1.3B); the headroom buys BOTH ffn saves at batch 3 — the
-        # backward re-runs no FFN matmuls at all. Measured (tools/r5
-        # sweeps): GPT 0.5468 -> 0.5629, LLaMA 0.5806 -> 0.638.
-        # bwd-block-2048 stays dead (scoped-VMEM OOM, not HBM).
-        os.environ.setdefault("PTPU_ADAM_FACTORED", "1")
-        # r6+: norm->ffn seam megakernel — (silu(gate)*up) @ wd streamed
-        # through VMEM, the [tokens, intermediate] product never touches
-        # HBM (ops/pallas/swiglu_down, docs/SCAN.md). PTPU_FUSED_FFN=0
-        # restores the unfused seam; PTPU_FUSED_SEAMS=1 additionally
-        # engages the addrms attn->norm seam.
-        os.environ.setdefault("PTPU_FUSED_FFN", "1")
-        if model_kind == "llama":
-            # BASELINE.md config-5 variant: LLaMA-7B architecture
-            # (h=4096, GQA, swiglu, rope) depth-scaled to 8 layers so
-            # params+Adam state fit one v5e chip. This line runs REAL
-            # sharding_stage=3 (group_sharded_parallel + the ZeRO
-            # execution mode below, docs/ZERO.md) over every
-            # addressable chip — degree = device count.
-            cfg = GPTConfig(vocab_size=32000, hidden_size=4096,
-                            num_layers=8, num_heads=32, num_kv_heads=8,
-                            intermediate_size=11008, max_seq_len=2048,
-                            dropout=0.0, dtype="bfloat16", recompute=True)
-        else:
-            # GPT-3 1.3B (BASELINE.md config 4) — the headline metric
-            cfg = GPTConfig(vocab_size=32000, hidden_size=2048,
-                            num_layers=24, num_heads=16, max_seq_len=2048,
-                            dropout=0.0, dtype="bfloat16", recompute=True)
+        apply_tpu_defaults()
+        cfg = tpu_model_config(model_kind)
         seq, steps = 2048, 10
         batch_grid = (3, 4, 5)
-    else:  # smoke path for CPU dev runs
+    else:  # smoke path for CPU runs the caller asked for (JAX_PLATFORMS)
         cfg = GPTConfig(vocab_size=512, hidden_size=128, num_layers=2,
                         num_heads=4, max_seq_len=256, dropout=0.0)
         seq, steps = 128, 3
@@ -277,18 +369,17 @@ def run_model(model_kind, ckpt=None):
     # cached per (config, chip); PTPU_BENCH_BATCH / PTPU_BENCH_REMAT
     # remain as overrides for perf sweeps (both set = planning skipped,
     # the override is still priced + recorded in the JSON).
-    base_saves = "attn_res,attn_lse,attn_q,attn_k,attn_v,rms_rstd"
     if on_tpu:
         policy_grid = (
-            f"names:{base_saves},resid_mid,ffn_gate,ffn_up",      # r5 default
-            f"names:{base_saves},resid_mid,int8:ffn_gate,int8:ffn_up",
-            f"names:{base_saves},int8:resid_mid,int8:ffn_gate,int8:ffn_up",
+            DEFAULT_POLICY,
+            f"names:{BASE_SAVES},resid_mid,int8:ffn_gate,int8:ffn_up",
+            f"names:{BASE_SAVES},int8:resid_mid,int8:ffn_gate,int8:ffn_up",
         )
     else:
         # CPU smoke pins the all-int8 policy so one tier-1 bench run
         # exercises planner + quantized save/restore end to end
         policy_grid = (
-            f"names:{base_saves},int8:resid_mid,int8:ffn_gate,int8:ffn_up",
+            f"names:{BASE_SAVES},int8:resid_mid,int8:ffn_gate,int8:ffn_up",
         )
     env_batch = os.environ.get("PTPU_BENCH_BATCH")
     env_remat = os.environ.get("PTPU_BENCH_REMAT")
@@ -311,53 +402,17 @@ def run_model(model_kind, ckpt=None):
     else:
         hchunk_grid = (256,)  # CPU smoke: multiple chunks over vocab 512
 
-    # stacked-decoder flagship: lax.scan over layers keeps compile time
-    # constant in depth; recompute = jax.checkpoint per block
-    with paddle.amp.auto_cast(enable=on_tpu, dtype="bfloat16", level="O2"):
-        model = GPTForCausalLMPipe(cfg)
-    if on_tpu:
-        for _, p in model.named_parameters():
-            p._data = p._data.astype(jax.numpy.bfloat16)
+    model = build_model(cfg, bf16=on_tpu)
 
     # config-5 (BASELINE.md): the LLaMA-arch line runs sharding_stage=3
-    # END TO END (docs/ZERO.md) — params resident as dp shards, grads
-    # reduce-scattered, the update on 1/degree slots, scan-body
-    # just-in-time weight gathers — over every addressable chip. One
-    # chip is the degree-1 degenerate of the SAME code path (the zero
-    # plan disengages, GSPMD placements are no-ops), not a separate
-    # single-chip approximation.
+    # over every addressable chip (zero3_mesh)
     zero_stage, zero_degree, zero_mesh = 0, 1, None
     if model_kind == "llama":
-        from paddle_tpu.distributed import fleet as _fleet
-
         zero_stage = 3
-        zero_degree = len(jax.devices())
-        strategy = _fleet.DistributedStrategy()
-        strategy.hybrid_configs = {"dp_degree": 1, "mp_degree": 1,
-                                   "pp_degree": 1,
-                                   "sharding_degree": zero_degree}
-        _fleet.init(is_collective=True, strategy=strategy)
-        zero_mesh = _fleet.get_fleet_mesh()
+        zero_mesh, zero_degree = zero3_mesh()
 
-    # PTPU_ADAM8=1: blockwise-int8 moments (8-bit Adam) — frees ~4GB of
-    # optimizer HBM at 1.3B, buying remat headroom (r4; measured LOSING
-    # on this chip, defaults off — docs/ROUND4_RESPONSE.md)
-    # PTPU_ADAM_FACTORED=1: Adafactor-style factored second moment —
-    # frees ~2.6GB (m2) with fp32 math, no quant round-trips (r5)
-    # The multi-chip stage-3 line uses PLAIN fp32 moments instead:
-    # factored/int8 moments compute cross-element statistics that can't
-    # run on a 1/degree shard (the zero plan would decline), and full
-    # moments divided by the shard degree beat factored's ~half saving
-    # from degree 2 up (docs/ZERO.md).
-    sharded_update = zero_stage >= 2 and zero_degree > 1
-    opt = paddle.optimizer.AdamW(
-        learning_rate=3e-4, parameters=model.parameters(),
-        moment_dtype=(None if sharded_update else
-                      ("int8" if os.environ.get("PTPU_ADAM8", "")
-                       not in ("", "0") else None)),
-        factored=(not sharded_update
-                  and os.environ.get("PTPU_ADAM_FACTORED", "")
-                  not in ("", "0")))
+    opt = build_optimizer(
+        model, sharded_update=zero_stage >= 2 and zero_degree > 1)
     if zero_stage:
         from paddle_tpu.distributed import group_sharded_parallel
 
@@ -387,13 +442,6 @@ def run_model(model_kind, ckpt=None):
     env_bquant = os.environ.get("PTPU_BENCH_QUANT", "").strip().lower()
     quant_grid = (None,) if env_bquant in ("0", "off") else ("all",)
 
-    def _quant_policy(policy, q):
-        # the request rides the names: policy (models/gpt.py
-        # _resolve_remat strips + resolves it); other policies can't
-        # carry quant entries
-        return (f"{policy},quant:{q}"
-                if q and str(policy).startswith("names:") else policy)
-
     if env_batch and env_remat:
         # reproduce path: only pin the head chunk when the sweep pinned it
         # too — otherwise keep the kernel default the recorded round used.
@@ -414,7 +462,7 @@ def run_model(model_kind, ckpt=None):
         require_fit = True
 
     def step_factory(cand):
-        pol = _quant_policy(cand.policy, getattr(cand, "quant", None))
+        pol = quant_policy(cand.policy, getattr(cand, "quant", None))
         cfg.recompute = pol != "none"
         cfg.recompute_policy = pol
         cfg.head_chunk = cand.head_chunk
@@ -531,7 +579,7 @@ def run_model(model_kind, ckpt=None):
         model, opt = step.model, step.optimizer
         batch = decision.batch
         cfg.recompute = decision.policy != "none"
-        cfg.recompute_policy = _quant_policy(
+        cfg.recompute_policy = quant_policy(
             decision.policy, getattr(decision, "quant", None))
         cfg.head_chunk = decision.head_chunk
     else:
@@ -544,7 +592,7 @@ def run_model(model_kind, ckpt=None):
             # to the same effective value share one lowering (the
             # planner memoizes on this key, docs/MEMORY.md)
             return (c.batch,
-                    _quant_policy(c.policy, getattr(c, "quant", None)),
+                    quant_policy(c.policy, getattr(c, "quant", None)),
                     resolve_vocab_chunk(cfg.vocab_size, c.head_chunk),
                     getattr(c, "depth", None))
 
@@ -558,16 +606,17 @@ def run_model(model_kind, ckpt=None):
             cache_extra=cache_extra)
         batch = decision.batch
         cfg.recompute = decision.policy != "none"
-        cfg.recompute_policy = _quant_policy(decision.policy,
+        cfg.recompute_policy = quant_policy(decision.policy,
                                              getattr(decision, "quant",
                                                      None))
         cfg.head_chunk = decision.head_chunk
 
         # NOTE: on a plan-cache miss the winning program compiles twice
         # (once AOT in the planner, once here at warmup — jit's dispatch
-        # cache is not fed by the AOT path). The disk cache makes every
-        # later run of the same config skip planning entirely, so the
-        # cost is first-run-per-config only.
+        # cache is not fed by the AOT path); JAX's persistent compile
+        # cache (device.compile_cache_dir) turns the second into a
+        # disk hit. The plan cache makes every later run of the same
+        # config skip planning entirely.
         step = make_step()
 
     # Crash-safe checkpointing (--ckpt-dir): per-step committed saves via
@@ -886,12 +935,10 @@ def run_model(model_kind, ckpt=None):
     # MFU: 6 * params * tokens/sec / peak_flops
     n_params = sum(int(np.prod(p.shape)) for _, p in model.named_parameters())
     model_flops = 6.0 * n_params * tokens_per_sec
-    kind = jax.devices()[0].device_kind.lower()
-    peak = (459e12 if "v5p" in kind or "v5" == kind else
-            197e12 if "v5e" in kind or "v5 lite" in kind else
-            275e12 if "v4" in kind else
-            918e12 if "v6" in kind or "trillium" in kind else
-            197e12) if on_tpu else 1e12  # bf16 peak per chip
+    # bf16 peak per chip from the one chip table (an unknown TPU kind
+    # raises; the CPU row is a flagged placeholder and its "mfu" rides
+    # under the CPU smoke's own metric name)
+    peak = chip_peaks()[0]["bf16_flops"]
     mfu = model_flops / peak
 
     if on_tpu:
@@ -916,10 +963,11 @@ def run_model(model_kind, ckpt=None):
         "metric": metric,
         "value": round(tokens_per_sec, 1),
         "unit": "tokens/sec/chip",
+        "device": device_record(),
         "vs_baseline": round(mfu, 4),
         # explicit MFU field (same value as vs_baseline, which predates
         # it): model FLOPs 6*params*tokens/sec over the chip's bf16 peak
-        # from the small chip table above — the driver-tracked headline
+        # from paddle_tpu.device.CHIP_PEAKS — the driver-tracked headline
         "mfu": round(mfu, 4),
         # planner decision + XLA memory_analysis peak: a BENCH_r*.json
         # regression explains its memory state the same way the
@@ -978,8 +1026,6 @@ def main():
     import argparse
     import gc
     import logging
-
-    import jax
 
     ap = argparse.ArgumentParser(
         description="paddle_tpu headline pretrain benchmark")
@@ -1044,7 +1090,10 @@ def main():
     logging.basicConfig()
     logging.getLogger("paddle_tpu.pallas").setLevel(logging.INFO)
 
-    on_tpu = jax.default_backend() not in ("cpu",)
+    from paddle_tpu.device import compile_cache_dir, require_accelerator
+
+    on_tpu = require_accelerator("bench.py")
+    compile_cache_dir()
     kind = os.environ.get("PTPU_BENCH_MODEL")
     if kind is not None or not on_tpu:
         if args.long_context:

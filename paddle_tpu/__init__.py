@@ -9,10 +9,6 @@ from __future__ import annotations
 
 import jax as _jax
 
-from . import _jax_compat as _jax_compat_module
-
-_jax_compat_module.install()
-
 # float64 capability parity with the reference (x64 must be on before tracing)
 _jax.config.update("jax_enable_x64", True)
 # keep python-float default at float32 (paddle semantics) via weak types.

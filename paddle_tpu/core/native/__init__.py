@@ -13,6 +13,7 @@ API working.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import tempfile
@@ -35,14 +36,30 @@ def _build(lib_path):
     subprocess.run(cmd, check=True, capture_output=True, timeout=240)
 
 
+def _sources_digest(srcs):
+    """sha256 over the source files' names and bytes: the binary's key.
+    mtimes do not survive a copy of the tree (git ignores the binary but
+    a copied checkout carries it), so a stale library could otherwise
+    load against newer sources."""
+    h = hashlib.sha256()
+    for path in srcs:
+        h.update(os.path.basename(path).encode() + b"\0")
+        with open(path, "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()[:16]
+
+
 def _load():
     global LIB
-    lib_path = os.path.join(_DIR, _LIB_NAME)
     srcs = _sources()
     if not srcs:
         return None
-    newest_src = max(os.path.getmtime(s) for s in srcs)
-    if not os.path.exists(lib_path) or os.path.getmtime(lib_path) < newest_src:
+    # the digest rides in the file name: a library built from other
+    # sources is simply not the file this import looks for
+    stem, ext = os.path.splitext(_LIB_NAME)
+    lib_path = os.path.join(_DIR, f"{stem}.{_sources_digest(srcs)}{ext}")
+    if not os.path.exists(lib_path):
+        tmp = None
         try:
             # build into a temp file then atomically rename, so concurrent
             # importers never load a half-written library
@@ -51,11 +68,19 @@ def _load():
             _build(tmp)
             os.replace(tmp, lib_path)
         except Exception:
-            try:
-                os.unlink(tmp)
-            except OSError:
-                pass
+            if tmp is not None:
+                try:
+                    os.unlink(tmp)
+                except OSError:
+                    pass
             return None
+        for f in os.listdir(_DIR):  # libraries of older sources
+            if (f.startswith(stem + ".") and f.endswith(ext)
+                    and os.path.join(_DIR, f) != lib_path):
+                try:
+                    os.unlink(os.path.join(_DIR, f))
+                except OSError:
+                    pass
     try:
         lib = ctypes.CDLL(lib_path)
     except OSError:

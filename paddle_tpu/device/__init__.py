@@ -79,6 +79,15 @@ _state = threading.local()
 _platform_cache = [None]
 
 
+def cpu_requested() -> bool:
+    """True when the caller asked for the CPU: ``JAX_PLATFORMS`` names it
+    FIRST (``tpu,cpu`` — the TPU machine's own setting — asks for the TPU
+    and merely keeps a CPU backend beside it). The CPU is something a
+    caller asks for — the tests and tier-1 do — never something the
+    program discovers and settles for."""
+    return os.environ.get("JAX_PLATFORMS", "").split(",")[0].strip() == "cpu"
+
+
 def _accelerator_platform():
     """The current jax platform name — WITHOUT initializing device backends.
 
@@ -90,17 +99,110 @@ def _accelerator_platform():
     if env:
         return env.split(",")[0].strip() or "cpu"
     if _platform_cache[0] is None:
-        try:
-            _platform_cache[0] = jax.default_backend()
-        except RuntimeError:  # pragma: no cover
-            _platform_cache[0] = "cpu"
+        _platform_cache[0] = jax.default_backend()
     return _platform_cache[0]
+
+
+def require_accelerator(what="this entry point"):
+    """The measurement entry points' platform check: returns ``True`` on a
+    TPU and ``False`` only when the caller asked for the CPU
+    (:func:`cpu_requested`). A machine where JAX found no TPU and nobody
+    asked for the CPU raises — no result may come from a device the
+    caller did not name."""
+    d = jax.devices()[0]
+    if d.platform == "tpu":
+        return True
+    if d.platform == "cpu" and cpu_requested():
+        return False
+    raise RuntimeError(
+        f"{what}: JAX found no TPU (platform={d.platform!r}, "
+        f"device_kind={d.device_kind!r}, count={len(jax.devices())}) and "
+        "JAX_PLATFORMS does not ask for the CPU — refusing to run on a "
+        "device nobody named. Set JAX_PLATFORMS=cpu for a CPU smoke run.")
+
+
+def device_record():
+    """``{"platform", "kind", "count"}`` as JAX reports the devices —
+    every benchmark line carries it."""
+    devs = jax.devices()
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs)}
+
+
+# -- chip peaks ---------------------------------------------------------------
+#: Published per-chip peaks, keyed by ``jax.Device.device_kind``. ONE table:
+#: MFU/roofline denominators (bench.py, jit cost summaries), the memory
+#: planner's HBM fallback and the layout autotuner's link term all read it.
+#: v5e: Google Cloud documentation, "TPU v5e" system architecture — 197
+#: TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM2e at 819 GB/s, 1,600 Gbit/s
+#: inter-chip interconnect per chip. A TPU kind missing here is an error,
+#: not a default: add its row with the source of the figures.
+CHIP_PEAKS = {
+    "TPU v5 lite": {  # what a v5e reports as device_kind
+        "bf16_flops": 197e12, "int8_ops": 393e12,
+        "hbm_bytes": 16e9, "hbm_bytes_per_sec": 819e9,
+        "ici_bytes_per_sec": 1600e9 / 8,
+    },
+}
+
+#: the CPU has no published peak: these are placeholders so CPU tests can
+#: exercise the cost-summary plumbing; every reader carries the flag and
+#: no CPU number is ever reported as a utilization
+_CPU_PLACEHOLDER = {
+    "bf16_flops": 1e12, "int8_ops": 1e12, "hbm_bytes": 16e9,
+    "hbm_bytes_per_sec": 100e9, "ici_bytes_per_sec": 10e9,
+}
+
+
+def chip_peaks(device=None):
+    """``(peaks, placeholder)`` for ``device`` (default: device 0):
+    the :data:`CHIP_PEAKS` row of its ``device_kind``. CPU devices get the
+    flagged placeholder row; any other kind missing from the table raises
+    ``KeyError`` naming it."""
+    d = device if device is not None else jax.devices()[0]
+    if d.platform == "cpu":
+        return dict(_CPU_PLACEHOLDER), True
+    try:
+        return dict(CHIP_PEAKS[d.device_kind]), False
+    except KeyError:
+        raise KeyError(
+            f"device_kind {d.device_kind!r} (platform {d.platform!r}) has "
+            "no row in paddle_tpu.device.CHIP_PEAKS — add its published "
+            "peaks with their source; an unknown chip never borrows "
+            "another chip's numbers") from None
+
+
+# -- compile cache ------------------------------------------------------------
+def compile_cache_dir():
+    """Place JAX's persistent compilation cache; returns the directory.
+
+    Called ONCE by each entry point that compiles for the chip
+    (chip_smoke.py, bench.py, tools/serve_bench.py, profile_bench.py, the
+    fleet workers) and never at ``import paddle_tpu``. When
+    ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself, so this sets
+    nothing and returns it; otherwise the cache goes to
+    ``<checkout>/.jax_cache``, resolved from this package's location —
+    the path is part of the cache key, so it must not move. A caller that
+    asked for the CPU gets no cache (returns ``None``): deserialized CPU
+    executables that hold collectives deadlock in this jaxlib
+    (tests/conftest.py)."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    if cpu_requested():
+        return None
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__)))), ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def set_device(device: str):
     """paddle.device.set_device — accepts 'tpu', 'tpu:0', 'cpu', 'gpu:0'...
 
     GPU/XPU/custom names are treated as the accelerator for compatibility.
+    Asking for the accelerator where the caller pinned JAX to the CPU
+    raises — a TPU place never quietly resolves to a CPU device.
     """
     device = str(device)
     name = device.split(":")[0]
@@ -108,6 +210,11 @@ def set_device(device: str):
     if name in ("cpu",):
         _state.place = CPUPlace(idx)
     else:
+        if _accelerator_platform() == "cpu":
+            raise RuntimeError(
+                f"set_device({device!r}): this process runs on the CPU "
+                "(JAX_PLATFORMS / backend) — there is no accelerator to "
+                "select; use set_device('cpu')")
         _state.place = TPUPlace(idx)
     return get_device()
 
@@ -130,12 +237,23 @@ def jax_device_for(place: Place | None = None):
     """Map a Place to a concrete jax.Device, or None for "default device".
 
     Returning None lets callers skip jax.device_put entirely — arrays land on
-    the default device lazily without forcing backend initialization.
+    the default device lazily without forcing backend initialization. An
+    accelerator place on a CPU-only backend, or a device id past the
+    device count, raises.
     """
     if place is None:
         return None
     devs = jax.devices("cpu") if place.is_cpu_place() else jax.devices()
-    return devs[place.get_device_id() % len(devs)]
+    if not place.is_cpu_place() and devs[0].platform == "cpu":
+        raise RuntimeError(
+            f"{place!r}: the default backend's devices are CPUs — an "
+            "accelerator place never resolves to a CPU device")
+    idx = place.get_device_id()
+    if not 0 <= idx < len(devs):
+        raise IndexError(
+            f"{place!r}: device id {idx} out of range for {len(devs)} "
+            f"{devs[0].platform} device(s)")
+    return devs[idx]
 
 
 def device_count() -> int:

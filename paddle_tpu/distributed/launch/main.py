@@ -4,9 +4,10 @@ Capability parity: `python/paddle/distributed/launch/main.py:23` +
 `controllers/collective.py` (pod/process model, env contract, restart).
 
 TPU-native process model: ONE controller process per HOST drives all local
-chips (multi-controller jax), so ``--nproc_per_node`` defaults to 1 on TPU
-— unlike the reference's process-per-GPU. Values > 1 are used by the
-CPU fake-backend test path (each process becomes one "rank").
+chips (multi-controller jax), so ``--nproc_per_node`` is 1 on TPU — unlike
+the reference's process-per-GPU — and a larger value is refused there (a
+chip belongs to one process). Values > 1 serve the CPU fake-backend path
+(``JAX_PLATFORMS=cpu``; each process becomes one "rank").
 
 Env contract written for each process (consumed by init_parallel_env):
   PADDLE_TRAINER_ID, PADDLE_TRAINERS_NUM, PADDLE_MASTER,
@@ -224,6 +225,20 @@ def launch() -> None:
     args = _parse()
     nnodes = _nnodes(args.nnodes)
     nproc = args.nproc_per_node or 1
+    if nproc > 1:
+        from ...device import cpu_requested
+
+        if not cpu_requested():
+            # each rank only receives FLAGS_selected_tpus, which confines
+            # nothing: every rank would open every local chip, and a chip
+            # belongs to one process. One controller process per host
+            # drives all its chips (multi-controller jax).
+            sys.exit(
+                f"paddle_tpu.distributed.launch: --nproc_per_node {nproc} "
+                "on a TPU host would start several processes on the same "
+                "chips, and a chip belongs to one process — use one "
+                "process per host (it drives every local chip), or set "
+                "JAX_PLATFORMS=cpu for the CPU fake-backend path.")
     node_rank = max(args.rank, 0)
     store = None
     if args.master and nnodes > 1:

@@ -583,13 +583,30 @@ class ShardedTrainStep(TrainStep):
                               rng_spec, z_spec, s1_spec, tp_spec, pp_spec)
                     + batch_specs,
                     out_specs=(P(), pspecs, nbspecs, sspecs, P()),
-                    check_vma=False, axis_names=set(axes),
+                    check_vma=False,
+                    axis_names=self._manual_axes(axes),
                 )(params, buffers, opt_state, lr, guard, key_arr,
                   rng_ids, z_ids, s1_ids, tp_ids, pp_ids, *batch)
 
         self._execs = {}
         self._checkified = False
         self._compiled = jax.jit(step, donate_argnums=(0, 2))
+
+    def _manual_axes(self, axes):
+        """``axis_names`` of a manual region over ``axes``: those, plus
+        every mesh axis of size 1. A size-1 axis has nothing to
+        partition, so making it manual changes no program — but Mosaic
+        refuses to lower a Pallas kernel inside a region that leaves ANY
+        mesh axis auto ("Mosaic kernels cannot be automatically
+        partitioned", met by the ZeRO-3 line on the four-chip v5e host,
+        PR 23: the fleet mesh always carries all five axes). Where
+        another axis is live (a true hybrid mesh) the region stays
+        partial, and a kernel inside it still needs a shard_map of its
+        own (ROADMAP Queue 1 item 5)."""
+        names = set(axes)
+        names.update(n for n in self.mesh.dim_names
+                     if self.mesh.get_dim_size(n) == 1)
+        return names
 
     def _build_zero(self, plan):
         """Compile the ZeRO step: one fully-manual shard_map region over
@@ -722,7 +739,8 @@ class ShardedTrainStep(TrainStep):
                     in_specs=(pspecs, bspecs, sspecs, P(), P(), P(),
                               P(axes), P(plan.shard_axis)) + batch_specs,
                     out_specs=(P(), pspecs, nbspecs, sspecs, P()),
-                    check_vma=False, axis_names=set(axes),
+                    check_vma=False,
+                    axis_names=self._manual_axes(axes),
                 )(params, buffers, opt_state, lr, guard, key_arr,
                   rng_ids, shard_ids, *batch)
 
@@ -973,7 +991,8 @@ class ShardedTrainStep(TrainStep):
                 in_specs=(pspecs, bspecs, P(), P(axes), P(plan.axis))
                 + batch_specs,
                 out_specs=(P(), nbspecs, pspecs),
-                check_vma=False, axis_names=set(axes),
+                check_vma=False,
+                    axis_names=self._manual_axes(axes),
             )(params, buffers, key_arr, shard_ids, sep_ids, *batch)
         if plan.calls_traced == 0:
             raise RuntimeError(
@@ -1075,7 +1094,7 @@ class ShardedTrainStep(TrainStep):
                 per_shard, mesh=self.mesh.jax_mesh,
                 in_specs=(pspecs, bspecs, P(), P(axes)) + batch_specs,
                 out_specs=(P(), nbspecs, pspecs),
-                check_vma=False, axis_names=region_axes,
+                check_vma=False, axis_names=self._manual_axes(region_axes),
             )(params, buffers, key_arr, shard_ids, *batch)
         return (loss, new_buffers), grads
 

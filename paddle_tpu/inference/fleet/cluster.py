@@ -94,18 +94,48 @@ class HeartbeatLost(ConnectionError):
     """A replica's heartbeat lease expired (=> transient taxonomy)."""
 
 
+def child_env():
+    """Environment of a worker / host-agent child process: the parent's
+    own, unbuffered. The platform is inherited, never chosen here — a
+    parent pinned to the CPU (the tests) spawns CPU children, a parent
+    on a TPU host spawns children that open the chip themselves."""
+    return dict(os.environ, PYTHONUNBUFFERED="1")
+
+
+def check_one_process_per_chip(live_children, where):
+    """A chip belongs to one process at a time, and nothing in a child's
+    environment confines it to a chip of its own (whether the installed
+    libtpu honours a per-process chip mask has not been verified on a
+    multi-chip host) — so a second chip-holding process on one host
+    would fail or hang on the device. Refuse it here, loudly. A fleet
+    the caller pinned to the CPU is not limited."""
+    from ...device import cpu_requested
+
+    if live_children and not cpu_requested():
+        raise RuntimeError(
+            f"{where}: {live_children} replica process(es) already hold "
+            "this host's TPU — one process per chip. Run more replicas "
+            "in ONE process (make_replicas / proc=False), or one "
+            "process-mode replica per host; JAX_PLATFORMS=cpu lifts the "
+            "limit for CPU runs.")
+
+
 # ---------------------------------------------------------------------------
 # Model spec (what crosses the spawn boundary)
 # ---------------------------------------------------------------------------
 def make_model_spec(config_kw, *, seed=0, version_seed_stride=0,
-                    engine_kw=None, flight_dir=None, metrics=False):
+                    engine_kw=None, flight_dir=None, metrics=False,
+                    dtype=None):
     """A plain-JSON replica spec: the child rebuilds its own weights
     from this, deterministically.  ``version_seed_stride`` controls
     what a rolling upgrade MEANS: 0 (default) reloads bitwise-identical
     weights (seed unchanged — migration and replay stay bitwise
     provable); N != 0 derives version v's seed as
-    ``seed + v * stride`` (a genuinely different checkpoint)."""
-    return {
+    ``seed + v * stride`` (a genuinely different checkpoint).
+    ``dtype`` (e.g. ``"bfloat16"``) is the parameter dtype the child
+    casts its seeded weights to — what the in-process TPU path serves;
+    unset keeps the float32 build."""
+    spec = {
         "model": "llama",
         "config": dict(config_kw),
         "seed": int(seed),
@@ -114,6 +144,9 @@ def make_model_spec(config_kw, *, seed=0, version_seed_stride=0,
         "flight_dir": flight_dir,
         "metrics": bool(metrics),
     }
+    if dtype is not None:
+        spec["dtype"] = str(dtype)
+    return spec
 
 
 def build_model_from_spec(spec, version=None):
@@ -127,7 +160,14 @@ def build_model_from_spec(spec, version=None):
     if version:
         seed += int(version) * int(spec.get("version_seed_stride", 0))
     paddle.seed(seed)
-    return LlamaForCausalLM(LlamaConfig(**spec["config"]))
+    model = LlamaForCausalLM(LlamaConfig(**spec["config"]))
+    if spec.get("dtype"):
+        import jax.numpy as jnp
+
+        dtype = jnp.dtype(spec["dtype"])
+        for _, p in model.named_parameters():
+            p._data = p._data.astype(dtype)
+    return model
 
 
 # ---------------------------------------------------------------------------
@@ -186,8 +226,6 @@ class ProcChild:
 
     def __init__(self, spec, replica_id, *, workdir,
                  spawn_timeout=180.0, transport_kw=None):
-        from ...testing.chaos import subprocess_env
-
         spec = dict(spec, replica_id=replica_id)
         os.makedirs(workdir, exist_ok=True)
         self.log_path = os.path.join(workdir, f"replica_{replica_id}.log")
@@ -199,11 +237,14 @@ class ProcChild:
             [sys.executable, "-m", "paddle_tpu.inference.fleet.worker",
              "--spec-file", spec_path],
             stdout=subprocess.PIPE, stderr=self._log,
-            env=subprocess_env(), cwd=os.getcwd())
+            env=child_env(), cwd=os.getcwd())
         self.pid = self.proc.pid
         info = self._handshake(spawn_timeout)
         self.port = info["port"]
         self.scrape_port = info.get("scrape_port")
+        #: the device the CHILD serves from, as its JAX reports it — the
+        #: parent never opens a backend to find out
+        self.device = info.get("device")
         # past the handshake, stdout is quiet; route the fd into the
         # log file and stop reading the pipe
         self.proc.stdout.close()
@@ -353,6 +394,7 @@ class FleetSupervisor:
         self.respawn = bool(respawn)
         self.warmup_new = bool(warmup_new)
         self.children = {}            # router idx -> child
+        self._proc_children = []      # every local ProcChild ever spawned
         self.tick = 0
         self.respawns = 0
         self.lease_deaths = 0
@@ -484,8 +526,12 @@ class FleetSupervisor:
                 host, self.spec, ordinal, transport_kw=self.transport_kw)
             host.pending += 1
         elif self.proc:
+            check_one_process_per_chip(
+                sum(1 for c in self._proc_children if c.poll() is None),
+                "FleetSupervisor")
             child = ProcChild(self.spec, ordinal, workdir=self.workdir,
                               transport_kw=self.transport_kw)
+            self._proc_children.append(child)
         else:
             child = LocalChild(self.spec, ordinal,
                                transport_kw=self.transport_kw)

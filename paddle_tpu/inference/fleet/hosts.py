@@ -88,19 +88,6 @@ class HostLost(ConnectionError):
     the work replays, the fleet survives)."""
 
 
-def _chip_inventory():
-    """Best-effort accelerator inventory for the rendezvous record —
-    advisory placement metadata, never load-bearing."""
-    try:
-        import jax
-
-        devs = jax.devices()
-        return {"count": len(devs),
-                "platform": devs[0].platform if devs else "none"}
-    except Exception:
-        return {"count": 0, "platform": "unknown"}
-
-
 # ---------------------------------------------------------------------------
 # Rendezvous directory (over the TCPStore)
 # ---------------------------------------------------------------------------
@@ -109,7 +96,9 @@ class HostDirectory:
 
     - ``fleet/nhosts`` — atomic ordinal allocator (``add(1) - 1``);
     - ``fleet/host/<n>`` — one JSON record per host (address, port,
-      slots, chips, pid), written by the host's own agent;
+      slots, pid), written by the host's own agent — which never
+      touches JAX: the chip belongs to its workers, whose spawn reply
+      carries the device each one serves from;
     - ``fleet/hb/<n>`` — a monotone heartbeat counter the agent bumps;
       liveness is "the counter advanced", never a wall-clock timestamp
       (an NTP step on either side must not kill a host).
@@ -216,7 +205,6 @@ class HostAgent:
             "pid": os.getpid(),
             "slots": self.slots,
             "mode": "proc" if self.proc else "local",
-            "chips": _chip_inventory(),
         })
         self.directory.beat(self.ordinal)
         return self.ordinal
@@ -273,14 +261,14 @@ class HostAgent:
         return {"host_id": self.host_id, "ordinal": self.ordinal,
                 "pid": os.getpid(), "slots": self.slots,
                 "mode": "proc" if self.proc else "local",
-                "n_workers": len(self.workers),
-                "chips": _chip_inventory()}
+                "n_workers": len(self.workers)}
 
     def _rpc_ping(self, a):
         return True
 
     def _rpc_spawn_worker(self, a):
-        from .cluster import LocalChild, ProcChild
+        from .cluster import (LocalChild, ProcChild,
+                              check_one_process_per_chip)
 
         wid = int(a["replica_id"])
         spec = a.get("spec") or self.spec
@@ -291,9 +279,14 @@ class HostAgent:
             raise RuntimeError(
                 f"host {self.host_id}: all {self.slots} slots in use")
         if self.proc:
+            check_one_process_per_chip(
+                sum(1 for c in self.workers.values()
+                    if c.poll() is None),
+                f"host agent {self.host_id}")
             child = ProcChild(spec, wid, workdir=self.workdir)
             info = {"mode": "proc", "port": child.port, "pid": child.pid,
-                    "scrape_port": child.scrape_port}
+                    "scrape_port": child.scrape_port,
+                    "device": child.device}
         else:
             child = LocalChild(spec, wid)
             info = {"mode": "local", "pid": child.pid}
@@ -559,7 +552,7 @@ class AgentProc:
 
     def __init__(self, spec, host_id, *, store_host, store_port,
                  workdir, slots=8, spawn_timeout=180.0):
-        from ...testing.chaos import subprocess_env
+        from .cluster import child_env
 
         os.makedirs(workdir, exist_ok=True)
         agent_spec = {
@@ -580,7 +573,7 @@ class AgentProc:
             [sys.executable, "-m", "paddle_tpu.inference.fleet.hosts",
              "--spec-file", spec_path],
             stdout=subprocess.PIPE, stderr=self._log,
-            env=subprocess_env(), cwd=os.getcwd())
+            env=child_env(), cwd=os.getcwd())
         self.pid = self.proc.pid
         info = self._handshake(spawn_timeout)
         self.port = info["port"]

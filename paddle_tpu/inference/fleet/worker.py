@@ -10,7 +10,8 @@ RPC socket.
 Startup handshake: one line on stdout ::
 
     PTPU_WORKER_READY {"port": ..., "pid": ..., "replica_id": ...,
-                       "scrape_port": ...}
+                       "scrape_port": ..., "device": {"platform": ...,
+                       "kind": ..., "count": ...}}
 
 then the socket serve loop runs until a ``shutdown`` RPC (or a signal).
 
@@ -97,6 +98,14 @@ def main(argv=None):
 
     from .router import RID_STRIDE
 
+    # this process owns its device: fail here if JAX found no TPU and
+    # nobody asked for the CPU, and keep compiled programs where the
+    # next replica of this checkout finds them
+    from ...device import (compile_cache_dir, device_record,
+                           require_accelerator)
+
+    require_accelerator(f"fleet worker {replica_id}")
+    compile_cache_dir()
     model = build_model_from_spec(spec)
     engine = ContinuousBatchingEngine(
         model, rid_base=replica_id * RID_STRIDE,
@@ -111,7 +120,8 @@ def main(argv=None):
     loop = SocketServerLoop(server, port=spec.get("port", 0))
     print("PTPU_WORKER_READY " + json.dumps({
         "port": loop.port, "pid": os.getpid(),
-        "replica_id": replica_id, "scrape_port": scrape_port}),
+        "replica_id": replica_id, "scrape_port": scrape_port,
+        "device": device_record()}),
         flush=True)
     loop.serve_forever()
     return 0

@@ -1928,6 +1928,29 @@ class ContinuousBatchingEngine:
         self.prefix_pages_imported += n
         return n
 
+    def _dummy_decode_operands(self, do_sample=False):
+        """Full-width decode-tick operands whose cache writes land in the
+        scratch page — what :meth:`warmup` compiles against."""
+        jax, jnp = self._jax, self._jnp
+        b = self.max_slots
+        return (self._weights, jnp.zeros((b,), jnp.int32),
+                jnp.zeros((b,), jnp.int32),
+                jnp.full((b, self.pages_per_seq), self._trash_page,
+                         jnp.int32),
+                self.kc, self.vc, jnp.zeros((b,), jnp.float32),
+                jnp.zeros((b,), jnp.int32), jnp.ones((b,), jnp.float32),
+                jax.random.PRNGKey(0),  # never touches self._key's stream
+                do_sample)
+
+    def decode_program_text(self):
+        """StableHLO text of the greedy decode tick (trace + lower, no
+        compile, nothing executed). The paged-attention Mosaic kernel
+        appears in it as a ``tpu_custom_call`` carrying its
+        ``kernel_name`` — chip_smoke.py's proof that decode runs the
+        kernel, not a reference."""
+        return self._decode_jit.lower(
+            *self._dummy_decode_operands()).as_text()
+
     def warmup(self, sample=False):
         """Compile the engine's programs on dummy operands (cache writes
         land in the scratch page) and record the wall time in
@@ -1941,20 +1964,14 @@ class ContinuousBatchingEngine:
         jax, jnp = self._jax, self._jnp
         t0 = time.perf_counter()
         b = self.max_slots
-        tokens = jnp.zeros((b,), jnp.int32)
         lens = jnp.zeros((b,), jnp.int32)
         tables = jnp.full((b, self.pages_per_seq), self._trash_page,
                           jnp.int32)
-        temps = jnp.zeros((b,), jnp.float32)
-        top_ks = jnp.zeros((b,), jnp.int32)
-        top_ps = jnp.ones((b,), jnp.float32)
-        key = jax.random.PRNGKey(0)   # never touches self._key's stream
         modes = () if self.prefill_only else (
             (False, True) if sample else (False,))
         for do_sample in modes:
             nxt, self.kc, self.vc = self._decode_jit(
-                self._weights, tokens, lens, tables, self.kc, self.vc,
-                temps, top_ks, top_ps, key, do_sample)
+                *self._dummy_decode_operands(do_sample))
             np.asarray(nxt)           # block: compile + first dispatch
         if self.prefill_chunk is not None:
             B, c = self.max_slots, self.prefill_chunk

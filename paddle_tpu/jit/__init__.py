@@ -57,27 +57,13 @@ _LAST_COMPILE = {}
 def _device_peaks():
     """(peak_flops, peak_bytes_per_sec, placeholder?) for device 0 —
     the roofline denominators behind the dispatch-span cost attrs and
-    the bench anatomy's cost-analysis MFU. bf16 peak per chip / HBM
-    bandwidth from the public chip tables; unknown kinds and CPU dev
-    runs get placeholder numbers flagged as such (the host-overhead
-    bench gate only engages on non-placeholder estimates)."""
-    try:
-        d = jax.devices()[0]
-        kind = d.device_kind.lower()
-        platform = d.platform
-    except Exception:
-        return 1e12, 100e9, True
-    if platform == "cpu":
-        return 1e12, 100e9, True
-    if "v5p" in kind:
-        return 459e12, 2765e9, False
-    if "v5e" in kind or "v5 lite" in kind or "v5" == kind:
-        return 197e12, 819e9, False
-    if "v4" in kind:
-        return 275e12, 1228e9, False
-    if "v6" in kind or "trillium" in kind:
-        return 918e12, 1640e9, False
-    return 197e12, 819e9, True
+    the bench anatomy's cost-analysis MFU, from the one chip table
+    (``paddle_tpu.device.CHIP_PEAKS``). CPU runs get the flagged
+    placeholder row; an unknown TPU kind raises."""
+    from ..device import chip_peaks
+
+    peaks, placeholder = chip_peaks()
+    return peaks["bf16_flops"], peaks["hbm_bytes_per_sec"], placeholder
 
 
 def compiled_cost_summary(compiled):
@@ -85,15 +71,9 @@ def compiled_cost_summary(compiled):
     {"flops", "bytes_accessed", "device_seconds_est" (roofline:
     max(flops/peak_flops, bytes/peak_bw)), "peak_flops",
     "peak_bytes_per_sec", "peak_model_placeholder"} — or None when the
-    executable exposes no cost analysis (plain jit dispatch
-    fallback)."""
-    try:
-        ca = compiled.cost_analysis()
-    except Exception:
-        return None
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
-    if not isinstance(ca, dict) or not ca:
+    executable exposes no cost analysis."""
+    ca = compiled.cost_analysis()
+    if not ca:
         return None
     flops = float(ca.get("flops", 0.0) or 0.0)
     nbytes = float(ca.get("bytes accessed", 0.0) or 0.0)
@@ -107,6 +87,31 @@ def compiled_cost_summary(compiled):
         "peak_flops": pf,
         "peak_bytes_per_sec": pb,
         "peak_model_placeholder": bool(placeholder),
+    }
+
+
+def _memory_record(compiled):
+    """``compiled.memory_analysis()`` as the planners' pricing dict.
+    ``peak_bytes`` is what gets compared with the HBM budget: on a TPU,
+    XLA's own high-water mark (``peak_memory_in_bytes``). Measured on
+    the v5e (PR 23) for GPT-1.3B: at batch 3 it reports 15.36 GB and the
+    program loads and runs in the chip's 15.75 GiB, while argument +
+    temp gives 18.71 GB for that same program (the TPU's temp size does
+    not net out the donated buffers); batch 4 the compiler itself
+    refuses at 16.69 G of 15.75 G. The CPU backend's
+    ``peak_memory_in_bytes`` leaves the temporaries out, so there the
+    sum stays."""
+    ma = compiled.memory_analysis()
+    if jax.devices()[0].platform == "tpu":
+        peak = ma.peak_memory_in_bytes
+    else:
+        peak = ma.argument_size_in_bytes + ma.temp_size_in_bytes
+    return {
+        "argument_bytes": int(ma.argument_size_in_bytes),
+        "output_bytes": int(ma.output_size_in_bytes),
+        "temp_bytes": int(ma.temp_size_in_bytes),
+        "alias_bytes": int(ma.alias_size_in_bytes),
+        "peak_bytes": int(peak),
     }
 
 
@@ -144,17 +149,9 @@ def _traced_dispatch(ex, label, cost, op_args):
 
 
 def _serialized_hlo_bytes(lowered):
-    """Size of the lowered program: serialized HLO proto when this
-    jax/jaxlib exposes it, StableHLO text length otherwise (both are
-    monotone in program size, which is what the depth-sweep asserts)."""
-    try:
-        return len(lowered.compiler_ir(
-            dialect="hlo").as_serialized_hlo_module_proto())
-    except Exception:
-        try:
-            return len(lowered.as_text())
-        except Exception:
-            return 0
+    """Size of the lowered program: its serialized HLO module proto."""
+    return len(lowered.compiler_ir(
+        dialect="hlo").as_serialized_hlo_module_proto())
 
 
 def _record_compile_phases(label, trace_s, lower_s, compile_s, hlo_bytes):
@@ -183,25 +180,9 @@ def timed_lower_compile(jitfn, label, *args, **kwargs):
     the per-phase gauges. Returns the Compiled executable (same program
     jit dispatch would build — donation and shardings preserved)."""
     t0 = _time.perf_counter()
-    traced = None
-    if hasattr(jitfn, "trace"):
-        try:
-            traced = jitfn.trace(*args, **kwargs)
-        except TypeError as e:
-            # only a .trace() CALLING-convention mismatch falls back to
-            # .lower(); genuine trace-time errors (TracerBoolConversion
-            # et al. subclass TypeError via JAXTypeError) must propagate
-            # — re-tracing through .lower() just to re-raise them would
-            # double the trace cost of every graph-breaking call
-            if isinstance(e, jax.errors.JAXTypeError):
-                raise
-            traced = None
-    if traced is not None:
-        t1 = _time.perf_counter()
-        lowered = traced.lower()
-    else:  # older jax: .lower() fuses trace+lower; report it as lower
-        t1 = t0
-        lowered = jitfn.lower(*args, **kwargs)
+    traced = jitfn.trace(*args, **kwargs)
+    t1 = _time.perf_counter()
+    lowered = traced.lower()
     t2 = _time.perf_counter()
     compiled = lowered.compile()
     t3 = _time.perf_counter()
@@ -470,19 +451,14 @@ class StaticFunction:
         so the compile-phase gauges (trace/lower/compile seconds +
         hlo_program_bytes, labeled by function) cover to_static programs
         too, and caches the program's cost_analysis summary for the
-        dispatch trace span. Graph-break tracer errors propagate to
-        __call__'s eager fallback; any other AOT surprise degrades to
-        plain jit dispatch."""
+        dispatch trace span. A failed build propagates: graph-break
+        tracer errors reach __call__'s eager fallback, anything else is
+        the caller's error — never a second, untimed compile."""
         jitfn, ex = slot[0], slot[1]
         label = self._dispatch_label
         if ex is None:
-            try:
-                ex = timed_lower_compile(jitfn, label, *args)
-                slot[2] = compiled_cost_summary(ex)
-            except self._GRAPH_BREAK_ERRORS:
-                raise
-            except Exception:
-                ex = jitfn
+            ex = timed_lower_compile(jitfn, label, *args)
+            slot[2] = compiled_cost_summary(ex)
             slot[1] = ex
         try:
             return _traced_dispatch(ex, label, slot[2], args)
@@ -863,20 +839,17 @@ class TrainStep:
         """Run the step program through an explicitly built executable so
         the build splits into measured trace/lower/compile phases
         (compile-phase gauges + the bench "compile" block). Signature
-        miss -> timed AOT build; any AOT surprise falls back to plain
-        ``jax.jit`` dispatch — never worse than the pre-telemetry path."""
+        miss -> timed AOT build; a failed build raises — it is never
+        swallowed into a second compile through ``jax.jit`` dispatch."""
         key = self._exec_sig(op_args)
         ex = self._execs.get(key)
         if ex is None:
-            try:
-                ex = timed_lower_compile(self._compiled,
-                                         self._compile_label(), *op_args)
-                cost = compiled_cost_summary(ex)
-                self._exec_costs[key] = cost
-                if cost is not None:
-                    self._last_cost = cost
-            except Exception:
-                ex = self._compiled
+            ex = timed_lower_compile(self._compiled,
+                                     self._compile_label(), *op_args)
+            cost = compiled_cost_summary(ex)
+            self._exec_costs[key] = cost
+            if cost is not None:
+                self._last_cost = cost
             self._execs[key] = ex
         try:
             return _traced_dispatch(ex, self._compile_label(),
@@ -1037,16 +1010,41 @@ class TrainStep:
         opt state on the mesh so the lowered program matches a real
         step's shardings — the zero-allocation guarantee is for the
         single-program TrainStep the planner drives.)"""
+        return timed_lower_compile(
+            self._compiled_fn(), self._compile_label(),
+            *self._aot_operands(*batch))
+
+    def lowered_text(self, *batch):
+        """StableHLO text of this step's program for ``batch`` (Tensors,
+        arrays or ShapeDtypeStructs): trace + lower, no compile and no
+        execution. Every Mosaic kernel in the step appears as a
+        ``tpu_custom_call`` carrying its ``kernel_name`` — how
+        chip_smoke.py proves the Pallas kernels are IN the program
+        rather than replaced by a reference."""
+        return self._compiled_fn().trace(
+            *self._aot_operands(*batch)).lower().as_text()
+
+    def _compiled_fn(self):
         if self._compiled is None:
             self._build()
+        return self._compiled
+
+    def _aot_operands(self, *batch):
+        """The step's operands as avals, for lowering without buffers
+        (call after :meth:`_compiled_fn`: the build names the params)."""
         raw_batch = self._prepare_batch(_unwrap_tensors(batch))
 
         def aval(a):
-            # keep the array's sharding (ShardedTrainStep places batch/
-            # state with NamedShardings via _prepare_batch — the lowered
-            # program must see the same placements a real step would)
+            # keep a MESH placement (ShardedTrainStep places batch/state
+            # with NamedShardings via _prepare_batch — the lowered
+            # program must see the same placements a real step would).
+            # A plain single-device array carries none into a real
+            # dispatch either: pinning its SingleDeviceSharding here
+            # would annotate every operand, so the priced program and
+            # the dispatched one would differ in text and never share a
+            # compile-cache entry.
             sh = getattr(a, "sharding", None)
-            if sh is not None:
+            if isinstance(sh, jax.sharding.NamedSharding):
                 return jax.ShapeDtypeStruct(tuple(a.shape),
                                             jnp.dtype(a.dtype), sharding=sh)
             return jax.ShapeDtypeStruct(tuple(a.shape), jnp.dtype(a.dtype))
@@ -1062,9 +1060,8 @@ class TrainStep:
         guard_aval = jax.ShapeDtypeStruct((4,), jnp.float32)
         key_arr = aval(framework.next_rng_key())
         batch_avals = tree_util.tree_map(aval, raw_batch)
-        return timed_lower_compile(
-            self._compiled, self._compile_label(), params, buffers,
-            opt_state, lr, guard_aval, key_arr, batch_avals)
+        return (params, buffers, opt_state, lr, guard_aval, key_arr,
+                batch_avals)
 
     def memory_stats(self, *batch):
         """XLA buffer-assignment stats for this step's program: dict of
@@ -1072,15 +1069,7 @@ class TrainStep:
         compiles ahead-of-time without executing (aot_compile) — meant
         for small trial programs (the auto_tuner's measure mode) and the
         memory planner, not the training hot path."""
-        ma = self.aot_compile(*batch).memory_analysis()
-        return {
-            "argument_bytes": int(ma.argument_size_in_bytes),
-            "output_bytes": int(ma.output_size_in_bytes),
-            "temp_bytes": int(ma.temp_size_in_bytes),
-            "alias_bytes": int(ma.alias_size_in_bytes),
-            "peak_bytes": int(ma.argument_size_in_bytes
-                              + ma.temp_size_in_bytes),
-        }
+        return _memory_record(self.aot_compile(*batch))
 
     def aot_report(self, *batch):
         """One AOT compile, both pricing surfaces: ``(memory, cost)``
@@ -1091,16 +1080,7 @@ class TrainStep:
         memory_stats and a separate cost pass would pay the
         lower+compile twice per candidate."""
         compiled = self.aot_compile(*batch)
-        ma = compiled.memory_analysis()
-        mem = {
-            "argument_bytes": int(ma.argument_size_in_bytes),
-            "output_bytes": int(ma.output_size_in_bytes),
-            "temp_bytes": int(ma.temp_size_in_bytes),
-            "alias_bytes": int(ma.alias_size_in_bytes),
-            "peak_bytes": int(ma.argument_size_in_bytes
-                              + ma.temp_size_in_bytes),
-        }
-        return mem, compiled_cost_summary(compiled)
+        return _memory_record(compiled), compiled_cost_summary(compiled)
 
     def _prepare_batch(self, raw_batch):
         """Hook: sharded subclasses place batch arrays on the mesh so the
@@ -1333,11 +1313,9 @@ def save(layer, path, input_spec=None, **configs):
             out = run(state_list, *inputs)
             return tuple(tree_util.tree_leaves(out))
 
-        try:  # platform-polymorphic artifact when supported (cpu dev / tpu)
-            exported = jax_export.export(
-                jax.jit(pure), platforms=("cpu", "tpu"))(weights, *avals)
-        except Exception:
-            exported = jax_export.export(jax.jit(pure))(weights, *avals)
+        # platform-polymorphic artifact (cpu dev / tpu)
+        exported = jax_export.export(
+            jax.jit(pure), platforms=("cpu", "tpu"))(weights, *avals)
         blob = exported.serialize()
     finally:
         if was_training:

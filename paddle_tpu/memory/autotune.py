@@ -53,7 +53,8 @@ import time
 
 from .. import telemetry as _telemetry
 from .planner import (MemoryPlanError, PlanDecision, _cache_load,
-                      _cache_store, chip_kind, hbm_budget_bytes)
+                      _cache_store, chip_kind, hbm_budget_bytes,
+                      is_hbm_oom)
 
 _CANDS = _telemetry.counter(
     "autotune_candidates_total",
@@ -212,25 +213,18 @@ class LayoutDecision:
 
 
 # -- link model --------------------------------------------------------------
-#: per-chip interconnect bytes/sec for the comm term — order-of-
-#: magnitude public ICI numbers; the cost model only needs to RANK
-#: layouts, not predict absolute step time. CPU/unknown chips get a
-#: placeholder flagged in the decision's "link" record.
-_CHIP_LINK = (("v5p", 180e9), ("v5e", 90e9), ("v5 lite", 90e9),
-              ("trillium", 180e9), ("v6", 180e9), ("v4", 100e9))
-
-
 def link_bytes_per_sec():
     """(bytes_per_sec, placeholder?) of the inter-chip link:
-    ``PTPU_LINK_GBPS`` override > chip table > 10 GB/s placeholder."""
+    ``PTPU_LINK_GBPS`` override > the chip table's published ICI figure
+    (``paddle_tpu.device.CHIP_PEAKS``; CPU runs get the flagged
+    placeholder, an unknown TPU kind raises)."""
     env = os.environ.get("PTPU_LINK_GBPS")
     if env:
         return float(env) * 1e9, False
-    kind = chip_kind().lower()
-    for k, v in _CHIP_LINK:
-        if k in kind:
-            return float(v), False
-    return 10e9, True
+    from ..device import chip_peaks
+
+    peaks, placeholder = chip_peaks()
+    return float(peaks["ici_bytes_per_sec"]), placeholder
 
 
 def plan_wire_bytes(step):
@@ -632,9 +626,17 @@ def autotune_train_step(model_factory, *, seq_len, layouts=None,
             try:
                 mem, cost = step.aot_report(*avals_fn(layout))
             except Exception as e:
+                if not is_hbm_oom(e):
+                    # a refused kernel or a tracing bug is not a
+                    # property of the layout's size — surface it
+                    e.add_note(f"while pricing layout {layout.label()} "
+                               "(memory.autotune_train_step)")
+                    raise
+                # the compiler ran out of HBM: the one lowering failure
+                # that means "this layout does not fit"
                 errors.append({"label": layout.label(),
                                "error": str(e)[:200]})
-                _CANDS.inc(labels=("error", "lowering_error"))
+                _CANDS.inc(labels=("error", "compile_oom"))
                 return None
             _CANDS.inc(labels=("lowered",
                                _lattice_owner_for(layout) or "composed"))

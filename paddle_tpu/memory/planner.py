@@ -48,9 +48,9 @@ _ACT_INT8 = _telemetry.gauge(
 _PLAN_EVALS = _telemetry.counter(
     "memory_plan_lowerings_total",
     "candidate TrainStep programs lowered+compiled by the planner",
-    # fit | over_budget | error | cache_hit | memoized ("memoized" =
-    # a build SAVED because an earlier candidate already lowered the
-    # same traced program; fit+over_budget+error = actual lowerings)
+    # fit | over_budget | cache_hit | memoized ("memoized" = a build
+    # SAVED because an earlier candidate already lowered the same
+    # traced program; fit+over_budget = actual lowerings)
     labelnames=("outcome",))
 
 
@@ -113,23 +113,16 @@ class PlanDecision:
 
 
 # -- budget -----------------------------------------------------------------
-#: per-chip HBM when the backend doesn't report bytes_limit
-_CHIP_HBM = (("v5p", 95e9), ("v5 lite", 16e9), ("v5e", 16e9),
-             ("trillium", 32e9), ("v6", 32e9), ("v4", 32e9))
-
-
 def chip_kind():
     import jax
 
-    try:
-        return jax.devices()[0].device_kind
-    except Exception:
-        return "unknown"
+    return jax.devices()[0].device_kind
 
 
 def hbm_budget_bytes(budget=None):
     """Resolve the HBM budget: PTPU_HBM_BUDGET env (GB if < 1024, bytes
-    otherwise) > explicit arg > backend bytes_limit > chip table > 16GB."""
+    otherwise) > explicit arg > backend bytes_limit > the chip table
+    (``paddle_tpu.device.CHIP_PEAKS``; an unknown TPU kind raises)."""
     env = os.environ.get("PTPU_HBM_BUDGET")
     if env:
         v = float(env)
@@ -138,17 +131,25 @@ def hbm_budget_bytes(budget=None):
         return int(budget)
     import jax
 
-    try:
-        stats = jax.devices()[0].memory_stats() or {}
-        if stats.get("bytes_limit"):
-            return int(stats["bytes_limit"])
-    except Exception:
-        pass
-    kind = chip_kind().lower()
-    for k, v in _CHIP_HBM:
-        if k in kind:
-            return int(v)
-    return int(16e9)
+    from ..device import chip_peaks
+
+    stats = jax.devices()[0].memory_stats() or {}
+    if stats.get("bytes_limit"):
+        return int(stats["bytes_limit"])
+    return int(chip_peaks()[0]["hbm_bytes"])
+
+
+def is_hbm_oom(exc):
+    """True when ``exc`` is XLA refusing a program because it does not
+    fit device memory — the ONLY compile failure the planners may file
+    as "over budget". Anything else (a Mosaic refusal, a scoped-VMEM
+    overflow inside a kernel, a tracing error) is a bug in the program,
+    not a property of the candidate, and must surface."""
+    import jax
+
+    msg = str(exc)
+    return (isinstance(exc, jax.errors.JaxRuntimeError)
+            and "RESOURCE_EXHAUSTED" in msg and "hbm" in msg.lower())
 
 
 # -- throughput estimate ----------------------------------------------------
@@ -370,10 +371,7 @@ def plan_train_step(step_factory, candidates, *, budget_bytes=None,
 
     budget = hbm_budget_bytes(budget_bytes)
     chip = chip_kind()
-    try:
-        ndev = len(jax.devices())
-    except Exception:
-        ndev = 1
+    ndev = len(jax.devices())
     order = sorted(
         candidates,
         key=lambda c: (c.score if c.score is not None
@@ -442,14 +440,24 @@ def plan_train_step(step_factory, candidates, *, budget_bytes=None,
             step._planning = True
             try:
                 mem = step.memory_stats(*batch_avals)
-            except Exception as e:  # lowering/compile failure = not plannable
-                _PLAN_EVALS.inc(labels=("error",))
+            except Exception as e:
+                if not is_hbm_oom(e):
+                    # not a property of the candidate's size: a kernel
+                    # the compiler refused, a tracing bug — surface it
+                    # with the candidate named, never as "does not fit"
+                    e.add_note(f"while pricing candidate {pkey!r} "
+                               "(memory.plan_train_step)")
+                    raise
+                # the compiler itself ran out of HBM: over budget, with
+                # no peak to report
+                _PLAN_EVALS.inc(labels=("over_budget",))
                 evaluated.append(
                     {"batch": cand.batch, "policy": cand.policy,
                      "head_chunk": getattr(cand, "head_chunk", None),
                      "depth": getattr(cand, "depth", None),
                      "quant": getattr(cand, "quant", None),
-                     "score": score, "error": str(e)[:200]})
+                     "score": score, "fits": False,
+                     "compile_oom": str(e)[:200]})
                 continue
             lowered[pkey] = mem
         # zero pricing: the sharded stages free (1 - 1/degree) of the

@@ -2,8 +2,8 @@
 
 On TPU the flash-attention capability slot (reference: CUDA flashattn lib at
 ``phi/kernels/gpu/flash_attn_kernel.cu``) is filled by a Pallas splash/flash
-kernel when running on real TPU hardware, with a pure-XLA fallback that still
-fuses well (used on CPU test meshes and for odd shapes).
+kernel when running on real TPU hardware; CPU test meshes and shapes Mosaic
+cannot tile take a pure-XLA path that still fuses well.
 
 Layout note: paddle attention tensors are [batch, seq, heads, head_dim].
 """
@@ -109,7 +109,10 @@ def _constrain_heads_over_mp(q, k, v):
 
 
 def sdpa_arrays(q, k, v, causal=True, scale=None):
-    """Array-level attention: pallas flash kernel when eligible, XLA fallback.
+    """Array-level attention: the pallas flash kernel on a TPU for
+    Mosaic-tileable shapes (:func:`_use_pallas`), XLA SDPA otherwise.
+    The shape test is the whole choice: an error from the kernel
+    propagates, it never selects the XLA path.
 
     The single dispatch point shared by the functional API and the pure
     model paths (models/gpt.py stacked decoder)."""
@@ -117,14 +120,11 @@ def sdpa_arrays(q, k, v, causal=True, scale=None):
 
     q, k, v = _constrain_heads_over_mp(q, k, v)
     if _use_pallas(q.shape):
-        try:
-            from ...ops.pallas import flash_attention as _fa_kernel
+        from ...ops.pallas import flash_attention as _fa_kernel
 
-            out = _fa_kernel(q, k, v, causal=causal, scale=scale)
-            log_path_once("sdpa", "pallas_flash")
-            return out
-        except Exception:
-            pass
+        out = _fa_kernel(q, k, v, causal=causal, scale=scale)
+        log_path_once("sdpa", "pallas_flash")
+        return out
     log_path_once("sdpa", "xla_sdpa")
     if k.shape[2] != q.shape[2]:
         rep = q.shape[2] // k.shape[2]

@@ -111,25 +111,17 @@ def int8_head_gate(tol=None):
         l, (gh, gw) = jax.value_and_grad(f, argnums=(0, 1))(h, w)
         return float(l), np.asarray(gh), np.asarray(gw)
 
-    try:
+    # a probe that CRASHES raises (a broken int8 dot is a bug to see,
+    # not a default to flip); only measured drift turns the head off.
+    # The first caller is usually the loss's own trace: evaluate eagerly
+    # there too, or the probe's values are tracers of that trace
+    with jax.ensure_compile_time_eval():
         lf, ghf, gwf = loss_grads(False)
         l8, gh8, gw8 = loss_grads(True)
-        ok = abs(l8 - lf) / max(abs(lf), 1e-9) < tol
-        for g8, gf in ((gh8, ghf), (gw8, gwf)):
-            denom = np.abs(gf).mean() + 1e-9
-            ok = ok and (np.abs(g8 - gf).mean() / denom < 5 * tol)
-    except Exception as e:
-        # a failing probe must never take the train step down, but a
-        # CRASHED gate (vs a numeric fail) silently turning the default
-        # off would only show up as an unexplained tokens/sec drop — be
-        # loud about which one happened
-        import warnings
-
-        warnings.warn(
-            f"int8_head_gate probe crashed ({type(e).__name__}: {e}); "
-            "defaulting the int8 LM head OFF. PTPU_INT8_HEAD=1 forces it.",
-            RuntimeWarning)
-        ok = False
+    ok = abs(l8 - lf) / max(abs(lf), 1e-9) < tol
+    for g8, gf in ((gh8, ghf), (gw8, gwf)):
+        denom = np.abs(gf).mean() + 1e-9
+        ok = ok and (np.abs(g8 - gf).mean() / denom < 5 * tol)
     _GATE_CACHE[tol] = bool(ok)
     return _GATE_CACHE[tol]
 

@@ -47,6 +47,7 @@ def _fwd(x2, r2, w, eps, interpret):
     with jax.enable_x64(False):
         y, o, rstd = pl.pallas_call(
             functools.partial(_fwd_kernel, eps=eps),
+            name="add_rms_norm_fwd",
             grid=(n // br,),
             in_specs=[
                 pl.BlockSpec((br, h), lambda i: (i, 0)),

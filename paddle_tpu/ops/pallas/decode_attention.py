@@ -33,8 +33,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# f32-typed constants: weak python floats promote to f64 under x64 on
-# old-jax interpret-mode lowering, which rejects the mixed-width where()
+# f32-typed constants: the package runs with x64 on, where a weak python
+# float would trace as f64 next to the kernel's f32 operands
 NEG_INF = np.float32(-1e30)
 ONE_F32 = np.float32(1.0)
 
@@ -104,6 +104,7 @@ def decode_attention(q, k_cache, v_cache, lengths, *, scale=None,
     with jax.enable_x64(False):
         out = pl.pallas_call(
             kern,
+            name="decode_attention",
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1,
                 grid=(b, hkv, nk),
@@ -266,6 +267,7 @@ def paged_attention_int8(q, k_codes, k_scales, v_codes, v_scales,
     with jax.enable_x64(False):
         out = pl.pallas_call(
             kern,
+            name="paged_attention_int8",
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2,
                 grid=(b, hkv, pages_per_seq),
@@ -323,8 +325,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
 
     def _page_index(bi, h, j, tables, lens):
         # clamp so garbage table entries past `lengths` stay in-bounds
-        # (i32 bounds: python-int literals weak-type to i64 under x64 and
-        # old-jax lowering rejects the mixed-width clip call)
+        # (i32 bounds: python-int literals weak-type to i64 under x64)
         t = tables[bi, j]
         return (h, jnp.clip(t, jnp.int32(0), jnp.int32(num_pages - 1)),
                 0, 0)
@@ -335,6 +336,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
     with jax.enable_x64(False):
         out = pl.pallas_call(
             kern,
+            name="paged_attention",
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2,
                 grid=(b, hkv, pages_per_seq),

@@ -32,8 +32,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# f32-typed constants: weak python floats promote to f64 under x64 on
-# old-jax interpret-mode lowering, which rejects the mixed-width where()
+# f32-typed constants: the package runs with x64 on, where a weak python
+# float would trace as f64 next to the kernel's f32 operands
 NEG_INF = np.float32(-1e30)  # large-negative instead of -inf: keeps exp()
                  # exact zero without nan from (-inf) - (-inf) in rescale
 ONE_F32 = np.float32(1.0)
@@ -245,8 +245,19 @@ def _fwd_call(kern, q, k, v, bhq, sq, sk, d, bq, bk, nq, nk, hq, hk,
         def kv_j(b, i, j):
             return (_kv_index(b, hq, hk), j, 0)
 
+    # Mosaic's scoped-VMEM default is 16 MiB, and at bq = bk = 2048 the
+    # f32 score tile alone is bq*bk*4 = 16 MiB: inside the LLaMA-arch
+    # ZeRO-3 step on the v5e the compiler asked for 16.48 MiB and refused
+    # the kernel by 496 KiB (PR 23; standalone and in the GPT-1.3B step
+    # the same block squeezed under the limit). Where two score tiles (s
+    # and exp(s - m) are live together) exceed the default, ask for
+    # exactly that — 32 MiB of the v5e's 128 MiB VMEM at block 2048;
+    # smaller blocks keep the default.
+    score_tiles = 2 * bq * bk * 4
+    vmem_limit = score_tiles if score_tiles > (16 << 20) else None
     return pl.pallas_call(
         kern,
+        name="flash_fwd",
         grid=(bhq, nq, nk),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
@@ -268,7 +279,8 @@ def _fwd_call(kern, q, k, v, bhq, sq, sk, d, bq, bk, nq, nk, hq, hk,
         ],
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit),
         cost_estimate=pl.CostEstimate(
             flops=4 * bhq * sq * sk * d,
             bytes_accessed=(2 * bhq * sq * d + 2 * (bhq // (hq // hk)) * sk * d)
@@ -492,9 +504,10 @@ def _bwd_impl(q, k, v, o, lse, do, scale, causal, interpret, hq, hk):
 
     # Fused single-pass backward (default where the dq scratch fits):
     # measured on v5e 1.3B/b3 GPT 0.5596 -> 0.5788 MFU, LLaMA-arch
-    # 0.6382 -> 0.6462 (tools/r5/sweep6). PTPU_FA_FUSED_BWD=1 forces it,
-    # =0 forces the split kernels; unset -> auto by VMEM budget (the
-    # [rep*sq, d] f32 dq scratch must leave room for the k/v/do blocks).
+    # 0.6382 -> 0.6462 (r5 A/B, docs/ROUND5_RESPONSE.md).
+    # PTPU_FA_FUSED_BWD=1 forces it, =0 forces the split kernels; unset ->
+    # auto by VMEM budget (the [rep*sq, d] f32 dq scratch must leave room
+    # for the k/v/do blocks).
     flag = _os.environ.get("PTPU_FA_FUSED_BWD", "")
     dq_scratch_bytes = rep * sq * d * 4
     use_fused = (flag != "0" if flag
@@ -515,6 +528,7 @@ def _bwd_impl(q, k, v, o, lse, do, scale, causal, interpret, hq, hk):
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           bq=bq, bk=bk, nk=nk, offset=offset),
+        name="flash_bwd_dq",
         grid=(bhq, nq, nk),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
@@ -540,6 +554,7 @@ def _bwd_impl(q, k, v, o, lse, do, scale, causal, interpret, hq, hk):
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           bq=bq, bk=bk, nq=nq, nq_total=rep * nq,
                           offset=offset),
+        name="flash_bwd_dkv",
         grid=(bhk, nk, rep * nq),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, jk, j: (_q_index(b, j), _qi_of(jk, j), 0)),
@@ -582,6 +597,7 @@ def _bwd_fused(q, k, v, do, lse8, delta8, *, scale, causal, interpret,
         functools.partial(_bwd_fused_kernel, scale=scale, causal=causal,
                           bq=bq, bk=bk, nq=nq, nq_total=rep * nq, nk=nk,
                           offset=offset, sq=sq),
+        name="flash_bwd_fused",
         grid=(bhk, nk, rep * nq),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, jk, j: (_q_index(b, j), _qi_of(jk, j), 0)),

@@ -42,6 +42,7 @@ def _fwd(x2, w, eps, interpret):
 def _fwd_call(n, h, br, eps, interpret, x2, w):
     return pl.pallas_call(
         functools.partial(_fwd_kernel, eps=eps),
+        name="rms_norm_fwd",
         grid=(n // br,),
         in_specs=[
             pl.BlockSpec((br, h), lambda i: (i, 0)),

@@ -85,6 +85,7 @@ def _fwd(g2, u2, wd, interpret):
     with jax.enable_x64(False):
         out = pl.pallas_call(
             functools.partial(_fwd_kernel, nk=nk),
+            name="swiglu_down_fwd",
             grid=(rows // br, nk),
             in_specs=[
                 pl.BlockSpec((br, bk), lambda i, k: (i, k)),

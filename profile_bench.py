@@ -18,10 +18,12 @@ def main():
     import jax
 
     import paddle_tpu as paddle
+    from paddle_tpu.device import compile_cache_dir, require_accelerator
     from paddle_tpu.jit import TrainStep
     from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLMPipe
 
-    on_tpu = jax.default_backend() not in ("cpu",)
+    on_tpu = require_accelerator("profile_bench.py")
+    compile_cache_dir()
     policy = os.environ.get("PTPU_BENCH_REMAT", "attn")
     if on_tpu:
         cfg = GPTConfig(vocab_size=32000, hidden_size=2048, num_layers=24,

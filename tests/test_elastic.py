@@ -98,6 +98,7 @@ def test_launcher_elastic_scale_in(tmp_path):
         "PATH": os.environ.get("PATH", "/usr/bin:/bin"),
         "HOME": os.environ.get("HOME", "/root"),
         "PYTHONPATH": os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "JAX_PLATFORMS": "cpu",
     }
     proc = subprocess.run(
         [sys.executable, "-m", "paddle_tpu.distributed.launch",
@@ -132,6 +133,7 @@ def test_launcher_elastic_scale_out(tmp_path):
         "PATH": os.environ.get("PATH", "/usr/bin:/bin"),
         "HOME": os.environ.get("HOME", "/root"),
         "PYTHONPATH": os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "JAX_PLATFORMS": "cpu",
     }
     proc = subprocess.run(
         [sys.executable, "-m", "paddle_tpu.distributed.launch",

@@ -195,6 +195,26 @@ class TestInt8HeadGate:
         FCE._GATE_CACHE.clear()
         assert FCE.int8_head_gate() is True
 
+    def test_gate_probe_runs_inside_an_outer_trace_and_crash_raises(
+            self, monkeypatch):
+        """On an accelerator the gate's FIRST caller is the loss's own
+        jit trace: the probe must evaluate eagerly there (it used to
+        see tracers, crash, and be turned into default-off). A probe
+        that genuinely crashes raises."""
+        FCE._GATE_CACHE.clear()
+        seen = []
+        jax.jit(lambda x: (seen.append(FCE.int8_head_gate()), x)[1])(1.0)
+        assert seen == [True]
+
+        def boom(*a, **kw):
+            raise RuntimeError("no int8 dot here")
+
+        monkeypatch.setattr(FCE, "chunked_lm_loss_arrays", boom)
+        FCE._GATE_CACHE.clear()
+        with pytest.raises(RuntimeError, match="no int8 dot here"):
+            FCE.int8_head_gate()
+        assert not FCE._GATE_CACHE
+
     def test_env_forces_both_ways(self, monkeypatch):
         monkeypatch.setenv("PTPU_INT8_HEAD", "0")
         assert FCE.int8_head_enabled() is False

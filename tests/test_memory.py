@@ -192,6 +192,40 @@ class TestPlanner:
                 factory, [pmem.Candidate(2, "names:attn_q")],
                 budget_bytes=1024, cache_path=str(tmp_path / "p.json"))
 
+    def test_only_hbm_oom_is_over_budget(self):
+        """A compile that runs out of HBM makes the candidate "over
+        budget"; any other lowering error is the program's own and
+        surfaces with the candidate named — never as "no candidate
+        fits"."""
+        class KernelRefused(RuntimeError):
+            pass
+
+        class _Step:
+            def __init__(self, exc):
+                self.exc = exc
+
+            def memory_stats(self, *avals):
+                raise self.exc
+
+        cands = [pmem.Candidate(2, "names:attn_q")]
+        with pytest.raises(KernelRefused) as ei:
+            pmem.plan_train_step(
+                lambda c: (_Step(KernelRefused("mosaic says no")), ()),
+                cands, budget_bytes=64e6, cache_path="")
+        assert "while pricing candidate" in "".join(ei.value.__notes__)
+        # scoped-VMEM exhaustion inside a kernel is not the HBM budget
+        vmem = jax.errors.JaxRuntimeError(
+            "RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem")
+        with pytest.raises(jax.errors.JaxRuntimeError):
+            pmem.plan_train_step(lambda c: (_Step(vmem), ()), cands,
+                                 budget_bytes=64e6, cache_path="")
+        hbm = jax.errors.JaxRuntimeError(
+            "RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran out "
+            "of memory in memory space hbm. Used 17.6G of 15.75G hbm.")
+        with pytest.raises(pmem.MemoryPlanError, match="compile_oom"):
+            pmem.plan_train_step(lambda c: (_Step(hbm), ()), cands,
+                                 budget_bytes=64e6, cache_path="")
+
     @pytest.mark.slow  # multi-compile planner/parity soak; tier-1 time budget (ISSUE 4): ~1110s suite vs 870s timeout
     def test_decision_cached(self, tmp_path):
         calls = []
@@ -374,6 +408,31 @@ class TestTrainStepAot:
         np.testing.assert_array_equal(
             before, np.asarray(model.decoder.wq._data))
         assert step._opt_state is None  # nothing materialized
+
+    def test_failed_aot_build_raises_without_a_second_compile(
+            self, monkeypatch):
+        """A failing step build surfaces from TrainStep as its own
+        error — it is not swallowed into a second compile through plain
+        jit dispatch."""
+        import paddle_tpu.jit as pjit
+
+        class KernelRefused(RuntimeError):
+            pass
+
+        builds = []
+
+        def refuse(jitfn, label, *args, **kw):
+            builds.append(label)
+            raise KernelRefused("mosaic says no")
+
+        monkeypatch.setattr(pjit, "timed_lower_compile", refuse)
+        factory, _, _ = _tiny_step_factory()
+        step, _ = factory(pmem.Candidate(2, "names:attn_q"))
+        ids = paddle.to_tensor(np.zeros((2, 64), np.int32))
+        labels = paddle.to_tensor(np.zeros((2, 64), np.int64))
+        with pytest.raises(KernelRefused):
+            step(ids, labels)
+        assert len(builds) == 1 and not step._execs
 
     @pytest.mark.slow  # multi-compile planner/parity soak; tier-1 time budget (ISSUE 4): ~1110s suite vs 870s timeout
     def test_memory_stats_accepts_tensors_and_avals(self):

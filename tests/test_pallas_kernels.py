@@ -122,6 +122,30 @@ def test_functional_flash_attention_uses_pallas_path():
     assert tuple(out.shape) == (2, 128, 2, 32)
 
 
+def test_sdpa_arrays_kernel_error_propagates(monkeypatch):
+    """The flash-vs-XLA choice is `_use_pallas(shape)` and nothing else:
+    an error from the kernel surfaces from sdpa_arrays, it never selects
+    the XLA path."""
+    import importlib
+
+    import paddle_tpu.ops.pallas as pallas
+
+    # (the package re-exports a function of the same name)
+    fa = importlib.import_module("paddle_tpu.nn.functional.flash_attention")
+
+    class KernelRefused(RuntimeError):
+        pass
+
+    def refuse(*a, **kw):
+        raise KernelRefused("mosaic says no")
+
+    monkeypatch.setattr(fa, "_use_pallas", lambda shape: True)
+    monkeypatch.setattr(pallas, "flash_attention", refuse)
+    q = jnp.ones((1, 128, 2, 64), jnp.float32)
+    with pytest.raises(KernelRefused):
+        fa.sdpa_arrays(q, q, q, causal=True)
+
+
 def test_flash_attention_causal_decode_offset():
     # sq != sk: queries align to the END of the key sequence (kv-cache decode)
     from paddle_tpu.ops.pallas import flash_attention

@@ -297,16 +297,29 @@ class TestEnablement:
             rep = quant.quant_gate_report()
         assert not rep["ok"] and not quant.quant_gate()
 
-    def test_crashed_probe_defaults_off_with_warning(self, monkeypatch):
+    def test_probe_runs_inside_an_outer_trace(self, monkeypatch):
+        """On an accelerator the gate's first caller is the step's own
+        jit trace (engagement resolves at trace time): the probe must
+        evaluate eagerly there instead of seeing that trace's tracers."""
+        monkeypatch.setattr(qgemm, "_GATE_CACHE", {})
+        seen = []
+        jax.jit(lambda x: (seen.append(quant.quant_gate_report()["ok"]),
+                           x)[1])(1.0)
+        assert seen == [True]
+
+    def test_crashed_probe_raises(self, monkeypatch):
+        """A probe that CRASHES is a bug to see, not a default to flip:
+        it raises (only measured drift keeps the warning + default-off),
+        and nothing is cached."""
         monkeypatch.setattr(qgemm, "_GATE_CACHE", {})
 
         def boom(tol, dtype):
             raise RuntimeError("no narrow dot here")
 
         monkeypatch.setattr(qgemm, "_gate_probe", boom)
-        with pytest.warns(RuntimeWarning, match="crashed"):
-            rep = quant.quant_gate_report()
-        assert not rep["ok"] and rep["loss_rel_err"] == float("inf")
+        with pytest.raises(RuntimeError, match="no narrow dot here"):
+            quant.quant_gate_report()
+        assert not qgemm._GATE_CACHE
 
     def test_dtype_resolution(self, monkeypatch):
         monkeypatch.setenv("PTPU_QUANT_DTYPE", "int8")

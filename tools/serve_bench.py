@@ -41,6 +41,36 @@ sys.path.insert(0, os.getcwd())
 import numpy as np
 
 
+def serving_sizes(on_tpu):
+    """The decoder and engine geometry of the serving bench:
+    ``(config kwargs, sizes)``. On TPU a 16-layer h=2048 bf16 decoder
+    behind a 16-slot engine; the tiny CPU smoke otherwise."""
+    if on_tpu:
+        cfg = dict(vocab_size=32000, hidden_size=2048, num_layers=16,
+                   num_heads=16, max_seq_len=1024, dropout=0.0)
+        sizes = dict(requests=2000, prompt_lens=(64, 128, 256, 512),
+                     max_new=64, page=64, slots=16, chunk=128,
+                     max_seq=1024, replicas=[1, 4])
+    else:
+        cfg = dict(vocab_size=256, hidden_size=64, num_layers=2,
+                   num_heads=4, num_kv_heads=2, max_seq_len=128,
+                   dropout=0.0)
+        sizes = dict(requests=96, prompt_lens=(6, 10, 14, 20),
+                     max_new=8, page=8, slots=4, chunk=8, max_seq=64,
+                     replicas=[1, 2])
+    return cfg, sizes
+
+
+def build_decoder(cfg_kw, seed, bf16):
+    """Seeded random-weight decoder (bf16 parameters on the TPU), built
+    the one way a fleet worker builds it from its spec."""
+    from paddle_tpu.inference.fleet.cluster import (build_model_from_spec,
+                                                    make_model_spec)
+
+    return build_model_from_spec(make_model_spec(
+        cfg_kw, seed=seed, dtype="bfloat16" if bf16 else None))
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(
         description="fleet serving soak benchmark (docs/SERVING.md)")
@@ -146,29 +176,24 @@ def main(argv=None):
                     "sub-blocks (docs/TELEMETRY.md)")
     args = ap.parse_args(argv)
 
-    import jax
-
-    import paddle_tpu as paddle
+    from paddle_tpu.device import (compile_cache_dir, cpu_requested,
+                                   device_record, require_accelerator)
     from paddle_tpu.inference.fleet import build_workload, soak_block
-    from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+    from paddle_tpu.models.llama import LlamaConfig
 
-    on_tpu = jax.default_backend() not in ("cpu",)
-    if on_tpu:
-        cfg = LlamaConfig(vocab_size=32000, hidden_size=2048,
-                          num_layers=16, num_heads=16, max_seq_len=1024,
-                          dropout=0.0)
-        requests = args.requests or 2000
-        prompt_lens = (64, 128, 256, 512)
-        max_new, page, slots, chunk, max_seq = 64, 64, 16, 128, 1024
-        replica_counts = args.replicas or [1, 4]
-    else:
-        cfg = LlamaConfig(vocab_size=256, hidden_size=64, num_layers=2,
-                          num_heads=4, num_kv_heads=2, max_seq_len=128,
-                          dropout=0.0)
-        requests = args.requests or 96
-        prompt_lens = (6, 10, 14, 20)
-        max_new, page, slots, chunk, max_seq = 8, 8, 4, 8, 64
-        replica_counts = args.replicas or [1, 2]
+    # --procs/--hosts: the replicas are child processes and a chip belongs
+    # to one process, so this parent must never initialise a backend —
+    # the size comes from the environment and each child builds its own
+    # model from the spec (worker.py), failing there if it finds no chip
+    multiproc = bool(args.procs or args.hosts)
+    on_tpu = (not cpu_requested() if multiproc
+              else require_accelerator("tools/serve_bench.py"))
+    cfg_kw, sz = serving_sizes(on_tpu)
+    requests = args.requests or sz["requests"]
+    prompt_lens = sz["prompt_lens"]
+    max_new, page, slots, chunk, max_seq = (
+        sz["max_new"], sz["page"], sz["slots"], sz["chunk"], sz["max_seq"])
+    replica_counts = args.replicas or sz["replicas"]
     if replica_counts[0] != 1:
         replica_counts = [1] + list(replica_counts)
     # a shared prefix longer than the drawn prompt length yields
@@ -179,25 +204,21 @@ def main(argv=None):
     need = max_prompt + max_new + (args.spec_tokens if args.spec else 0)
     if need > max_seq:
         max_seq = need
-        cfg.max_seq_len = max(cfg.max_seq_len, max_seq)
+        cfg_kw["max_seq_len"] = max(cfg_kw["max_seq_len"], max_seq)
+    cfg = LlamaConfig(**cfg_kw)  # resolves num_kv_heads et al.
 
-    paddle.seed(args.seed)
-    model = LlamaForCausalLM(cfg)
-    if on_tpu:
-        for _, p in model.named_parameters():
-            p._data = p._data.astype(jax.numpy.bfloat16)
-    draft = None
-    if args.spec:
-        dcfg = LlamaConfig(
-            vocab_size=cfg.vocab_size, hidden_size=cfg.hidden_size // 2,
-            num_layers=1, num_heads=max(1, cfg.num_heads // 2),
-            num_kv_heads=max(1, cfg.num_kv_heads // 2),
-            max_seq_len=cfg.max_seq_len, dropout=0.0)
-        paddle.seed(args.seed + 1)
-        draft = LlamaForCausalLM(dcfg)
-        if on_tpu:
-            for _, p in draft.named_parameters():
-                p._data = p._data.astype(jax.numpy.bfloat16)
+    model = draft = None
+    if not multiproc:
+        compile_cache_dir()
+        model = build_decoder(cfg_kw, args.seed, bf16=on_tpu)
+        if args.spec:
+            draft = build_decoder(
+                dict(vocab_size=cfg.vocab_size,
+                     hidden_size=cfg.hidden_size // 2,
+                     num_layers=1, num_heads=max(1, cfg.num_heads // 2),
+                     num_kv_heads=max(1, cfg.num_kv_heads // 2),
+                     max_seq_len=cfg.max_seq_len, dropout=0.0),
+                args.seed + 1, bf16=on_tpu)
 
     workload = build_workload(
         requests, args.rate or (requests * 4.0), prompt_lens,
@@ -236,11 +257,9 @@ def main(argv=None):
         he_kw.setdefault("page_size", page)
         he_kw["seed"] = args.seed
         spec = make_model_spec(
-            dict(vocab_size=cfg.vocab_size, hidden_size=cfg.hidden_size,
-                 num_layers=cfg.num_layers, num_heads=cfg.num_heads,
-                 num_kv_heads=cfg.num_kv_heads,
-                 max_seq_len=cfg.max_seq_len, dropout=0.0),
-            seed=args.seed, engine_kw=he_kw)
+            cfg_kw,
+            seed=args.seed, engine_kw=he_kw,
+            dtype="bfloat16" if on_tpu else None)
         proc = fleet_proc_enabled()
         sup = FleetSupervisor(
             spec, n, proc=proc, policy=args.policy, hosts=n_hosts,
@@ -285,11 +304,9 @@ def main(argv=None):
         pe_kw.setdefault("page_size", page)
         pe_kw["seed"] = args.seed
         spec = make_model_spec(
-            dict(vocab_size=cfg.vocab_size, hidden_size=cfg.hidden_size,
-                 num_layers=cfg.num_layers, num_heads=cfg.num_heads,
-                 num_kv_heads=cfg.num_kv_heads,
-                 max_seq_len=cfg.max_seq_len, dropout=0.0),
-            seed=args.seed, engine_kw=pe_kw)
+            cfg_kw,
+            seed=args.seed, engine_kw=pe_kw,
+            dtype="bfloat16" if on_tpu else None)
         chaos = None
         if not args.no_chaos and n > 1:
             # deterministic small fault schedule on replica 1's link:
@@ -313,6 +330,10 @@ def main(argv=None):
                 kill_replica=0,
                 window_goodput_floor=args.window_goodput_floor,
                 window_ttft_budget=args.ttft_budget)
+            # the device each CHILD serves from, from its handshake (this
+            # parent holds no backend to ask)
+            block["devices"] = [getattr(c, "device", None)
+                                for c in sup.children.values()]
         finally:
             sup.close()
         block["chaos"] = (None if chaos is None else
@@ -348,6 +369,7 @@ def main(argv=None):
             "metric": f"serve_goodput_tokens_per_sec_r{n}",
             "value": block.get("goodput_tokens_per_sec"),
             "unit": "tokens/sec",
+            "device": device_record(),
             "serving": block,
         }), flush=True)
 
@@ -409,6 +431,7 @@ def main(argv=None):
             "metric": f"serve_overload_goodput_r{n}",
             "value": block.get("goodput_tokens_per_sec"),
             "unit": "tokens/sec",
+            "device": device_record(),
             "overload": block,
         }), flush=True)
 
