@@ -104,6 +104,25 @@ class TestNoChipMeansFailure:
         assert proc.stdout.startswith("platform=cpu")
         assert '"ok"' not in proc.stdout  # no result line
 
+    def test_last_line_is_exactly_ok_and_device(self, monkeypatch, capsys):
+        """Whoever checks a chip run parses only the last stdout line and
+        refuses any key beside ``ok`` and ``device``; the per-phase record
+        rides on the ``summary:`` line before it."""
+        device = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+        monkeypatch.setattr(pdevice, "device_record", lambda: dict(device))
+        monkeypatch.setattr(pdevice, "chip_peaks", lambda: ({}, False))
+        monkeypatch.setattr(pdevice, "compile_cache_dir", lambda: "/cache")
+        for phase in ("kernel_phase", "trainer_phase", "server_phase"):
+            monkeypatch.setattr(chip_smoke, phase, lambda: {"stub": True})
+        chip_smoke.main()
+        lines = capsys.readouterr().out.strip().splitlines()
+        assert json.loads(lines[-1]) == {"ok": True, "device": device}
+        assert lines[-2].startswith("summary: ")
+        summary = json.loads(lines[-2][len("summary: "):])
+        assert summary["claim"] is None
+        assert set(summary["phases"]) == {
+            "kernels", "trainer", "server", "sharded"}
+
     def test_require_accelerator(self, monkeypatch):
         assert pdevice.require_accelerator("t") is False  # CPU was asked
         # the TPU machine's own setting keeps a CPU backend BESIDE the
