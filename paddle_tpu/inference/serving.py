@@ -410,7 +410,8 @@ class _Request:
     __slots__ = ("rid", "prompt", "generated", "length", "pages",
                  "temperature", "top_k", "top_p", "on_token",
                  "prefill_pos", "seq_tokens", "admit_seq", "swapped",
-                 "submit_t", "first_token_t", "deadline")
+                 "submit_t", "first_token_t", "deadline",
+                 "queued_t", "queue_s", "admit_t")
 
     def __init__(self, rid, prompt, temperature=0.0, top_k=0, top_p=1.0,
                  on_token=None, deadline=None):
@@ -433,6 +434,13 @@ class _Request:
         self.submit_t = time.perf_counter()   # latency telemetry anchors
         self.first_token_t = None
         self.deadline = deadline  # absolute perf_counter() cancel point
+        # TTFT anatomy, kept on the request so it holds whether or not
+        # the tracer was on at submit: when it last entered the waiting
+        # queue, the seconds it has waited there (summed over requeues)
+        # and its newest admission
+        self.queued_t = self.submit_t
+        self.queue_s = 0.0
+        self.admit_t = None
 
 
 def _sample_rows(jax, jnp, logits, temps, top_ks, top_ps, key):
@@ -558,6 +566,8 @@ class ContinuousBatchingEngine:
         self.prefill_batches = 0      # observability: admission group count
         self.preemptions = 0          # pages reclaimed from the youngest
         self._admit_counter = 0
+        self._tick = 0           # step() calls so far: names a tick in
+                                 # the trace (engine_step, first_token)
         # preempt_policy: what happens to a victim's KV state.
         #   "recompute" — drop pages, fold generated tokens into the resume
         #     prompt, rebuild KV by re-prefilling on re-admission (vLLM
@@ -1129,12 +1139,29 @@ class ContinuousBatchingEngine:
     def _emit(self, req, tok):
         if req.first_token_t is None:
             req.first_token_t = time.perf_counter()
-            _TTFT.observe(req.first_token_t - req.submit_t)
-            _trace.async_end("prefill", req.rid)
-            _trace.async_instant("first_token", req.rid)
+            ttft = req.first_token_t - req.submit_t
+            _TTFT.observe(ttft)
+            if _trace.enabled():
+                # everything of the TTFT that was not waiting for a slot
+                # is prefill, a preempted first attempt included
+                _trace.async_end("prefill", req.rid)
+                _trace.async_instant("first_token", req.rid, {
+                    "queue_ms": 1e3 * req.queue_s,
+                    "prefill_ms": 1e3 * (ttft - req.queue_s),
+                    "tick": self._tick})
         req.generated.append(tok)
         if req.on_token is not None:
             req.on_token(req.rid, tok)
+
+    def _mark_admitted(self, req, kind):
+        """A request leaves the waiting queue for a slot: its wait is
+        added to what it has waited before, on the request itself."""
+        req.admit_t = time.perf_counter()
+        req.queue_s += req.admit_t - req.queued_t
+        if _trace.enabled():
+            _trace.async_end("queue", req.rid)
+            _trace.async_instant("admitted", req.rid,
+                                 {"kind": kind, "tick": self._tick})
 
     def _admit(self):
         group = []
@@ -1197,9 +1224,7 @@ class ContinuousBatchingEngine:
                         [req],
                         [(req.prompt + req.generated)[:req.length]])
                 _ADMISSIONS.inc(labels=("swap_restore",))
-                _trace.async_end("queue", req.rid)
-                _trace.async_instant("admitted", req.rid,
-                                     {"kind": "swap_restore"})
+                self._mark_admitted(req, "swap_restore")
                 if req.first_token_t is None:
                     # a mid-prefill swap victim resumes its prefill
                     # phase here — re-open the span so the restore-to-
@@ -1248,8 +1273,7 @@ class ContinuousBatchingEngine:
             self._admit_counter += 1
             self._slots[i] = req
             _ADMISSIONS.inc(labels=("prefill",))
-            _trace.async_end("queue", req.rid)
-            _trace.async_instant("admitted", req.rid, {"kind": "prefill"})
+            self._mark_admitted(req, "prefill")
             if req.first_token_t is None:
                 _trace.async_begin(
                     "prefill", req.rid,
@@ -1329,45 +1353,52 @@ class ContinuousBatchingEngine:
         last = x[jnp.arange(B), last_rows]                   # [B, H]
         return _rms_pure(last, weights["fnorm"]), kc, vc
 
-    def _prefill_tick(self):
+    def _prefill_tick(self, span):
         """Chunked prefill: advance EVERY prefilling slot by up to
         `prefill_chunk` prompt tokens in one jitted batched pass, so
         running requests keep decoding every tick while long prompts fill
         incrementally (the reference serving stack's chunked-prefill /
         mixed-batch scheduling over block_multihead_attention; r3's
         eager per-request loop paid the per-dispatch host cost per layer
-        per request)."""
+        per request). ``span`` is the tick's ``prefill_tick`` span: a
+        pass that launches annotates it with how many of the positions
+        it computes are real."""
         jnp = self._jnp
         reqs = [r for r in self._slots
                 if r is not None and r.prefill_pos < len(r.seq_tokens)]
         if not reqs:
             return
         B, c = self.max_slots, self.prefill_chunk
-        # brownout L3: a live chunk cap shrinks the per-tick prefill
-        # token budget WITHOUT recompiling — the jitted pass keeps its
-        # [B, c] shapes and simply sees fewer valid tokens per row
-        c_eff = (c if self.prefill_chunk_cap is None
-                 else max(1, min(c, self.prefill_chunk_cap)))
-        ids_np = np.zeros((B, c), np.int32)
-        pos0 = np.zeros(B, np.int32)
-        nvalid = np.zeros(B, np.int32)
-        tok_pages = np.full((B, c), self._trash_page, np.int32)
-        offs = np.zeros((B, c), np.int32)
-        hist = np.zeros((B, self.pages_per_seq), np.int32)
-        for i, r in enumerate(reqs):
-            pos = r.prefill_pos
-            n = min(c_eff, len(r.seq_tokens) - pos)
-            ids_np[i, :n] = r.seq_tokens[pos:pos + n]
-            pos0[i], nvalid[i] = pos, n
-            pages = np.asarray(r.pages, np.int64)
-            ap = np.arange(pos, pos + n)
-            tok_pages[i, :n] = pages[ap // self.page]
-            offs[i, :n] = ap % self.page
-            hist[i, :len(r.pages)] = r.pages[:self.pages_per_seq]
-        last, self.kc, self.vc = self._prefill_jit(
-            self._weights, jnp.asarray(ids_np), jnp.asarray(pos0),
-            jnp.asarray(nvalid), jnp.asarray(tok_pages), jnp.asarray(offs),
-            jnp.asarray(hist), self.kc, self.vc)
+        with _trace.span("prefill_build", cat="serve"):
+            # brownout L3: a live chunk cap shrinks the per-tick prefill
+            # token budget WITHOUT recompiling — the jitted pass keeps
+            # its [B, c] shapes and simply sees fewer valid tokens per row
+            c_eff = (c if self.prefill_chunk_cap is None
+                     else max(1, min(c, self.prefill_chunk_cap)))
+            ids_np = np.zeros((B, c), np.int32)
+            pos0 = np.zeros(B, np.int32)
+            nvalid = np.zeros(B, np.int32)
+            tok_pages = np.full((B, c), self._trash_page, np.int32)
+            offs = np.zeros((B, c), np.int32)
+            hist = np.zeros((B, self.pages_per_seq), np.int32)
+            for i, r in enumerate(reqs):
+                pos = r.prefill_pos
+                n = min(c_eff, len(r.seq_tokens) - pos)
+                ids_np[i, :n] = r.seq_tokens[pos:pos + n]
+                pos0[i], nvalid[i] = pos, n
+                pages = np.asarray(r.pages, np.int64)
+                ap = np.arange(pos, pos + n)
+                tok_pages[i, :n] = pages[ap // self.page]
+                offs[i, :n] = ap % self.page
+                hist[i, :len(r.pages)] = r.pages[:self.pages_per_seq]
+        if _trace.enabled():
+            span.annotate(rows=len(reqs), valid_tokens=int(nvalid.sum()),
+                          computed_tokens=B * c)
+        with _trace.span("prefill_launch", cat="serve"):
+            last, self.kc, self.vc = self._prefill_jit(
+                self._weights, jnp.asarray(ids_np), jnp.asarray(pos0),
+                jnp.asarray(nvalid), jnp.asarray(tok_pages),
+                jnp.asarray(offs), jnp.asarray(hist), self.kc, self.vc)
         self.prefill_chunk_steps += 1
         completed = []
         for i, r in enumerate(reqs):
@@ -1375,8 +1406,9 @@ class ContinuousBatchingEngine:
             if r.prefill_pos == len(r.seq_tokens):
                 completed.append((i, r))
         if completed:
-            rows = last[jnp.asarray([i for i, _ in completed])]
-            toks = self._head_tokens(rows, [r for _, r in completed])
+            with _trace.span("first_token_fetch", cat="serve"):
+                rows = last[jnp.asarray([i for i, _ in completed])]
+                toks = self._head_tokens(rows, [r for _, r in completed])
             for (i, r), tok in zip(completed, toks):
                 self.prefills_completed += 1
                 r.length = len(r.seq_tokens)
@@ -1575,6 +1607,7 @@ class ContinuousBatchingEngine:
             r.length = 0
         self._slots[slot_idx] = None
         self._waiting.appendleft(r)
+        r.queued_t = time.perf_counter()
         self.preemptions += 1
         _PREEMPTIONS.inc(labels=(self.preempt_policy,))
         if r.first_token_t is None:
@@ -1646,33 +1679,42 @@ class ContinuousBatchingEngine:
     def _retire(self, req: _Request):
         _REQ_LATENCY.observe(time.perf_counter() - req.submit_t)
         self._release_pages(req, register=True)
-        with _trace.span("detokenize", attrs={"rid": req.rid},
-                         cat="serve"):
-            out = req.prompt + req.generated
-        _trace.async_end("request", req.rid,
-                         {"generated_tokens": len(req.generated)})
-        return out
+        if _trace.enabled():
+            _trace.async_end("request", req.rid,
+                             {"generated_tokens": len(req.generated)})
+        return req.prompt + req.generated
 
     def step(self):
         """Admit + one batched decode tick. Returns {rid: full_ids} for
-        requests finishing THIS tick."""
+        requests finishing THIS tick. With the tracer on, the tick is
+        one ``engine_step`` span whose children are its phases
+        (docs/TELEMETRY.md Tracing)."""
+        self._tick += 1
+        with _trace.span("engine_step",
+                         {"tick": self._tick} if _trace.enabled() else None,
+                         cat="serve"):
+            return self._step()
+
+    def _step(self):
         jax, jnp = self._jax, self._jnp
         newly = {}
-        # deadlines sweep FIRST: an expired request must not occupy a
-        # slot (or pages) for even one more tick
-        self._sweep_deadlines()
-        # retire next: a finishing slot frees pages and a slot for this
-        # very tick's admissions
-        for i, r in enumerate(list(self._slots)):
-            if r is not None and self._finished(r):
-                newly[r.rid] = self._retire(r)
-                self._slots[i] = None
+        with _trace.span("retire", cat="serve"):
+            # deadlines sweep FIRST: an expired request must not occupy
+            # a slot (or pages) for even one more tick
+            self._sweep_deadlines()
+            # retire next: a finishing slot frees pages and a slot for
+            # this very tick's admissions
+            for i, r in enumerate(list(self._slots)):
+                if r is not None and self._finished(r):
+                    newly[r.rid] = self._retire(r)
+                    self._slots[i] = None
         with _trace.span("admission", cat="serve"):
             self._admit()
         if self.prefill_chunk is not None:
-            with _trace.span("prefill_tick", cat="serve"):
-                self._prefill_tick()
-        self._grow_pages()
+            with _trace.span("prefill_tick", cat="serve") as span:
+                self._prefill_tick(span)
+        with _trace.span("grow_pages", cat="serve"):
+            self._grow_pages()
         # a request that hit max_new/eos at prefill completion THIS
         # tick must not decode once more before next tick's retire —
         # the off-by-one emitted max_new+1 tokens (and a token PAST
@@ -1702,24 +1744,34 @@ class ContinuousBatchingEngine:
             return newly
         if self._draft is not None:
             _SPEC_TICKS.inc(labels=("fallback",))
-        # fixed-width batch: pad with slot 0's state (results discarded)
-        pad_to = self.max_slots
-        rows = [r for _, r in live] + [live[0][1]] * (pad_to - len(live))
-        tokens = jnp.asarray([r.generated[-1] for r in rows], jnp.int32)
-        lens = jnp.asarray([r.length for r in rows], jnp.int32)
-        tables = self._table_rows(rows)
-        temps = jnp.asarray([r.temperature for r in rows], jnp.float32)
-        top_ks = jnp.asarray([r.top_k for r in rows], jnp.int32)
-        top_ps = jnp.asarray([r.top_p for r in rows], jnp.float32)
-        self._key, sub = jax.random.split(self._key)
+        with _trace.span("decode_build", cat="serve"):
+            # fixed-width batch: pad with slot 0's state (results
+            # discarded)
+            pad_to = self.max_slots
+            rows = ([r for _, r in live]
+                    + [live[0][1]] * (pad_to - len(live)))
+            host = (
+                np.asarray([r.generated[-1] for r in rows], np.int32),
+                np.asarray([r.length for r in rows], np.int32),
+                self._table_rows(rows),
+                np.asarray([r.temperature for r in rows], np.float32),
+                np.asarray([r.top_k for r in rows], np.int32),
+                np.asarray([r.top_p for r in rows], np.float32))
+        with _trace.span("decode_upload", cat="serve"):
+            tokens, lens, tables, temps, top_ks, top_ps = (
+                jnp.asarray(a) for a in host)
+            self._key, sub = jax.random.split(self._key)
         with _trace.span("decode_tick",
-                         attrs={"live": len(live)}, cat="serve"):
-            nxt, self.kc, self.vc = self._decode_jit(
-                self._weights, tokens, lens, tables, self.kc,
-                self.vc, temps, top_ks, top_ps, sub, do_sample)
+                         {"live": len(live)} if _trace.enabled() else None,
+                         cat="serve"):
+            with _trace.span("decode_launch", cat="serve"):
+                nxt, self.kc, self.vc = self._decode_jit(
+                    self._weights, tokens, lens, tables, self.kc,
+                    self.vc, temps, top_ks, top_ps, sub, do_sample)
             # the host fetch is the tick's real sync point — inside the
-            # span so decode wall time includes device work
-            nxt = np.asarray(nxt)
+            # decode_tick span so its wall time includes device work
+            with _trace.span("decode_fetch", cat="serve"):
+                nxt = np.asarray(nxt)
         if self._draft is not None:
             # fallback tick under a draft: mirror the carry token into
             # the draft's KV (proposal discarded) so the draft cache
@@ -1727,19 +1779,20 @@ class ContinuousBatchingEngine:
             # a permanently stale draft row and speculative acceptance
             # silently collapses once greedy ticks resume
             self._draft.catch_up(tokens, lens, tables)
-        for j, (i, r) in enumerate(live):
-            r.length += 1
-            self._emit(r, int(nxt[j]))
+        with _trace.span("emit", cat="serve"):
+            for j, (i, r) in enumerate(live):
+                r.length += 1
+                self._emit(r, int(nxt[j]))
         return newly
 
     def _table_rows(self, rows):
         """Fixed-shape [B, pages_per_seq] page tables (zero-padded; the
-        kernels clamp + length-mask padded entries)."""
+        kernels clamp + length-mask padded entries), on the host."""
         table_rows = []
         for r in rows:
             row = list(r.pages) + [0] * (self.pages_per_seq - len(r.pages))
             table_rows.append(row[: self.pages_per_seq])
-        return self._jnp.asarray(np.asarray(table_rows, np.int32))
+        return np.asarray(table_rows, np.int32)
 
     def _spec_tick(self, live):
         """Draft-model speculative decode tick (docs/SERVING.md): the
@@ -1754,7 +1807,7 @@ class ContinuousBatchingEngine:
         rows = [r for _, r in live] + [live[0][1]] * (pad_to - len(live))
         lens_np = np.asarray([r.length for r in rows], np.int32)
         lens = jnp.asarray(lens_np)
-        tables = self._table_rows(rows)
+        tables = jnp.asarray(self._table_rows(rows))
 
         def ctx_tok(r, i):
             # context token i without materializing prompt+generated
@@ -1872,6 +1925,7 @@ class ContinuousBatchingEngine:
         must share the page geometry (page_size, pages_per_seq) and KV
         mode; the disagg wrapper enforces this."""
         self._next_rid = max(self._next_rid, req.rid + 1)
+        req.queued_t = time.perf_counter()
         self._waiting.append(req)
 
     def export_prefix_pages(self, max_pages=None):

@@ -117,11 +117,12 @@ def _memory_record(compiled):
 
 def _traced_dispatch(ex, label, cost, op_args):
     """Run one compiled dispatch, recording a ``dispatch`` span with the
-    program's cost-analysis attrs when tracing is on: flops, bytes, the
-    roofline device-seconds estimate, the per-call MFU estimate
-    (flops / wall / peak) and host_gap = wall − device estimate (async
-    dispatch can legitimately clamp it to 0). Plain call when the
-    tracer is disabled — the hot path pays one attribute check."""
+    program's cost-analysis attrs when tracing is on: flops, bytes and
+    the roofline device-seconds estimate. Under async dispatch the
+    span's wall time is enqueue time, so nothing per call is derived
+    from it (``last_dispatch_cost()`` over a step's wall is the MFU
+    figure). Plain call when the tracer is disabled — the hot path pays
+    one attribute check."""
     tr = _telemetry.trace
     if not tr.enabled():
         return ex(*op_args)
@@ -130,20 +131,9 @@ def _traced_dispatch(ex, label, cost, op_args):
     dt = _time.perf_counter() - t0
     attrs = {"function": label}
     if cost:
-        dev = cost["device_seconds_est"]
         attrs.update(
             flops=cost["flops"], bytes_accessed=cost["bytes_accessed"],
-            device_seconds_est=round(dev, 6),
-            host_gap_seconds=round(max(0.0, dt - dev), 6))
-        # per-call MFU only when the wall time plausibly COVERED the
-        # device work (dt >= roofline estimate): under async dispatch
-        # the call returns in enqueue time and flops/wall would
-        # overstate MFU by orders of magnitude — exactly on the TPU
-        # runs the attr targets. Those runs read the per-STEP cost_mfu
-        # in the bench anatomy block instead.
-        if dt >= dev > 0.0 and not cost["peak_model_placeholder"]:
-            attrs["mfu_est"] = round(
-                cost["flops"] / (dt * cost["peak_flops"]), 4)
+            device_seconds_est=round(cost["device_seconds_est"], 6))
     tr.complete("dispatch", t0, dt, attrs, cat="jit")
     return out
 
@@ -655,12 +645,6 @@ def _step_update_tail(opt, clip, reg, params, grads, loss, new_buffers,
     finite = jnp.isfinite(loss32) & jnp.isfinite(gsumsq)
     from ..nn.clip import ClipGradByGlobalNorm, ClipGradByNorm, ClipGradByValue
 
-    # trace-phase anatomy: this function runs under jax tracing (once
-    # per program build), so these spans decompose the jit:trace phase
-    # of a build — they never fire per executed step
-    _tr = _telemetry.trace
-    _tr_on = _tr.enabled()
-    _t_clip = _time.perf_counter() if _tr_on else 0.0
     if isinstance(clip, ClipGradByGlobalNorm):
         grads = _functional_clip_global_norm(grads, clip.clip_norm,
                                              gnorm=gnorm)
@@ -678,16 +662,8 @@ def _step_update_tail(opt, clip, reg, params, grads, loss, new_buffers,
             return (g * jnp.minimum(c / jnp.maximum(n, c), 1.0)).astype(g.dtype)
 
         grads = tree_util.tree_map(_clip_one, grads)
-    if _tr_on:
-        _t_upd = _time.perf_counter()
-        _tr.complete("trace:grad_clip", _t_clip, _t_upd - _t_clip,
-                     cat="jit")
     new_params, new_opt_state = opt.functional_update(params, grads,
                                                       opt_state, lr)
-    if _tr_on:
-        _t_guard = _time.perf_counter()
-        _tr.complete("trace:opt_update", _t_upd, _t_guard - _t_upd,
-                     cat="jit")
     # in-graph skip (StepGuard): a nonfinite or above-threshold step
     # keeps the pre-step param/slot/buffer trees. select on a true
     # predicate returns the update bytes unchanged, and the pre-step
@@ -704,9 +680,6 @@ def _step_update_tail(opt, clip, reg, params, grads, loss, new_buffers,
                    for n in new_buffers}
     health = jnp.stack([finite.astype(jnp.float32), gnorm, loss32,
                         ok.astype(jnp.float32)])
-    if _tr_on:
-        _tr.complete("trace:guard_select", _t_guard,
-                     _time.perf_counter() - _t_guard, cat="jit")
     return loss, new_params, new_buffers, new_opt_state, health
 
 

@@ -23,21 +23,34 @@ Design constraints (same discipline as the registry):
 - **Bounded.** Per-thread ring capacity (``PTPU_TRACE_BUFFER``, default
   65536 events); past it the oldest events drop and are counted.
 - **Pure stdlib.** No jax/numpy imports; span attrs are caller-owned
-  dicts serialized with ``default=str``.
+  dicts serialized with ``default=str``. (``enable()`` arms the jax
+  compile listener of :mod:`.watchdog`, which imports ``jax.monitoring``
+  itself and does nothing where jax is missing.)
+- **A heartbeat while enabled.** One daemon thread wakes every 20 ms and
+  keeps how late each wake-up was (``beats()``); a beat over 50 ms late
+  also records a ``host_stall`` instant. It is the host-stall detector
+  of docs/TELEMETRY.md: started by ``enable()``, stopped by
+  ``disable()``, never alive while the tracer was never enabled.
 
 Span-name / attrs contract and the bench ``"anatomy"`` schema:
 docs/TELEMETRY.md (Tracing section).
 """
 from __future__ import annotations
 
+import collections
 import functools
 import json
 import os
 import threading
 import time
 
+try:
+    import resource
+except ImportError:  # not a POSIX host: the stall deltas go without it
+    resource = None
+
 __all__ = [
-    "enable", "disable", "enabled", "reset",
+    "enable", "disable", "enabled", "reset", "epoch", "beats",
     "span", "traced", "instant", "complete",
     "async_begin", "async_end", "async_instant",
     "events", "live_spans", "to_perfetto", "dump_jsonl",
@@ -132,6 +145,80 @@ class _Span:
         return False
 
 
+class _Heartbeat:
+    """The tracer's host-stall detector: wakes every ``PERIOD`` seconds
+    and keeps (scheduled time, lateness) of each wake-up in the tracer's
+    bounded deque of beats, never as an ``X`` event, so span and gap
+    attribution do not see it. A beat more than ``STALL`` late also
+    records a ``host_stall`` instant with what the process did since the
+    previous beat. ``clock`` and ``wait`` (timeout -> True to stop) are
+    injectable so a test can plant a stall without waiting for one."""
+
+    PERIOD = 0.020
+    STALL = 0.050
+
+    def __init__(self, tracer, clock=time.perf_counter, wait=None):
+        self._tracer = tracer
+        self._clock = clock
+        self._stop = threading.Event()
+        self._wait = wait or self._stop.wait
+        self.thread = None
+
+    def start(self):
+        self.thread = threading.Thread(
+            target=self.run, name="ptpu-trace-heartbeat", daemon=True)
+        self.thread.start()
+
+    def stop(self):
+        self._stop.set()
+        t = self.thread
+        if t is not None and t is not threading.current_thread():
+            t.join()
+
+    @staticmethod
+    def _usage():
+        """(process CPU s, main thread's run-delay s or None, involuntary
+        switches, major faults): what a late beat is read beside."""
+        delay = None
+        try:
+            with open("/proc/self/schedstat", "rb") as f:
+                delay = int(f.read().split()[1]) / 1e9
+        except (OSError, IndexError, ValueError):
+            pass
+        if resource is None:
+            return time.process_time(), delay, None, None
+        ru = resource.getrusage(resource.RUSAGE_SELF)
+        return time.process_time(), delay, ru.ru_nivcsw, ru.ru_majflt
+
+    def run(self):
+        clock, period = self._clock, self.PERIOD
+        due = clock() + period
+        prev = self._usage()
+        while not self._wait(max(0.0, due - clock())):
+            now = clock()
+            late = now - due
+            self._tracer._beats.append((due, late))
+            cur = self._usage()
+            if late > self.STALL:
+                self._tracer.instant("host_stall", _stall_attrs(
+                    late, prev, cur), cat="host")
+            prev = cur
+            due += period
+            if due < now:   # beats missed while stalled are not replayed
+                due = now + period
+
+
+def _stall_attrs(late, prev, cur):
+    def delta(i, scale=1.0):
+        if prev[i] is None or cur[i] is None:
+            return None
+        return round((cur[i] - prev[i]) * scale, 3)
+
+    return {"late_ms": round(late * 1e3, 3), "cpu_ms": delta(0, 1e3),
+            "run_delay_ms": delta(1, 1e3), "invol_switches": delta(2),
+            "major_faults": delta(3)}
+
+
 class SpanTracer:
     """One process-local tracer instance (module-level ``_TRACER``)."""
 
@@ -148,6 +235,9 @@ class SpanTracer:
         self._epoch_ts = time.time()
         self._registry = None    # bound by telemetry/__init__
         self._mirror_hist = None
+        self._heartbeat = None   # alive only while enabled
+        # (due, late) of each heartbeat: ~22 minutes at 50 a second
+        self._beats = collections.deque(maxlen=65536)
 
     # -- wiring -------------------------------------------------------------
     def bind_registry(self, registry):
@@ -184,6 +274,28 @@ class SpanTracer:
         self.enabled = False
         return self
 
+    def start_heartbeat(self):
+        hb = self._heartbeat
+        if hb is None or not hb.thread.is_alive():  # dead: a forked child
+            self._heartbeat = _Heartbeat(self)
+            self._heartbeat.start()
+
+    def stop_heartbeat(self):
+        hb, self._heartbeat = self._heartbeat, None
+        if hb is not None:
+            hb.stop()
+
+    def epoch(self):
+        """The ``time.perf_counter()`` value event ``ts`` (and beats)
+        are relative to; ``reset()`` re-zeros it."""
+        return self._epoch
+
+    def beats(self):
+        """[(ts, late)]: when each heartbeat was due, in seconds since
+        the trace epoch, and how many seconds late it woke."""
+        epoch = self._epoch
+        return [(due - epoch, late) for due, late in list(self._beats)]
+
     def reset(self):
         """Drop every recorded event and re-zero the epoch. Live span
         stacks survive (their owners still hold the context managers)."""
@@ -197,6 +309,7 @@ class SpanTracer:
                 buf.ring = []
                 buf.head = 0
                 buf.dropped = 0
+        self._beats.clear()
         self._epoch = time.perf_counter()
         self._epoch_ts = time.time()
 
@@ -395,7 +508,7 @@ class SpanTracer:
         """Reassemble async events into per-id span trees:
         ``{id: {"name", "start", "end", "attrs", "children": [...],
         "marks": [...]}}`` — the serving request anatomy (admission →
-        queue → prefill → decode → detokenize). The root is the
+        queue → prefill → decode). The root is the
         longest-covering span per id (the engine opens "request"
         first); unclosed spans get ``end=None``."""
         per_id = {}
@@ -460,20 +573,27 @@ def _json_attrs(attrs):
 # ---------------------------------------------------------------- module API
 _TRACER = SpanTracer()
 
-if os.environ.get("PTPU_TRACE", "") not in ("", "0"):
-    _TRACER.enabled = True
-
 
 def get_tracer() -> SpanTracer:
     return _TRACER
 
 
 def enable():
-    return _TRACER.enable()
+    """Turn the process tracer on (idempotent). Also starts its
+    heartbeat (the host-stall detector) and arms the jax compile
+    listener that writes ``xla_compile`` spans."""
+    _TRACER.enable()
+    _TRACER.start_heartbeat()
+    from .watchdog import install_jax_compile_listener
+
+    install_jax_compile_listener()
+    return _TRACER
 
 
 def disable():
-    return _TRACER.disable()
+    _TRACER.disable()
+    _TRACER.stop_heartbeat()
+    return _TRACER
 
 
 def enabled() -> bool:
@@ -482,6 +602,14 @@ def enabled() -> bool:
 
 def reset():
     _TRACER.reset()
+
+
+def epoch() -> float:
+    return _TRACER.epoch()
+
+
+def beats():
+    return _TRACER.beats()
 
 
 def span(name, attrs=None, cat="phase"):
@@ -565,3 +693,7 @@ def step_anatomy(step_span="step"):
 
 def request_trees(cat="request"):
     return _TRACER.request_trees(cat)
+
+
+if os.environ.get("PTPU_TRACE", "") not in ("", "0"):
+    enable()

@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import os
 import threading
+import time
 import warnings
 
 
@@ -86,28 +87,47 @@ class RecompileWatchdog:
             self._warned.clear()
 
 
-_JAX_LISTENER_INSTALLED = [False]
+_JAX_LISTENER = {"installed": False, "registry": None, "hist": None}
+
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
-def install_jax_compile_listener(registry):
-    """Mirror jax's own compile events into the registry (best-effort:
-    the monitoring API and its event names vary across jax releases).
-    Registered once per process; the listener itself checks the enabled
-    flag so disable() silences it without deregistration."""
-    if _JAX_LISTENER_INSTALLED[0]:
-        return
-    _JAX_LISTENER_INSTALLED[0] = True
-    try:
-        from jax import monitoring
-
-        hist = registry.histogram(
+def install_jax_compile_listener(registry=None):
+    """Mirror jax's own compile events (best-effort: the monitoring API
+    and its event names vary across jax releases). Given a registry,
+    every compile-duration event lands in ``jax_compilation_seconds``
+    while that registry is enabled; and while the span tracer is on,
+    every backend compile jax reports, whichever program or eager op
+    caused it, is written as an ``xla_compile`` span ending at the
+    report. Registered once per process (``telemetry.enable()`` and
+    ``trace.enable()`` both arm it); the listener itself checks the
+    enabled flags, so disable() silences it without deregistration."""
+    st = _JAX_LISTENER
+    if registry is not None and st["hist"] is None:
+        st["registry"] = registry
+        st["hist"] = registry.histogram(
             "jax_compilation_seconds",
             "XLA compile wall time as reported by jax.monitoring",
             labelnames=("event",))
+    if st["installed"]:
+        return
+    st["installed"] = True
+    try:
+        from jax import monitoring
+
+        from . import trace
+
+        tracer = trace.get_tracer()
 
         def _on_duration(event, duration, **kw):
-            if registry.enabled and "compil" in event:
-                hist.observe(duration, labels=(event.strip("/"),))
+            reg = st["registry"]
+            if reg is not None and reg.enabled and "compil" in event:
+                st["hist"].observe(duration, labels=(event.strip("/"),))
+            if tracer.enabled and event == BACKEND_COMPILE_EVENT:
+                tracer.complete(
+                    "xla_compile", time.perf_counter() - duration, duration,
+                    {"event": event, "seconds": round(duration, 6)},
+                    cat="jit")
 
         monitoring.register_event_duration_secs_listener(_on_duration)
     except Exception:  # noqa: BLE001 — telemetry must never break startup

@@ -146,8 +146,11 @@ def request_stats(events):
             for name, ds in durs.items()}, unclosed
 
 
-_SERVE_SPANS = ("admission", "prefill_group", "prefill_tick",
-                "decode_tick", "spec_draft", "spec_verify", "detokenize")
+_SERVE_SPANS = ("engine_step", "retire", "admission", "prefill_group",
+                "prefill_tick", "prefill_build", "prefill_launch",
+                "first_token_fetch", "grow_pages", "decode_build",
+                "decode_upload", "decode_tick", "decode_launch",
+                "decode_fetch", "emit", "spec_draft", "spec_verify")
 _SERVE_ASYNC = ("request", "route", "queue", "prefill")
 
 
