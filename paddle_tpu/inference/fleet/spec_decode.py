@@ -71,8 +71,9 @@ class DraftRunner:
     def _run_layers(self, x, layer_fn, kc, vc):
         """Layer walk over the DRAFT stack through the engine's shared
         :func:`serving._run_layer_stack` walker (one scan/unroll
-        discipline for target and draft; cold start flat in draft depth
-        too)."""
+        discipline for target and draft, the draft's pool carried whole
+        and written in place like the target's; cold start flat in
+        draft depth too)."""
         from ..serving import _run_layer_stack
 
         return _run_layer_stack(self.engine._scan_layers,
@@ -109,31 +110,23 @@ class DraftRunner:
         jnp = self._jnp
         from ...models.gpt import _rms_pure
         from ...ops.pallas.decode_attention import paged_attention
+        from ..serving import _kv_write_run
 
-        eng = self.engine
-        b, C = toks.shape
+        C = toks.shape[1]
         x = weights["embed"][toks]                       # [B, C, H]
-        pos = pos0[:, None] + jnp.arange(C)[None, :]
-        page_idx = jnp.clip(pos // eng.page, 0, eng.pages_per_seq - 1)
-        page_ids = jnp.take_along_axis(tables, page_idx, 1)
-        offs = pos % eng.page
 
-        def layer_fn(lp, x, kc_l, vc_l):
-            new = {}
-
+        def layer_fn(lp, li, x, kc, vc):
             def attend(q, k, v):
-                kl = kc_l.at[:, page_ids, offs, :].set(
-                    jnp.transpose(k, (2, 0, 1, 3)).astype(kc_l.dtype))
-                vl = vc_l.at[:, page_ids, offs, :].set(
-                    jnp.transpose(v, (2, 0, 1, 3)).astype(vc_l.dtype))
-                new["k"], new["v"] = kl, vl
+                nonlocal kc, vc
+                kc = _kv_write_run(kc, li, tables, pos0, C, k)
+                vc = _kv_write_run(vc, li, tables, pos0, C, v)
                 return jnp.stack(
-                    [paged_attention(q[:, i], kl, vl, tables,
-                                     pos0 + i + 1) for i in range(C)],
-                    1)                                   # [B, C, Hq, D]
+                    [paged_attention(q[:, i], kc, vc, tables,
+                                     pos0 + i + 1, layer=li)
+                     for i in range(C)], 1)              # [B, C, Hq, D]
 
             x = self._layer_forward(lp, x, pos0, attend)
-            return x, new["k"], new["v"]
+            return x, kc, vc
 
         x, kc, vc = self._run_layers(x, layer_fn, kc, vc)
         last = _rms_pure(x[:, -1], weights["fnorm"])     # [B, H]
@@ -167,6 +160,8 @@ class DraftRunner:
         bitwise aligned with the target's (the acceptance-rate
         guarantee for self-drafting tests)."""
         jax, jnp = self._jax, self._jnp
+        from ..serving import _kv_write_run
+
         eng = self.engine
         w = self._weights
         B = len(reqs)
@@ -181,14 +176,8 @@ class DraftRunner:
         rep = self.cfg.num_heads // self.hkv
         mask = jnp.tril(jnp.ones((S, S), bool))
 
-        rows = np.concatenate([np.full(n, i) for i, n in enumerate(lens)])
-        poss = np.concatenate([np.arange(n) for n in lens])
-        tok_pages = np.concatenate(
-            [np.asarray(r.pages, np.int64)[np.arange(n) // eng.page]
-             for r, n in zip(reqs, lens)])
-        offs = jnp.asarray(poss % eng.page)
-        rows_j, poss_j = jnp.asarray(rows), jnp.asarray(poss)
-        tok_pages = jnp.asarray(tok_pages)
+        tables = jnp.asarray(eng._table_rows(reqs))
+        nvalid = jnp.asarray(lens, jnp.int32)
 
         for li in range(self.cfg.num_layers):
             def attend(q, k, v, li=li):
@@ -201,12 +190,10 @@ class DraftRunner:
                 probs = jax.nn.softmax(logits, -1)
                 o = jnp.einsum("bhts,bshd->bthd", probs,
                                cv.astype(jnp.float32)).astype(q.dtype)
-                # scalar li + separated advanced indices: broadcast
-                # dims move to the FRONT, so the payload is [N, Hkv, D]
-                self.kc = self.kc.at[li, :, tok_pages, offs, :].set(
-                    k[rows_j, poss_j].astype(self.kc.dtype))
-                self.vc = self.vc.at[li, :, tok_pages, offs, :].set(
-                    v[rows_j, poss_j].astype(self.vc.dtype))
+                self.kc = _kv_write_run(self.kc, li, tables, pos0,
+                                        nvalid, k)
+                self.vc = _kv_write_run(self.vc, li, tables, pos0,
+                                        nvalid, v)
                 return o
 
             x = self._layer_forward(
@@ -224,11 +211,16 @@ class DraftRunner:
     def warmup(self, tables):
         """Compile the window widths serving will actually use (C=2
         always; C=1 only when spec_tokens >= 2) on dummy operands —
-        writes land in the engine's scratch page, and the compile time
-        lands in the engine's gated cold-start number."""
+        writes land in the engine's scratch page, the compile time
+        lands in the engine's gated cold-start number, and each width's
+        bytes in the engine's ``program_bytes`` (``draft_window_c2``,
+        ``draft_window_c1``)."""
         jnp = self._jnp
         b = self.engine.max_slots
-        zeros = np.zeros((b,), np.int32)
-        lens = jnp.ones((b,), jnp.int32)
-        self.propose(zeros, zeros, lens, tables,
-                     min(self.engine.spec_tokens, 2))
+        pos0 = jnp.zeros((b,), jnp.int32)
+        for C in (2, 1)[:min(self.engine.spec_tokens, 2)]:
+            d, self.kc, self.vc = self.engine._warm(
+                f"draft_window_c{C}", self._window_jit, self._weights,
+                jnp.zeros((b, C), jnp.int32), pos0, tables,
+                self.kc, self.vc)
+            np.asarray(d)

@@ -103,6 +103,12 @@ _WEIGHT_BYTES = _telemetry.gauge(
     "resident packed decode-weight bytes per storage dtype "
     "(docs/QUANT.md: int8-packed replicas report the reduced footprint)",
     labelnames=("dtype",))
+_PROGRAM_TEMP_BYTES = _telemetry.gauge(
+    "serving_program_temp_bytes",
+    "temporary device bytes of each compiled serving program, from its "
+    "memory_analysis() at warmup: under one layer's K/V slab while the "
+    "pool stays in place (docs/SERVING.md)",
+    labelnames=("program",))
 
 
 # ---------------------------------------------------------------- int8 KV
@@ -198,8 +204,13 @@ def _int8_paged_kernel_active():
 # mode) or a (codes int8 [L, Hkv, num_pages+1, page, D],
 # scales f32 [L, Hkv, num_pages+1, page, 1]) pair (int8 mode) — the
 # fp32 per-row scales ride NEXT TO the page payload, addressed by the
-# same page table. The helpers below are tuple-aware so every cache
-# consumer (decode, chunked prefill, swap, handoff) is written once.
+# same page table. That is the pool's ONE layout, and it never moves: a
+# program takes the whole pool, writes into it in place
+# (`_kv_write_run`, the one writer of rows; `_swap_scatter` restores
+# whole pages), reads pages out of it by (layer, page), and returns the
+# same buffer. No consumer slices a layer's slab out or stacks one
+# back. The helpers below are tuple-aware so every cache consumer
+# (decode, chunked prefill, swap, handoff) is written once.
 
 def _kv_map(fn, c):
     return tuple(fn(x) for x in c) if isinstance(c, tuple) else fn(c)
@@ -211,65 +222,83 @@ def _kv_map2(fn, a, b):
     return fn(a, b)
 
 
-def _kv_index(c, li):
-    """Per-layer view of a stacked cache (basic int index, axis 0)."""
-    return _kv_map(lambda x: x[li], c)
+def _kv_write_run(cache, li, tables, pos0, nvalid, vals):
+    """Write each slot's run of consecutive positions into layer ``li``
+    of the stacked cache, where it lies: slot ``b`` puts rows
+    ``vals[b, :nvalid[b]]`` (``vals`` [B, c, Hkv, D] at the compute
+    dtype; ``nvalid`` [B], or one count for every slot) at positions
+    ``pos0[b]..`` through its page table ``tables[b]``. Every writer writes such runs: a decode tick one row
+    a slot, a verify or draft window C, a prefill chunk or a whole
+    prompt up to c. ``li`` is a python int (eager prefill, the unrolled
+    walk) or the layer scan's counter; on the scan's carry, and on a
+    donated buffer, XLA performs the scatter in place.
 
-
-def _kv_stack(per_layer):
-    """Inverse of _kv_index over a list of per-layer caches."""
+    The pages a run touches are read, the new rows laid over them, and
+    the pages written back whole, one (page, D) tile-aligned window per
+    (head, page): the pool keeps the layout the kernels read, and a
+    chunk of 128 rows costs 96 page updates a head, not 128 rows' (row
+    by row the chip takes 72 ns a row, 2.4 ms a chunk write; with the
+    heads as the scatter's window XLA relays the whole pool heads-minor
+    and back, every layer). A frame page no row of the run falls in,
+    and every page of a slot with ``nvalid`` 0, goes to the pool's last
+    page, the scratch page nothing reads; positions past the table are
+    dropped. Two slots never touch one page with different rows (pages
+    are exclusively owned past a shared prefix; a padded decode row
+    repeats another slot's write exactly). int8 caches quantize each
+    row (one fp32 scale per head_dim row — the block the page table
+    addresses) at the write."""
     import jax.numpy as jnp
 
-    if isinstance(per_layer[0], tuple):
-        return tuple(jnp.stack([p[i] for p in per_layer])
-                     for i in range(len(per_layer[0])))
-    return jnp.stack(per_layer)
+    B, c = vals.shape[:2]
+    pps = tables.shape[1]
+    nvalid = jnp.reshape(jnp.asarray(nvalid), (-1, 1, 1))
 
+    def put(x, v):
+        hkv, page = x.shape[1], x.shape[3]
+        npg = (c + page - 2) // page + 1      # pages a run of c can touch
+        j0 = pos0 // page
+        lp = j0[:, None] + jnp.arange(npg)[None]                # [B, npg]
+        # frame row t of slot b is position j0*page + t: run row r
+        r = (jnp.arange(npg * page)[None]
+             - (pos0 - j0 * page)[:, None]).reshape(B, npg, page)
+        new = (r >= 0) & (r < nvalid) & (lp < pps)[..., None]
+        pid = jnp.where(new.any(-1),
+                        jnp.take_along_axis(tables, jnp.minimum(lp, pps - 1),
+                                            1),
+                        x.shape[2] - 1)
+        # three ADJACENT advanced indices: [Hkv, B, npg] pages of
+        # [page, D] each, out and back in
+        at = (li, jnp.arange(hkv)[:, None, None], pid[None])
+        rows = jnp.take_along_axis(
+            v, jnp.clip(r, 0, c - 1).reshape(B, -1, 1, 1), 1)
+        rows = jnp.moveaxis(rows, 2, 0).reshape(hkv, B, npg, page, -1)
+        return x.at[at].set(jnp.where(new[None, ..., None],
+                                      rows.astype(x.dtype), x[at]))
 
-def _kv_write(cache_l, pages, offs, vals):
-    """Scatter token rows into a PER-LAYER cache: ``pages``/``offs``
-    index arrays (any matching shape S*), ``vals`` [Hkv, *S, D] at the
-    compute dtype. int8 caches quantize each row (one fp32 scale per
-    head_dim row — the block the page table addresses) at the write."""
-    if isinstance(cache_l, tuple):
-        from ..memory import quantize_rows_int8
-
-        q, s = cache_l
-        qv, sv = quantize_rows_int8(vals)
-        return (q.at[:, pages, offs, :].set(qv),
-                s.at[:, pages, offs, :].set(sv))
-    return cache_l.at[:, pages, offs, :].set(vals.astype(cache_l.dtype))
-
-
-def _kv_write_layer(cache, li, pages, offs, vals):
-    """`_kv_write` against ONE layer of a stacked cache (the eager
-    group-prefill path, which walks layers python-side). NOTE the
-    scalar ``li`` is itself an advanced index: with the Hkv slice
-    separating it from ``pages``/``offs``, the broadcast advanced dims
-    move to the FRONT, so the update payload is [N, Hkv, D]."""
-    import jax.numpy as jnp
-
-    vals = jnp.swapaxes(vals, 0, 1)                   # [N, Hkv, D]
     if isinstance(cache, tuple):
         from ..memory import quantize_rows_int8
 
-        q, s = cache
-        qv, sv = quantize_rows_int8(vals)
-        return (q.at[li, :, pages, offs, :].set(qv),
-                s.at[li, :, pages, offs, :].set(sv))
-    return cache.at[li, :, pages, offs, :].set(vals.astype(cache.dtype))
+        return _kv_map2(put, cache, quantize_rows_int8(vals))
+    return put(cache, vals)
 
 
-def _kv_gather_rows(cache_l, idx, dtype):
-    """Gather pages by id from a PER-LAYER cache -> values at the
-    engine's logical ``dtype``. int8 caches dequantize (codes * scales)
-    on the way out; exact caches return their storage as-is."""
+def _kv_gather_rows(cache, li, idx, dtype):
+    """Gather pages by id out of layer ``li`` of the stacked cache, in
+    one indexing operation (no slab is sliced out first) ->
+    [Hkv, *idx.shape, page, D] at the engine's logical ``dtype``. int8
+    caches dequantize (codes * scales) on the way out; exact caches
+    return their storage as-is."""
     import jax.numpy as jnp
 
-    if isinstance(cache_l, tuple):
-        q, s = cache_l
-        return (q[:, idx].astype(jnp.float32) * s[:, idx]).astype(dtype)
-    return cache_l[:, idx]
+    def g(x):
+        # three ADJACENT advanced indices: the result keeps them in place
+        heads = jnp.arange(x.shape[1]).reshape((-1,) + (1,) * idx.ndim)
+        return x[li, heads, idx[None]]
+
+    if isinstance(cache, tuple):
+        q, s = cache
+        return (g(q).astype(jnp.float32) * g(s)).astype(dtype)
+    return g(cache)
 
 
 def _kv_nbytes(c):
@@ -329,30 +358,33 @@ def _weight_nbytes(weights):
 
 
 def _run_layer_stack(scan_layers, layers, x, layer_fn, kc, vc):
-    """THE scan-or-unrolled walker over a [L, ...]-stacked weight tuple
-    plus cache slabs: ``layer_fn(lp, x, kc_l, vc_l) -> (x, kc_l, vc_l)``.
-    Shared by the engine's decode/prefill programs AND the spec-decode
-    DraftRunner, so the scan carry/ys shape discipline cannot drift
-    between target and draft. Scanned: compile flat in depth (the
-    replica cold-start win); unrolled (``PTPU_SCAN_LAYERS=0``): bitwise
-    identical, compile linear in depth."""
+    """THE scan-or-unrolled walker over a [L, ...]-stacked weight tuple:
+    ``layer_fn(lp, li, x, kc, vc) -> (x, kc, vc)`` over the WHOLE
+    stacked caches. Shared by the engine's decode/prefill/verify
+    programs AND the spec-decode DraftRunner, so the pool discipline
+    cannot drift between target and draft. Scanned: the caches ride the
+    scan's CARRY beside ``x`` and only the weights and the layer
+    counter are ``xs`` (a scan cannot alias ``xs`` to ``ys``: caches
+    there cost a second pool and a slab copied in and out per layer);
+    compile flat in depth (the replica cold-start win). Unrolled
+    (``PTPU_SCAN_LAYERS=0``): ``li`` is a python int and the same
+    ``layer_fn`` runs; bitwise identical, compile linear in depth."""
     import jax
+    import jax.numpy as jnp
 
+    n = layers[0].shape[0]
     if scan_layers:
         def step(carry, per):
-            lp, kc_l, vc_l = per
-            x2, kl, vl = layer_fn(lp, carry, kc_l, vc_l)
-            return x2, (kl, vl)
+            lp, li = per
+            return layer_fn(lp, li, *carry), None
 
-        x, (kc, vc) = jax.lax.scan(step, x, (layers, kc, vc))
+        (x, kc, vc), _ = jax.lax.scan(
+            step, (x, kc, vc), (layers, jnp.arange(n, dtype=jnp.int32)))
         return x, kc, vc
-    kls, vls = [], []
-    for li in range(layers[0].shape[0]):
-        x, kl, vl = layer_fn(tuple(_layer_slice(w, li) for w in layers), x,
-                             _kv_index(kc, li), _kv_index(vc, li))
-        kls.append(kl)
-        vls.append(vl)
-    return x, _kv_stack(kls), _kv_stack(vls)
+    for li in range(n):
+        x, kc, vc = layer_fn(tuple(_layer_slice(w, li) for w in layers),
+                             li, x, kc, vc)
+    return x, kc, vc
 
 
 def _pack_weights_stacked(model):
@@ -640,7 +672,7 @@ class ContinuousBatchingEngine:
         # (VERDICT r3 item 7 — the eager per-request chunk loop paid the
         # ~2.5ms/dispatch host cost per layer per request)
         self._prefill_jit = jax.jit(self._prefill_chunk_step,
-                                    donate_argnums=(7, 8))
+                                    donate_argnums=(5, 6))
         self.prefill_chunk_steps = 0  # observability: jitted pass count
         # -- request deadlines / cancellation (docs/SERVING.md) --
         self.cancelled = {}           # rid -> reason, drained by callers
@@ -669,6 +701,10 @@ class ContinuousBatchingEngine:
         self._lookahead = (self.spec_tokens + 1 if self._draft is not None
                            else 1)
         self.build_seconds = None     # set by warmup() (cold-start gate)
+        # program name -> {"temp", "alias"} bytes, read off each compiled
+        # program's memory_analysis() at warmup(): temp under one layer's
+        # slab says no pool-sized buffer is left in the program
+        self.program_bytes = {}
         # -- brownout degradation knobs (fleet.overload, docs/SERVING.md
         # "Overload & degradation") — reversible service caps the fleet
         # brownout ladder sets under sustained pressure and restores on
@@ -824,14 +860,8 @@ class ContinuousBatchingEngine:
         rep = self.cfg.num_heads // self.hkv
         mask = jnp.tril(jnp.ones((S, S), bool))
 
-        # flattened valid (row, pos) pairs -> page/offset scatter targets
-        rows = np.concatenate([np.full(l, i) for i, l in enumerate(lens)])
-        poss = np.concatenate([np.arange(l) for l in lens])
-        tok_pages = np.concatenate(
-            [np.asarray(r.pages, np.int64)[np.arange(l) // self.page]
-             for r, l in zip(reqs, lens)])
-        offs = jnp.asarray(poss % self.page)
-        rows_j, poss_j = jnp.asarray(rows), jnp.asarray(poss)
+        tables = jnp.asarray(self._table_rows(reqs))
+        nvalid = jnp.asarray(lens, jnp.int32)
 
         def attend(li, q, k, v):
             if self.int8_kv:
@@ -855,12 +885,9 @@ class ContinuousBatchingEngine:
             probs = jax.nn.softmax(logits, -1)
             o = jnp.einsum("bhts,bshd->bthd", probs,
                            cv.astype(jnp.float32)).astype(q.dtype)
-            # scatter the group's valid k/v into the owned pages; ADJACENT
-            # advanced indices stay in place -> [Hkv, N, D]
-            kvals = jnp.swapaxes(k[rows_j, poss_j], 0, 1)
-            vvals = jnp.swapaxes(v[rows_j, poss_j], 0, 1)
-            self.kc = _kv_write_layer(self.kc, li, tok_pages, offs, kvals)
-            self.vc = _kv_write_layer(self.vc, li, tok_pages, offs, vvals)
+            # each prompt's valid k/v rows into the pages it owns
+            self.kc = _kv_write_run(self.kc, li, tables, pos0, nvalid, k)
+            self.vc = _kv_write_run(self.vc, li, tables, pos0, nvalid, v)
             return o
 
         for li in range(self.cfg.num_layers):
@@ -880,18 +907,32 @@ class ContinuousBatchingEngine:
             self._draft.prefill(reqs, [r.seq_tokens for r in reqs])
         return toks
 
-    def _run_layers(self, weights, x, layer_fn, kc, vc):
-        """Run ``layer_fn`` over every decoder layer through the shared
-        :func:`_run_layer_stack` walker (scan-over-layers per the
-        models.gpt resolver; ``PTPU_SCAN_LAYERS=0`` unrolls bitwise —
-        docs/SERVING.md)."""
+    def _run_layers(self, weights, x, pos0, kc, vc, attend):
+        """Run every decoder layer of a compiled program over the whole
+        stacked caches through the shared :func:`_run_layer_stack`
+        walker (scan-over-layers per the models.gpt resolver;
+        ``PTPU_SCAN_LAYERS=0`` unrolls bitwise — docs/SERVING.md).
+        ``attend(li, q, k, v, kc, vc) -> (o, kc, vc)`` owns the layer's
+        cache writes and its attention; the rest of the layer is
+        `_layer_forward`, shared with the eager prefill so a program's
+        numerics can never drift from prefill's."""
+        def layer_fn(lp, li, x, kc, vc):
+            def inner(li, q, k, v):
+                nonlocal kc, vc
+                o, kc, vc = attend(li, q, k, v, kc, vc)
+                return o
+
+            x = self._layer_forward(li, lp, x, pos0, inner)
+            return x, kc, vc
+
         return _run_layer_stack(self._scan_layers, weights["layers"], x,
                                 layer_fn, kc, vc)
 
-    def _paged_attend(self, q, kc_l, vc_l, tables, lens):
-        """Single-position paged attention over a PER-LAYER cache:
-        q [B, Hq, D] -> [B, Hq, D]. Exact caches take the Pallas paged
-        kernel; int8 caches take the int8-page Pallas kernel
+    def _paged_attend(self, q, kc, vc, li, tables, lens):
+        """Single-position paged attention over layer ``li`` of the
+        stacked caches: q [B, Hq, D] -> [B, Hq, D]. Exact caches take
+        the Pallas paged kernel, which reads its pages out of the pool
+        by (layer, page); int8 caches take the int8-page Pallas kernel
         (``paged_attention_int8``: (codes, scales) dequantized in VMEM
         per fetched page — the PR 12 named follow-up) when the device
         gate allows, else gather the owned pages, dequantize in HBM,
@@ -900,24 +941,22 @@ class ContinuousBatchingEngine:
         itself engages only behind the quantizer parity gate
         (``int8_kv_enabled``)."""
         jax, jnp = self._jax, self._jnp
-        if not isinstance(kc_l, tuple):
+        if not isinstance(kc, tuple):
             from ..ops.pallas.decode_attention import paged_attention
 
-            return paged_attention(q, kc_l, vc_l, tables, lens)
+            return paged_attention(q, kc, vc, tables, lens, layer=li)
         mode = _int8_paged_kernel_mode()
         if mode != "off":
             from ..ops.pallas.decode_attention import paged_attention_int8
 
-            kc, ks = kc_l
-            vc, vs = vc_l
             return paged_attention_int8(
-                q, kc, ks, vc, vs, tables, lens,
+                q, *kc, *vc, tables, lens, layer=li,
                 interpret=True if mode == "interpret" else None)
         b, hq, hd = q.shape
         dt = self._kv_dtype
         S = self.pages_per_seq * self.page
-        ck = _kv_gather_rows(kc_l, tables, dt).reshape(self.hkv, b, S, hd)
-        cv = _kv_gather_rows(vc_l, tables, dt).reshape(self.hkv, b, S, hd)
+        ck = _kv_gather_rows(kc, li, tables, dt).reshape(self.hkv, b, S, hd)
+        cv = _kv_gather_rows(vc, li, tables, dt).reshape(self.hkv, b, S, hd)
         rep = hq // self.hkv
         if rep > 1:
             ck = jnp.repeat(ck, rep, 0)
@@ -932,27 +971,6 @@ class ContinuousBatchingEngine:
         o = jnp.einsum("bhs,hbsd->bhd", probs, cv.astype(jnp.float32))
         return o.astype(q.dtype)
 
-    def _decode_layer(self, lp, x, lens, tables, page_ids, offs,
-                      kc_l, vc_l):
-        """One decoder layer of the batched decode tick (the scan
-        body): write this token's KV row, paged-attend, MLP. Shares
-        `_layer_forward` with the prefill paths so decode numerics can
-        never drift from prefill's."""
-        jnp = self._jnp
-        new = {}
-
-        def attend(li, q, k, v):
-            kl = _kv_write(kc_l, page_ids, offs,
-                           jnp.swapaxes(k[:, 0], 0, 1))
-            vl = _kv_write(vc_l, page_ids, offs,
-                           jnp.swapaxes(v[:, 0], 0, 1))
-            new["k"], new["v"] = kl, vl
-            o = self._paged_attend(q[:, 0], kl, vl, tables, lens + 1)
-            return o[:, None]                         # [B, 1, Hq, D]
-
-        x = self._layer_forward(0, lp, x, lens, attend)
-        return x, new["k"], new["v"]
-
     def _decode_step(self, weights, tokens, lens, tables, kc, vc,
                      temps, top_ks, top_ps, key, do_sample=False):
         """ONE batched decode: tokens [B] (last emitted), lens [B] tokens
@@ -961,16 +979,16 @@ class ContinuousBatchingEngine:
         jax, jnp = self._jax, self._jnp
         from ..models.gpt import _rms_pure
 
-        b = tokens.shape[0]
         x = weights["embed"][tokens][:, None]                # [B, 1, H]
-        page_ids = tables[jnp.arange(b), lens // self.page]
-        offs = lens % self.page
 
-        def layer_fn(lp, x, kc_l, vc_l):
-            return self._decode_layer(lp, x, lens, tables, page_ids,
-                                      offs, kc_l, vc_l)
+        def attend(li, q, k, v, kc, vc):
+            # write this token's KV row, then read it back with the rest
+            kc = _kv_write_run(kc, li, tables, lens, 1, k)
+            vc = _kv_write_run(vc, li, tables, lens, 1, v)
+            o = self._paged_attend(q[:, 0], kc, vc, li, tables, lens + 1)
+            return o[:, None], kc, vc                 # [B, 1, Hq, D]
 
-        x, kc, vc = self._run_layers(weights, x, layer_fn, kc, vc)
+        x, kc, vc = self._run_layers(weights, x, lens, kc, vc, attend)
         x = _rms_pure(x, weights["fnorm"])[:, 0]
         lg = (x @ weights["head"] if weights["head"] is not None
               else x @ weights["embed"].T)
@@ -998,30 +1016,17 @@ class ContinuousBatchingEngine:
         jnp = self._jnp
         from ..models.gpt import _rms_pure
 
-        b, C = toks.shape
+        C = toks.shape[1]
         x = weights["embed"][toks]                           # [B, C, H]
-        pos = lens[:, None] + jnp.arange(C)[None, :]         # [B, C]
-        page_idx = jnp.clip(pos // self.page, 0, self.pages_per_seq - 1)
-        page_ids = jnp.take_along_axis(tables, page_idx, 1)
-        offs = pos % self.page
 
-        def layer_fn(lp, x, kc_l, vc_l):
-            new = {}
+        def attend(li, q, k, v, kc, vc):
+            kc = _kv_write_run(kc, li, tables, lens, C, k)
+            vc = _kv_write_run(vc, li, tables, lens, C, v)
+            o = [self._paged_attend(q[:, i], kc, vc, li, tables,
+                                    lens + i + 1) for i in range(C)]
+            return jnp.stack(o, 1), kc, vc            # [B, C, Hq, D]
 
-            def attend(li, q, k, v):
-                kl = _kv_write(kc_l, page_ids, offs,
-                               jnp.transpose(k, (2, 0, 1, 3)))
-                vl = _kv_write(vc_l, page_ids, offs,
-                               jnp.transpose(v, (2, 0, 1, 3)))
-                new["k"], new["v"] = kl, vl
-                o = [self._paged_attend(q[:, i], kl, vl, tables,
-                                        lens + i + 1) for i in range(C)]
-                return jnp.stack(o, 1)                # [B, C, Hq, D]
-
-            x = self._layer_forward(0, lp, x, lens, attend)
-            return x, new["k"], new["v"]
-
-        x, kc, vc = self._run_layers(weights, x, layer_fn, kc, vc)
+        x, kc, vc = self._run_layers(weights, x, lens, kc, vc, attend)
         x = _rms_pure(x, weights["fnorm"])                   # [B, C, H]
         lg = (x @ weights["head"] if weights["head"] is not None
               else x @ weights["embed"].T)
@@ -1289,13 +1294,12 @@ class ContinuousBatchingEngine:
                 self._emit(req, tok)
         # chunked mode: KV fills incrementally in step()
 
-    def _prefill_chunk_step(self, weights, ids, pos0, nvalid, tok_pages,
-                            offs, hist, kc, vc):
+    def _prefill_chunk_step(self, weights, ids, pos0, nvalid, hist, kc, vc):
         """ONE jitted fixed-shape chunk pass over ALL prefilling slots:
         ids [B, c] chunk tokens (zero-padded), pos0 [B] absolute start,
-        nvalid [B] real tokens this chunk, tok_pages/offs [B, c] scatter
-        targets (padded rows -> the scratch page), hist [B, pages_per_seq]
-        page tables. Returns (final-normed last-valid hidden [B, H],
+        nvalid [B] real tokens this chunk (0 for a slot with none: its
+        writes go to the scratch page), hist [B, pages_per_seq] page
+        tables. Returns (final-normed last-valid hidden [B, H],
         new kc, new vc). Shapes are engine constants (max_slots x
         prefill_chunk x pages_per_seq), so this compiles ONCE."""
         jax, jnp = self._jax, self._jnp
@@ -1310,45 +1314,32 @@ class ContinuousBatchingEngine:
         cols = jnp.arange(S)
         # chunk rows attend to [cached prefix + own chunk] causally
         mask = cols[None, None, :] <= row_pos[:, :, None]    # [B, c, S]
-        tp = tok_pages.reshape(-1)
-        of = offs.reshape(-1)
         dt = self._kv_dtype
 
-        def layer_fn(lp, x, kc_l, vc_l):
-            new = {}
+        def attend(li, q, k, v, kc, vc):
+            # write the chunk's kv FIRST, then gather the prefix back
+            # (one source of truth for the attention operands; in int8
+            # mode both the own-chunk and prefix reads come back
+            # dequantized — identical to what decode will see)
+            kc = _kv_write_run(kc, li, hist, pos0, nvalid, k)
+            vc = _kv_write_run(vc, li, hist, pos0, nvalid, v)
+            ck = _kv_gather_rows(kc, li, hist, dt).reshape(
+                self.hkv, B, S, self.hd)
+            cv = _kv_gather_rows(vc, li, hist, dt).reshape(
+                self.hkv, B, S, self.hd)
+            if rep > 1:
+                ck = jnp.repeat(ck, rep, 0)
+                cv = jnp.repeat(cv, rep, 0)
+            logits = jnp.einsum("bchd,hbsd->bhcs",
+                                (q * scale).astype(jnp.float32),
+                                ck.astype(jnp.float32))
+            logits = jnp.where(mask[:, None], logits, -1e30)
+            probs = jax.nn.softmax(logits, -1)
+            o = jnp.einsum("bhcs,hbsd->bchd", probs,
+                           cv.astype(jnp.float32))
+            return o.astype(q.dtype), kc, vc             # [B, c, Hq, D]
 
-            def attend(li, q, k, v):
-                # write the chunk's kv FIRST, then gather the prefix
-                # back (one source of truth for the attention operands;
-                # in int8 mode both the own-chunk and prefix reads come
-                # back dequantized — identical to what decode will see)
-                kv = jnp.swapaxes(
-                    k.reshape(B * c, self.hkv, self.hd), 0, 1)
-                vv = jnp.swapaxes(
-                    v.reshape(B * c, self.hkv, self.hd), 0, 1)
-                kl = _kv_write(kc_l, tp, of, kv)
-                vl = _kv_write(vc_l, tp, of, vv)
-                new["k"], new["v"] = kl, vl
-                ck = _kv_gather_rows(kl, hist, dt).reshape(
-                    self.hkv, B, S, self.hd)
-                cv = _kv_gather_rows(vl, hist, dt).reshape(
-                    self.hkv, B, S, self.hd)
-                if rep > 1:
-                    ck = jnp.repeat(ck, rep, 0)
-                    cv = jnp.repeat(cv, rep, 0)
-                logits = jnp.einsum("bchd,hbsd->bhcs",
-                                    (q * scale).astype(jnp.float32),
-                                    ck.astype(jnp.float32))
-                logits = jnp.where(mask[:, None], logits, -1e30)
-                probs = jax.nn.softmax(logits, -1)
-                o = jnp.einsum("bhcs,hbsd->bchd", probs,
-                               cv.astype(jnp.float32))
-                return o.astype(q.dtype)                 # [B, c, Hq, D]
-
-            x = self._layer_forward(0, lp, x, pos0, attend)
-            return x, new["k"], new["v"]
-
-        x, kc, vc = self._run_layers(weights, x, layer_fn, kc, vc)
+        x, kc, vc = self._run_layers(weights, x, pos0, kc, vc, attend)
         last_rows = jnp.clip(nvalid - 1, 0, c - 1)
         last = x[jnp.arange(B), last_rows]                   # [B, H]
         return _rms_pure(last, weights["fnorm"]), kc, vc
@@ -1378,18 +1369,12 @@ class ContinuousBatchingEngine:
             ids_np = np.zeros((B, c), np.int32)
             pos0 = np.zeros(B, np.int32)
             nvalid = np.zeros(B, np.int32)
-            tok_pages = np.full((B, c), self._trash_page, np.int32)
-            offs = np.zeros((B, c), np.int32)
             hist = np.zeros((B, self.pages_per_seq), np.int32)
             for i, r in enumerate(reqs):
                 pos = r.prefill_pos
                 n = min(c_eff, len(r.seq_tokens) - pos)
                 ids_np[i, :n] = r.seq_tokens[pos:pos + n]
                 pos0[i], nvalid[i] = pos, n
-                pages = np.asarray(r.pages, np.int64)
-                ap = np.arange(pos, pos + n)
-                tok_pages[i, :n] = pages[ap // self.page]
-                offs[i, :n] = ap % self.page
                 hist[i, :len(r.pages)] = r.pages[:self.pages_per_seq]
         if _trace.enabled():
             span.annotate(rows=len(reqs), valid_tokens=int(nvalid.sum()),
@@ -1397,8 +1382,7 @@ class ContinuousBatchingEngine:
         with _trace.span("prefill_launch", cat="serve"):
             last, self.kc, self.vc = self._prefill_jit(
                 self._weights, jnp.asarray(ids_np), jnp.asarray(pos0),
-                jnp.asarray(nvalid), jnp.asarray(tok_pages),
-                jnp.asarray(offs), jnp.asarray(hist), self.kc, self.vc)
+                jnp.asarray(nvalid), jnp.asarray(hist), self.kc, self.vc)
         self.prefill_chunk_steps += 1
         completed = []
         for i, r in enumerate(reqs):
@@ -2005,16 +1989,34 @@ class ContinuousBatchingEngine:
         return self._decode_jit.lower(
             *self._dummy_decode_operands()).as_text()
 
+    def _warm(self, name, jitted, *operands):
+        """Compile one program on dummy operands, keep what its
+        ``memory_analysis()`` says under ``program_bytes[name]`` (and in
+        the ``serving_program_temp_bytes`` gauge and a ``program_memory``
+        instant of the trace), and run it once. The run finds the
+        executable the analysis compiled: one compile a program."""
+        mem = jitted.lower(*operands).compile().memory_analysis()
+        nb = {"temp": int(mem.temp_size_in_bytes),
+              "alias": int(mem.alias_size_in_bytes)}
+        self.program_bytes[name] = nb
+        _PROGRAM_TEMP_BYTES.set(float(nb["temp"]), labels=(name,))
+        if _trace.enabled():
+            _trace.instant("program_memory", {"program": name, **nb},
+                           cat="serve")
+        return jitted(*operands)
+
     def warmup(self, sample=False):
         """Compile the engine's programs on dummy operands (cache writes
         land in the scratch page) and record the wall time in
         ``self.build_seconds`` — the replica cold-start number the
-        serving bench records and bench_gate gates (docs/SERVING.md).
-        Greedy programs only unless ``sample=True`` (the first sampled
-        tick otherwise pays its own compile). A ``prefill_only`` engine
-        compiles only its prefill program — the decode/verify programs
-        never run there, and charging their compile into the gated
-        cold-start number would overstate real spin-up cost."""
+        serving bench records and bench_gate gates (docs/SERVING.md) —
+        and each program's temporary and aliased bytes in
+        ``self.program_bytes``. Greedy programs only unless
+        ``sample=True`` (the first sampled tick otherwise pays its own
+        compile). A ``prefill_only`` engine compiles only its prefill
+        program — the decode/verify programs never run there, and
+        charging their compile into the gated cold-start number would
+        overstate real spin-up cost."""
         jax, jnp = self._jax, self._jnp
         t0 = time.perf_counter()
         b = self.max_slots
@@ -2024,22 +2026,21 @@ class ContinuousBatchingEngine:
         modes = () if self.prefill_only else (
             (False, True) if sample else (False,))
         for do_sample in modes:
-            nxt, self.kc, self.vc = self._decode_jit(
-                *self._dummy_decode_operands(do_sample))
+            nxt, self.kc, self.vc = self._warm(
+                "decode_sample" if do_sample else "decode",
+                self._decode_jit, *self._dummy_decode_operands(do_sample))
             np.asarray(nxt)           # block: compile + first dispatch
         if self.prefill_chunk is not None:
             B, c = self.max_slots, self.prefill_chunk
-            last, self.kc, self.vc = self._prefill_jit(
+            last, self.kc, self.vc = self._warm(
+                "prefill", self._prefill_jit,
                 self._weights, jnp.zeros((B, c), jnp.int32),
                 jnp.zeros((B,), jnp.int32), jnp.zeros((B,), jnp.int32),
-                jnp.full((B, c), self._trash_page, jnp.int32),
-                jnp.zeros((B, c), jnp.int32),
-                jnp.full((B, self.pages_per_seq), self._trash_page,
-                         jnp.int32),
-                self.kc, self.vc)
+                tables, self.kc, self.vc)
             np.asarray(last)
         if self._draft is not None and not self.prefill_only:
-            t_out, self.kc, self.vc = self._verify_jit(
+            t_out, self.kc, self.vc = self._warm(
+                "verify", self._verify_jit,
                 self._weights,
                 jnp.zeros((b, self.spec_tokens + 1), jnp.int32),
                 lens, tables, self.kc, self.vc)

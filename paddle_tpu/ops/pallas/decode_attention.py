@@ -17,7 +17,9 @@ dim sequential over KV, page/block granularity aligned to Mosaic tiling.
 
 Layouts:
   decode_attention:  q [B, Hq, D], cache [B, Hkv, S, D], lengths [B]
-  paged_attention:   q [B, Hq, D], pages [Hkv, NumPages, PageSize, D],
+  paged_attention:   q [B, Hq, D], pool [L, Hkv, NumPages, PageSize, D]
+                     addressed by (layer, page) — or one layer's
+                     [Hkv, NumPages, PageSize, D] —
                      block_tables [B, PagesPerSeq], lengths [B]
 `lengths[b]` counts the VALID kv positions (including the current token's
 freshly-written slot).
@@ -137,8 +139,40 @@ def decode_attention(q, k_cache, v_cache, lengths, *, scale=None,
 
 # ------------------------------------------------------------------ paged
 
-def _paged_kernel(tables_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                  acc, m_scr, l_scr, *, scale, page, npages):
+def _stacked(pools, layer):
+    """The pools as ``[L, Hkv, NumPages, PageSize, *]`` and the layer as
+    the i32[1] scalar-prefetch operand. One layer's four-dimensional
+    pages get a leading axis of 1 (a reshape: free) and layer 0."""
+    if pools[0].ndim == 4:
+        if layer is not None:
+            raise ValueError("layer= addresses a stacked [L, Hkv, P, page, "
+                             "D] pool; four-dimensional pages have none")
+        pools, layer = tuple(p[None] for p in pools), 0
+    elif layer is None:
+        raise ValueError("a stacked [L, Hkv, P, page, D] pool needs layer=")
+    return pools, jnp.asarray(layer, jnp.int32).reshape(1)
+
+
+def _table_page(tables, bi, j, num_pages):
+    """Sequence ``bi``'s ``j``-th page id, clamped so garbage table
+    entries past `lengths` stay in-bounds (i32 bounds: python-int
+    literals weak-type to i64 under x64)."""
+    return jnp.clip(tables[bi, j], jnp.int32(0), jnp.int32(num_pages - 1))
+
+
+def _pool_spec(page, width, num_pages):
+    """One page of one kv head of one layer, read where it lies in the
+    stacked pool: the layer axis is squeezed (the kernel sees
+    ``[1, 1, page, width]``), the block table picks the page."""
+
+    def index(bi, h, j, tables, lens, layer):
+        return (layer[0], h, _table_page(tables, bi, j, num_pages), 0, 0)
+
+    return pl.BlockSpec((None, 1, 1, page, width), index)
+
+
+def _paged_kernel(tables_ref, len_ref, layer_ref, q_ref, k_ref, v_ref,
+                  o_ref, acc, m_scr, l_scr, *, scale, page, npages):
     b = pl.program_id(0)
     j = pl.program_id(2)
 
@@ -178,8 +212,8 @@ def _paged_kernel(tables_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[0, 0] = (acc[:] / jnp.where(l == 0.0, ONE_F32, l)).astype(o_ref.dtype)
 
 
-def _paged_int8_kernel(tables_ref, len_ref, q_ref, kc_ref, ks_ref,
-                       vc_ref, vs_ref, o_ref, acc, m_scr, l_scr,
+def _paged_int8_kernel(tables_ref, len_ref, layer_ref, q_ref, kc_ref,
+                       ks_ref, vc_ref, vs_ref, o_ref, acc, m_scr, l_scr,
                        *, scale, page, npages):
     """Paged decode over int8 KV pages: dequantize (codes, scales)
     INSIDE the kernel, so only ~1/4 of the exact cache's bytes cross
@@ -230,38 +264,50 @@ def _paged_int8_kernel(tables_ref, len_ref, q_ref, kc_ref, ks_ref,
 
 
 def paged_attention_int8(q, k_codes, k_scales, v_codes, v_scales,
-                         block_tables, lengths, *, scale=None,
+                         block_tables, lengths, *, layer=None, scale=None,
                          interpret=None):
     """Paged-KV decode attention over int8 pages (the serving
     ``int8_kv=True`` storage: ``memory.quantize_rows_int8`` codes
-    ``[Hkv, NumPages, PageSize, D]`` int8 + scales
-    ``[Hkv, NumPages, PageSize, 1]`` f32). Dequantization happens in
-    VMEM per fetched page — numerically identical to gathering the
-    owned pages and dequantizing in HBM (same codes * scales product),
-    without ever materializing the dequantized cache.
+    ``[L, Hkv, NumPages, PageSize, D]`` int8 + scales
+    ``[L, Hkv, NumPages, PageSize, 1]`` f32, read at ``layer``; or one
+    layer's four-dimensional pages with ``layer`` left out). The codes
+    are addressed in place by (layer, page); only the layer's scales
+    are sliced out, to be laid along the lanes.
+    Dequantization happens in VMEM per fetched page — numerically
+    identical to gathering the owned pages and dequantizing in HBM
+    (same codes * scales product), without ever materializing the
+    dequantized cache.
     """
     from . import use_interpret
 
     if interpret is None:
         interpret = use_interpret()
+    (k_codes, k_scales, v_codes, v_scales), layer = _stacked(
+        (k_codes, k_scales, v_codes, v_scales), layer)
+    li = layer[0]
     b, hq, d = q.shape
-    hkv, num_pages, page, _ = k_codes.shape
+    _, hkv, num_pages, page, _ = k_codes.shape
     rep = hq // hkv
     pages_per_seq = block_tables.shape[1]
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
 
-    def _page_index(bi, h, j, tables, lens):
-        t = tables[bi, j]
-        return (h, jnp.clip(t, jnp.int32(0), jnp.int32(num_pages - 1)),
-                0, 0)
-
     qg = q.reshape(b, hkv, rep, d)
-    # scales ride sublane-padded [Hkv, P, 8, page] (the lse8 pattern:
-    # Mosaic blocks need >= 8 sublanes) — a broadcast view, 32B/page-row
-    ks8 = jnp.broadcast_to(k_scales.reshape(hkv, num_pages, 1, page),
-                           (hkv, num_pages, 8, page))
-    vs8 = jnp.broadcast_to(v_scales.reshape(hkv, num_pages, 1, page),
-                           (hkv, num_pages, 8, page))
+    codes = _pool_spec(page, d, num_pages)
+
+    def lane_dense(scales):
+        # the codes are read where they lie; the scales cannot be. Held
+        # as [.., page, 1] columns, Mosaic would want each padded to 128
+        # lanes, a relayout of the whole pool of them. So the layer's
+        # scales (1/32 of its codes' bytes at D=128) are sliced out and
+        # laid [Hkv, P, 8, page]: page along the lanes, sublane-padded
+        # (the lse8 pattern: Mosaic blocks need >= 8 sublanes)
+        s = jax.lax.dynamic_index_in_dim(scales, li, 0, keepdims=False)
+        return jnp.broadcast_to(s.reshape(hkv, num_pages, 1, page),
+                                (hkv, num_pages, 8, page))
+
+    scales = pl.BlockSpec(
+        (1, 1, 8, page), lambda bi, h, j, tables, lens, layer: (
+            h, _table_page(tables, bi, j, num_pages), 0, 0))
     kern = functools.partial(_paged_int8_kernel, scale=scale, page=page,
                              npages=pages_per_seq)
     with jax.enable_x64(False):
@@ -269,18 +315,15 @@ def paged_attention_int8(q, k_codes, k_scales, v_codes, v_scales,
             kern,
             name="paged_attention_int8",
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=2,
+                num_scalar_prefetch=3,
                 grid=(b, hkv, pages_per_seq),
                 in_specs=[
                     pl.BlockSpec((1, 1, rep, d),
-                                 lambda bi, h, j, T, L: (bi, h, 0, 0)),
-                    pl.BlockSpec((1, 1, page, d), _page_index),
-                    pl.BlockSpec((1, 1, 8, page), _page_index),
-                    pl.BlockSpec((1, 1, page, d), _page_index),
-                    pl.BlockSpec((1, 1, 8, page), _page_index),
+                                 lambda bi, h, j, T, L, li: (bi, h, 0, 0)),
+                    codes, scales, codes, scales,
                 ],
                 out_specs=pl.BlockSpec(
-                    (1, 1, rep, d), lambda bi, h, j, T, L: (bi, h, 0, 0)),
+                    (1, 1, rep, d), lambda bi, h, j, T, L, li: (bi, h, 0, 0)),
                 scratch_shapes=[
                     pltpu.VMEM((rep, d), jnp.float32),
                     pltpu.VMEM((rep, 128), jnp.float32),
@@ -298,39 +341,37 @@ def paged_attention_int8(q, k_codes, k_scales, v_codes, v_scales,
                                 * (d + 4)),
                 transcendentals=b * hq * pages_per_seq * page,
             ),
-        )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
-          qg, k_codes, ks8, v_codes, vs8)
+        )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32), layer,
+          qg, k_codes, lane_dense(k_scales), v_codes, lane_dense(v_scales))
     return out.reshape(b, hq, d)
 
 
 def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
-                    scale=None, interpret=None):
+                    layer=None, scale=None, interpret=None):
     """Paged-KV decode attention (block_multi_head_attention slot).
 
-    q [B, Hq, D]; pages [Hkv, NumPages, PageSize, D];
+    q [B, Hq, D]; the stacked pool [L, Hkv, NumPages, PageSize, D] read
+    at ``layer`` (an int or a traced scalar: the serving programs hand
+    over the whole pool and never slice a layer out of it), or one
+    layer's pages [Hkv, NumPages, PageSize, D] with ``layer`` left out;
     block_tables [B, PagesPerSeq] (page ids per sequence, row-major);
-    lengths [B] valid kv length. The BlockSpec index map reads the block
-    table via scalar prefetch, so only the pages a sequence actually owns
-    are fetched from HBM.
+    lengths [B] valid kv length. The BlockSpec index map reads the layer
+    and the block table via scalar prefetch, so only the pages a
+    sequence actually owns are fetched from HBM.
     """
     from . import use_interpret
 
     if interpret is None:
         interpret = use_interpret()
+    (k_pages, v_pages), layer = _stacked((k_pages, v_pages), layer)
     b, hq, d = q.shape
-    hkv, num_pages, page, _ = k_pages.shape
+    _, hkv, num_pages, page, _ = k_pages.shape
     rep = hq // hkv
     pages_per_seq = block_tables.shape[1]
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
 
-    def _page_index(bi, h, j, tables, lens):
-        # clamp so garbage table entries past `lengths` stay in-bounds
-        # (i32 bounds: python-int literals weak-type to i64 under x64)
-        t = tables[bi, j]
-        return (h, jnp.clip(t, jnp.int32(0), jnp.int32(num_pages - 1)),
-                0, 0)
-
     qg = q.reshape(b, hkv, rep, d)
+    pages = _pool_spec(page, d, num_pages)
     kern = functools.partial(_paged_kernel, scale=scale, page=page,
                              npages=pages_per_seq)
     with jax.enable_x64(False):
@@ -338,16 +379,15 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
             kern,
             name="paged_attention",
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=2,
+                num_scalar_prefetch=3,
                 grid=(b, hkv, pages_per_seq),
                 in_specs=[
                     pl.BlockSpec((1, 1, rep, d),
-                                 lambda bi, h, j, T, L: (bi, h, 0, 0)),
-                    pl.BlockSpec((1, 1, page, d), _page_index),
-                    pl.BlockSpec((1, 1, page, d), _page_index),
+                                 lambda bi, h, j, T, L, li: (bi, h, 0, 0)),
+                    pages, pages,
                 ],
                 out_specs=pl.BlockSpec(
-                    (1, 1, rep, d), lambda bi, h, j, T, L: (bi, h, 0, 0)),
+                    (1, 1, rep, d), lambda bi, h, j, T, L, li: (bi, h, 0, 0)),
                 scratch_shapes=[
                     pltpu.VMEM((rep, d), jnp.float32),
                     pltpu.VMEM((rep, 128), jnp.float32),
@@ -365,6 +405,6 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
                 * q.dtype.itemsize,
                 transcendentals=b * hq * pages_per_seq * page,
             ),
-        )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
+        )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32), layer,
           qg, k_pages, v_pages)
     return out.reshape(b, hq, d)

@@ -359,10 +359,13 @@ class TestPagedAttentionInt8:
         a, r = np.asarray(out), np.asarray(ref)
         assert a.tobytes() == r.tobytes(), float(np.abs(a - r).max())
 
-    def test_serving_paged_attend_kernel_vs_gather_path(self, monkeypatch):
+    @pytest.mark.parametrize("li", [0, 1, 2])
+    def test_serving_paged_attend_kernel_vs_gather_path(self, monkeypatch,
+                                                        li):
         """The engine's int8 `_paged_attend` with the kernel forced
         (PTPU_PAGED_INT8_KERNEL=interpret) matches the default HBM
-        gather+dequant reference path on the same (codes, scales)."""
+        gather+dequant reference path on the same (codes, scales), at
+        every layer of a stacked pool."""
         from paddle_tpu.inference.serving import (
             ContinuousBatchingEngine, _int8_paged_kernel_active)
 
@@ -384,16 +387,74 @@ class TestPagedAttentionInt8:
         shim = _Shim()
         b, hq, d = 2, 4, 64
         num_pages = 8
-        k = _rand((shim.hkv, num_pages, shim.page, d), seed=21)
-        v = _rand((shim.hkv, num_pages, shim.page, d), seed=22)
+        k = _rand((3, shim.hkv, num_pages, shim.page, d), seed=21)
+        v = _rand((3, shim.hkv, num_pages, shim.page, d), seed=22)
         kq, ks = quantize_rows_int8(k)
         vq, vs = quantize_rows_int8(v)
         tables = jnp.asarray(np.random.default_rng(5).choice(
             num_pages, (b, shim.pages_per_seq)).astype(np.int32))
         lens = jnp.array([13, 30], jnp.int32)
         q = _rand((b, hq, d), seed=23)
-        ref = shim._paged_attend(q, (kq, ks), (vq, vs), tables, lens)
+        ref = shim._paged_attend(q, (kq, ks), (vq, vs), li, tables, lens)
         monkeypatch.setenv("PTPU_PAGED_INT8_KERNEL", "interpret")
-        out = shim._paged_attend(q, (kq, ks), (vq, vs), tables, lens)
+        out = shim._paged_attend(q, (kq, ks), (vq, vs), li, tables, lens)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
+
+
+class TestStackedPool:
+    """The serving programs hand the kernels the WHOLE stacked pool
+    [L, Hkv, P, page, D] and a layer (ISSUE 28): the pages are read
+    where they lie, by (layer, page). The stacked call must be bitwise
+    the four-dimensional call on that layer's pages, for a python-int
+    layer (the unrolled walk) and a traced one (the scan's counter)."""
+
+    L, B, HQ, HKV, D, PAGE, PPS = 3, 2, 4, 2, 64, 8, 4
+
+    def _operands(self):
+        num_pages = 2 * self.PPS
+        shape = (self.L, self.HKV, num_pages, self.PAGE, self.D)
+        k, v = _rand(shape, seed=31), _rand(shape, seed=32)
+        tables = jnp.asarray(np.random.default_rng(33).choice(
+            num_pages, (self.B, self.PPS)).astype(np.int32))
+        lens = jnp.array([13, 30], jnp.int32)
+        return _rand((self.B, self.HQ, self.D), seed=34), k, v, tables, lens
+
+    def _kernel_and_pools(self, pool, k, v):
+        from paddle_tpu.memory import quantize_rows_int8
+        from paddle_tpu.ops.pallas.decode_attention import (
+            paged_attention_int8)
+
+        if pool == "exact":
+            return paged_attention, (k, v)
+        return paged_attention_int8, (*quantize_rows_int8(k),
+                                      *quantize_rows_int8(v))
+
+    @pytest.mark.parametrize("traced", [False, True],
+                             ids=["int-layer", "traced-layer"])
+    @pytest.mark.parametrize("li", [0, 1, 2])
+    @pytest.mark.parametrize("pool", ["exact", "int8"])
+    def test_stacked_equals_per_layer_bitwise(self, pool, li, traced):
+        q, k, v, tables, lens = self._operands()
+        kernel, pools = self._kernel_and_pools(pool, k, v)
+
+        def stacked(layer):
+            return kernel(q, *pools, tables, lens, layer=layer,
+                          interpret=True)
+
+        out = (jax.jit(stacked)(jnp.int32(li)) if traced else stacked(li))
+        ref = kernel(q, *(p[li] for p in pools), tables, lens,
+                     interpret=True)
+        assert np.asarray(out).tobytes() == np.asarray(ref).tobytes()
+
+    @pytest.mark.parametrize("pool", ["exact", "int8"])
+    def test_layer_and_rank_must_agree(self, pool):
+        """A stacked pool without a layer, or one layer's pages with
+        one, is a caller's mistake, not a default."""
+        q, k, v, tables, lens = self._operands()
+        kernel, pools = self._kernel_and_pools(pool, k, v)
+        with pytest.raises(ValueError, match="needs layer="):
+            kernel(q, *pools, tables, lens, interpret=True)
+        with pytest.raises(ValueError, match="have none"):
+            kernel(q, *(p[0] for p in pools), tables, lens, layer=0,
+                   interpret=True)

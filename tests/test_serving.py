@@ -446,6 +446,222 @@ class TestScanDecode:
         assert warm == fresh.run_until_complete()[0]
 
 
+class TestKvWriteRun:
+    """`_kv_write_run`, the one writer of K/V rows: each slot's run of
+    consecutive positions lands in the pages its table names, row for
+    row what a plain per-row loop writes, and nothing else moves."""
+
+    L, HKV, PAGES, PAGE, D, B, PPS = 3, 2, 9, 8, 4, 3, 3
+
+    def _case(self, c, seed):
+        rng = np.random.default_rng(seed)
+        pool = rng.standard_normal(
+            (self.L, self.HKV, self.PAGES + 1, self.PAGE, self.D)
+        ).astype(np.float32)
+        # distinct pages a slot: pages are exclusively owned
+        tables = rng.permutation(self.PAGES).reshape(
+            self.B, self.PPS).astype(np.int32)
+        vals = rng.standard_normal(
+            (self.B, c, self.HKV, self.D)).astype(np.float32)
+        return pool, tables, vals
+
+    @staticmethod
+    def _reference(pool, li, tables, pos0, nvalid, vals, page):
+        want = pool.copy()
+        for b in range(len(pos0)):
+            for r in range(int(nvalid[b])):
+                pos = int(pos0[b]) + r
+                if pos // page < tables.shape[1]:
+                    want[li, :, tables[b, pos // page], pos % page] = \
+                        vals[b, r]
+        return want
+
+    @pytest.mark.parametrize("traced", [False, True],
+                             ids=["int-layer", "traced-layer"])
+    @pytest.mark.parametrize("c,pos0,nvalid", [
+        (1, [0, 7, 17], [1, 1, 1]),        # a decode tick: one row a slot
+        (3, [6, 15, 21], [3, 3, 3]),       # a window across a page edge
+        (8, [0, 8, 16], [8, 5, 0]),        # aligned chunk, a short and an
+                                           # idle slot
+        (8, [3, 13, 5], [8, 8, 2]),        # unaligned: three pages a run
+        (12, [0, 2, 9], [12, 10, 7]),      # c over a page
+        (8, [20, 23, 16], [8, 8, 8]),      # positions past the table's
+                                           # end are dropped
+    ])
+    def test_matches_row_loop(self, c, pos0, nvalid, traced):
+        import jax
+        import jax.numpy as jnp
+
+        from paddle_tpu.inference.serving import _kv_write_run
+
+        pool, tables, vals = self._case(c, seed=c + sum(pos0))
+        li = 1
+        want = self._reference(pool, li, tables, pos0, nvalid, vals,
+                               self.PAGE)
+        args = (jnp.asarray(tables), jnp.asarray(pos0, jnp.int32),
+                jnp.asarray(nvalid, jnp.int32), jnp.asarray(vals))
+        if traced:
+            got = jax.jit(lambda p, l: _kv_write_run(p, l, *args))(
+                jnp.asarray(pool), jnp.int32(li))
+        else:
+            got = _kv_write_run(jnp.asarray(pool), li, *args)
+        got = np.asarray(got)
+        # the scratch page (the pool's last) may hold anything
+        assert got[:, :, :-1].tobytes() == want[:, :, :-1].tobytes()
+
+    def test_int8_pool_quantizes_each_row(self):
+        import jax.numpy as jnp
+
+        from paddle_tpu.inference.serving import _kv_write_run
+        from paddle_tpu.memory import quantize_rows_int8
+
+        pool, tables, vals = self._case(8, seed=5)
+        pos0, nvalid = [3, 13, 5], [8, 6, 0]
+        codes, scales = quantize_rows_int8(jnp.asarray(pool))
+        qv, sv = quantize_rows_int8(jnp.asarray(vals))
+        want_q = self._reference(np.asarray(codes), 2, tables, pos0, nvalid,
+                                 np.asarray(qv), self.PAGE)
+        want_s = self._reference(np.asarray(scales), 2, tables, pos0,
+                                 nvalid, np.asarray(sv), self.PAGE)
+        got_q, got_s = _kv_write_run(
+            (codes, scales), 2, jnp.asarray(tables),
+            jnp.asarray(pos0, jnp.int32), jnp.asarray(nvalid, jnp.int32),
+            jnp.asarray(vals))
+        assert got_q.dtype == jnp.int8 and got_s.dtype == jnp.float32
+        assert np.asarray(got_q)[:, :, :-1].tobytes() == \
+            want_q[:, :, :-1].tobytes()
+        assert np.asarray(got_s)[:, :, :-1].tobytes() == \
+            want_s[:, :, :-1].tobytes()
+
+
+_POOL_PROGRAMS = ("decode", "prefill", "verify", "draft_window_c2")
+
+
+def _walk_eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations carry
+    (scan and pjit bodies, branches), except a Pallas kernel's own body:
+    what a kernel does to one VMEM block is not the program's traffic."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _walk_eqns(sub)
+
+
+class TestPoolStaysInPlace:
+    """ISSUE 28: a serving program takes the stacked K/V pool, writes
+    rows into it in place and returns the same buffer. Structural: the
+    CPU's buffer assignment keeps a pool-sized temporary in either
+    form, so bytes are the chip's to prove (``program_bytes``); what a
+    CPU can prove is that the jaxpr gives XLA nothing to copy — the
+    pools ride the layer scan's carry, and no equation makes a layer's
+    slab or a second pool."""
+
+    @pytest.fixture(scope="class", params=[
+        (scan, kv) for scan in ("scan", "unrolled")
+        for kv in ("exact", "int8")], ids="-".join)
+    def programs(self, request):
+        """One warmed engine with a draft per (walk, pool kind): its
+        programs' jaxprs as ``warmup()`` compiled them, taken at
+        ``_warm``, the one seam every compiled program passes."""
+        import paddle_tpu.telemetry as telemetry
+        from paddle_tpu.telemetry import trace
+
+        scan, kv = request.param
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setenv("PTPU_SCAN_LAYERS", "1" if scan == "scan" else "0")
+            model = _tiny_model()
+            eng = ContinuousBatchingEngine(
+                model, max_slots=2, page_size=16, max_seq_len=64,
+                max_new_tokens=4, prefill_chunk=8, draft_model=model,
+                spec_tokens=2, int8_kv=kv == "int8")
+            assert eng._scan_layers == (scan == "scan")
+            assert eng.int8_kv == (kv == "int8")
+            jaxprs, warm = {}, eng._warm
+
+            def spy(name, jitted, *operands):
+                jaxprs[name] = jitted.trace(*operands).jaxpr.jaxpr
+                return warm(name, jitted, *operands)
+
+            eng._warm = spy
+            telemetry.enable()
+            trace.enable()
+            try:
+                eng.warmup()
+                gauges = telemetry.snapshot()["gauges"]
+                instants = [e for e in trace.events()
+                            if e["name"] == "program_memory"]
+            finally:
+                trace.disable()
+                trace.reset()
+                telemetry.disable()
+                telemetry.reset()
+        return eng, jaxprs, gauges, instants
+
+    @pytest.mark.parametrize("program", _POOL_PROGRAMS)
+    def test_pool_rides_the_carry_and_no_slab_is_made(self, programs,
+                                                      program):
+        eng, jaxprs, _, _ = programs
+        # the draft's pool has the target's shape here (self-drafting)
+        pool = tuple(np.shape(eng._draft.kc))
+        slab = pool[1:]
+        eqns = list(_walk_eqns(jaxprs[program]))
+
+        def shapes(vs):
+            return [tuple(getattr(v.aval, "shape", ())) for v in vs]
+
+        for eqn in eqns:
+            for shp in shapes(eqn.outvars):
+                assert shp != slab and shp != (1,) + slab, (
+                    f"{program}: {eqn.primitive.name} makes a layer's "
+                    f"slab {shp}")
+        makers = {eqn.primitive.name for eqn in eqns
+                  if pool in shapes(eqn.outvars)}
+        # pages scattered back in place, and the layer scan hands the
+        # buffer on: nothing else has a pool for a result
+        assert "scatter" in makers
+        assert makers <= {"scatter", "scan"}, makers
+        scans = [e for e in eqns if e.primitive.name == "scan"
+                 and e.params["length"] == eng.cfg.num_layers]
+        if not eng._scan_layers:
+            assert not scans
+            return
+        (scan,) = scans
+        nc, nk = scan.params["num_consts"], scan.params["num_carry"]
+        ins = shapes(scan.invars)
+        assert ins[nc:nc + nk].count(pool) == 2       # K and V, carried
+        assert pool not in ins[:nc] + ins[nc + nk:]   # not consts, not xs
+        outs = shapes(scan.outvars)
+        assert outs[:nk].count(pool) == 2
+        assert not outs[nk:]                          # the scan has no ys
+
+    def test_warmup_fills_program_bytes(self, programs):
+        """Each compiled program's memory_analysis(), once, at warmup:
+        on the engine, in the gauge, and as an instant in the trace."""
+        eng, jaxprs, gauges, instants = programs
+        names = set(_POOL_PROGRAMS) | {"draft_window_c1"}
+        assert set(eng.program_bytes) == set(jaxprs) == names
+        pool_bytes = 2 * sum(
+            x.nbytes for x in (eng.kc if eng.int8_kv else (eng.kc,)))
+        for name, nb in eng.program_bytes.items():
+            assert set(nb) == {"temp", "alias"}
+            assert nb["temp"] >= 0
+            # both pools are donated and come back in the same buffers
+            want = (2 * eng._draft.kc.nbytes if name.startswith("draft")
+                    else pool_bytes)
+            assert nb["alias"] >= want, (name, nb, want)
+        g = gauges["serving_program_temp_bytes"]
+        assert g == {f"program={n}": float(nb["temp"])
+                     for n, nb in eng.program_bytes.items()}
+        assert {e["attrs"]["program"]: {k: e["attrs"][k]
+                                        for k in ("temp", "alias")}
+                for e in instants} == eng.program_bytes
+
+
 def test_batched_prefill_single_compile_and_throughput():
     """VERDICT r3 item 7: chunked prefill is one BATCHED jitted pass over
     all prefilling slots (fixed shapes -> compiles once), and the engine
