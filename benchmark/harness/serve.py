@@ -1,7 +1,7 @@
-"""Serving cells: build the decoder and the ContinuousBatchingEngine from a
-configuration file, offer open-loop (timed from when each request was due)
-or closed-loop load on a real clock from one thread, and compare a sample
-of what was served with the plain reference afterwards."""
+"""Serving cells: build the family's model and the ContinuousBatchingEngine
+from a configuration file, offer open-loop (timed from when each request
+was due) or closed-loop load on a real clock from one thread, and compare a
+sample of what was served with the family's plain reference afterwards."""
 from __future__ import annotations
 
 import gc
@@ -11,7 +11,7 @@ import time
 
 import numpy as np
 
-from . import reference, roofline, traffic
+from . import families, traffic
 
 
 def log(msg):
@@ -19,27 +19,12 @@ def log(msg):
 
 
 def build_engine(cfg, seed):
-    """The decoder as a fleet worker builds it, the seed's weights in it,
-    and the engine at the deployment's geometry, warmed on every shape the
-    traffic can use."""
-    import jax.numpy as jnp
-
+    """The family's model with the seed's weights in it, and the engine at
+    the deployment's geometry, warmed on every shape the traffic can use."""
     from paddle_tpu.inference.serving import ContinuousBatchingEngine
-    from tools.serve_bench import build_decoder
 
-    dtype = jnp.dtype(cfg["torch_dtype"])
-    eng = cfg["deployment"]["engine"]
-    cfg_kw = dict(vocab_size=cfg["vocab_size"],
-                  hidden_size=cfg["hidden_size"],
-                  num_layers=cfg["num_hidden_layers"],
-                  num_heads=cfg["num_attention_heads"],
-                  num_kv_heads=cfg["num_key_value_heads"],
-                  intermediate_size=cfg["intermediate_size"],
-                  max_seq_len=eng["max_seq_len"], dropout=0.0,
-                  tie_embeddings=cfg["tie_word_embeddings"])
-    model = build_decoder(cfg_kw, seed=0, bf16=dtype == jnp.bfloat16)
-    load_weights(model, reference.make_weights(cfg, seed, dtype))
-    engine = ContinuousBatchingEngine(model, **eng)
+    model = families.of(cfg).serving_model(cfg, seed)
+    engine = ContinuousBatchingEngine(model, **cfg["deployment"]["engine"])
     # the engine packed its own stacked copy: drop the per-layer one
     for _, p in model.named_parameters():
         p._data = None
@@ -47,28 +32,6 @@ def build_engine(cfg, seed):
     engine.warmup()
     warm_first_token_shapes(engine)
     return engine
-
-
-def load_weights(model, w):
-    """Canonical stacked leaves into LlamaForCausalLM's per-layer
-    parameters (nn.Linear holds [in, out], as the leaves do)."""
-    from paddle_tpu.models.gpt import _BLOCK_PARAM_FIELDS
-
-    params = dict(model.named_parameters())
-
-    def put(name, arr):
-        if tuple(params[name]._data.shape) != tuple(arr.shape):
-            raise RuntimeError(f"{name}: {params[name]._data.shape} != "
-                               f"{arr.shape}")
-        params[name]._data = arr
-
-    put("model.embed_tokens.weight", w["embed"])
-    put("model.final_norm.weight", w["fnorm"])
-    if "head" in w:
-        put("lm_head.weight", w["head"].T)
-    for leaf, suffix in _BLOCK_PARAM_FIELDS:
-        for l in range(w[leaf].shape[0]):
-            put(f"model.layers.{l}.{suffix}", w[leaf][l])
 
 
 def warm_first_token_shapes(engine):
@@ -208,19 +171,19 @@ def window_metrics(load, t0, t1, cfg, counters):
     log(f"K/V filled at most: {toks} tokens in {rows} decoding rows, "
         f"{-(-toks // eng['page_size'])}-{toks // eng['page_size'] + rows} "
         f"of the pool's {eng.get('num_pages')} pages")
-    hd = cfg["hidden_size"] // cfg["num_attention_heads"]
-    counters["model_flops"] = float(roofline.serve_flops(cfg, positions))
-    # K and V rows (2 bytes each) of every live row's length, every layer
+    family = families.of(cfg)
+    counters["model_flops"] = float(family.serve_flops(cfg, positions))
+    # the cache rows of every live row's length, in every layer
     counters["paged_kv_bytes"] = float(
-        sum(s for _, s in in_win) * cfg["num_hidden_layers"]
-        * cfg["num_key_value_heads"] * hd * 2 * 2)
+        sum(s for _, s in in_win) * family.cache_bytes_per_token(cfg))
     return e2e, int(attempted)
 
 
 _GAP_FNS = {}
 
 
-def reference_gaps(weights, prompt, served, cfg, mode, control):
+def reference_gaps(forward_logits, weights, prompt, served, cfg, mode,
+                   control):
     """For each served token, how far its reference logit lies below the
     reference's best at that position; with ``control`` (a lower-precision
     mode) the token judged is the one that mode puts first. The sequence
@@ -232,12 +195,11 @@ def reference_gaps(weights, prompt, served, cfg, mode, control):
     ids = list(prompt) + list(served[:-1])
     t = len(ids)
     pad = -t % 256
-    key = (t + pad, mode, control)
+    key = (forward_logits, t + pad, mode, control)
     if key not in _GAP_FNS:
         _GAP_FNS[key] = jax.jit(lambda w, ids: (
-            reference.forward_logits(w, ids, cfg, mode),
-            reference.forward_logits(w, ids, cfg, control)
-            if control else None))
+            forward_logits(w, ids, cfg, mode),
+            forward_logits(w, ids, cfg, control) if control else None))
     ref, low = _GAP_FNS[key](weights,
                              jnp.asarray(ids + [0] * pad, jnp.int32))
     sl = slice(len(prompt) - 1, t)
@@ -257,9 +219,11 @@ def check_served(finished, cfg, seed, sample, mode="f32", control=None):
     order = np.random.default_rng(int(seed)).permutation(len(finished))
     longest = max(range(len(finished)), key=lambda i: len(finished[i][0]))
     pick = [longest] + [i for i in order if i != longest][:sample - 1]
-    weights = reference.make_weights(cfg, seed, jnp.dtype(cfg["torch_dtype"]))
-    gaps = np.concatenate([reference_gaps(weights, *finished[i], cfg, mode,
-                                          control) for i in pick])
+    family = families.of(cfg)
+    weights = family.make_weights(cfg, seed, jnp.dtype(cfg["torch_dtype"]))
+    gaps = np.concatenate([reference_gaps(
+        family.forward_logits, weights, *finished[i], cfg, mode, control)
+        for i in pick])
     return {"served_compared": float(len(gaps)),
             "gap_max": float(gaps.max()), "gap_mean": float(gaps.mean())}
 

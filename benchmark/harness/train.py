@@ -1,7 +1,8 @@
-"""Training cells: build the model, the optimizer and the compiled step
-from a configuration file, drive the step from the seed through its first
+"""Training cells: build the family's model, the optimizer and the compiled
+step from a configuration file, drive the step from the seed through its first
 steps (the readings `correct` compares), hand the same object to the timed
-window, and follow the first steps with the plain reference afterwards."""
+window, and follow the first steps with the family's plain reference
+afterwards."""
 from __future__ import annotations
 
 import gc
@@ -12,11 +13,8 @@ import statistics
 import sys
 import time
 
-from . import reference, roofline, traffic
-
-#: canonical leaf -> parameter name of GPTForCausalLMPipe
-PIPE_NAMES = {"embed": "embed_tokens.weight", "fnorm": "final_norm.weight",
-              **{k: f"decoder.{k}" for k in reference.LAYER_LEAVES}}
+from . import families, traffic
+from .reference import seed_key
 
 
 def log(msg):
@@ -32,33 +30,24 @@ class Trainer:
         import jax.numpy as jnp
 
         import bench
-        from paddle_tpu.models.gpt import GPTConfig
 
         bench.apply_tpu_defaults()  # the trainer's tuned settings, its own
         self.cfg, self.mix, self.chips = cfg, mix, chips
+        self.family = families.of(cfg)
         self.counters = {}
-        prog = cfg["program"]
         self.dtype = jnp.dtype(cfg["torch_dtype"])
-        bf16 = self.dtype == jnp.bfloat16
-        self.gcfg = GPTConfig(
-            vocab_size=cfg["vocab_size"], hidden_size=cfg["hidden_size"],
-            num_layers=cfg["num_hidden_layers"],
-            num_heads=cfg["num_attention_heads"],
-            num_kv_heads=cfg["num_key_value_heads"],
-            intermediate_size=cfg["intermediate_size"],
-            max_seq_len=mix["seq"], dropout=0.0, dtype=cfg["torch_dtype"],
-            recompute=True, tie_embeddings=cfg["tie_word_embeddings"])
-        self.model = bench.build_model(self.gcfg, bf16=bf16)
+        self.model = self.family.training_model(cfg, mix)
         self.opt = bench.build_optimizer(self.model)
-        self._plan(bench.DEFAULT_POLICY, prog, jax)
+        self._plan(bench.DEFAULT_POLICY, cfg["program"], jax)
         self.step = self._make_step()
         self.counters.update(batch_per_chip=self.batch // chips,
                              seq=mix["seq"])
 
     def _configure(self, policy, head_chunk):
-        self.gcfg.recompute = policy != "none"
-        self.gcfg.recompute_policy = policy
-        self.gcfg.head_chunk = head_chunk
+        mcfg = self.model.config  # the program's own remat settings
+        mcfg.recompute = policy != "none"
+        mcfg.recompute_policy = policy
+        mcfg.head_chunk = head_chunk
 
     def _train_fn(self, ids, labels):
         return self.model.loss(ids, labels)
@@ -111,14 +100,8 @@ class Trainer:
         the seed's batches on the device."""
         import jax
 
-        weights = reference.make_weights(self.cfg, seed, self.dtype)
-        params = dict(self.model.named_parameters())
-        for leaf, name in PIPE_NAMES.items():
-            old = params[name]._data
-            if tuple(old.shape) != tuple(weights[leaf].shape):
-                raise RuntimeError(f"{name}: {old.shape} != "
-                                   f"{weights[leaf].shape}")
-            params[name]._data = weights.pop(leaf)
+        self.family.load_training_weights(
+            self.model, self.family.make_weights(self.cfg, seed, self.dtype))
         self.step._opt_state = None  # fresh moments for a further seed
         self.step._placed = False    # (benchmark/readings.py loads several)
         self.ids, self.labels = traffic.train_batches(
@@ -139,7 +122,7 @@ class Trainer:
 
         f = jax.jit(lambda a: jnp.sum(jnp.square(a.astype(jnp.float32))))
         return {leaf: float(f(tree_of(name)))
-                for leaf, name in PIPE_NAMES.items()}
+                for leaf, name in self.family.TRAIN_PARAMS.items()}
 
     def first_steps(self, n):
         """Drive the step through its first n steps; returns the program's
@@ -157,18 +140,17 @@ class Trainer:
                 st = self.step._opt_state
                 m = self._leaf_sumsq(lambda nm: st[nm]["moment1"])
                 grad = {k: v / (1 - b1) ** 2 for k, v in m.items()}
-        key = reference.seed_key(self.seed)
-        shapes = reference.leaf_shapes(self.cfg)
+        key = seed_key(self.seed)
         params = dict(self.model.named_parameters())
 
         def delta(leaf, key, arr):  # the key an operand: one program a leaf
-            p0 = reference.make_leaf(key, leaf, shapes[leaf], self.dtype)
+            p0 = self.family.seed_param(self.cfg, key, leaf, self.dtype)
             return jnp.sum(jnp.square(arr.astype(jnp.float32)
                                       - p0.astype(jnp.float32)))
 
         change = {leaf: float(jax.jit(delta, static_argnums=0)(
             leaf, key, params[name]._data))
-            for leaf, name in PIPE_NAMES.items()}
+            for leaf, name in self.family.TRAIN_PARAMS.items()}
         return {"loss": losses, "grad_sumsq": grad, "change_sumsq": change}
 
     # ----------------------------------------------------------- the window
@@ -205,8 +187,9 @@ class Trainer:
         log(f"steps {steps}: wall s min {min(walls):.4f} median "
             f"{statistics.median(walls):.4f} max {walls[slow]:.4f} "
             f"(slowest is step {slow}); last loss {last_loss:.4f}")
-        self.counters["model_flops"] = tokens * roofline.train_flops_per_token(
-            self.cfg, self.mix["seq"])
+        self.counters["model_flops"] = (
+            tokens * self.family.train_flops_per_token(self.cfg,
+                                                       self.mix["seq"]))
         return {"t0": t0, "t1": t1, "steps": steps, "last_loss": last_loss,
                 "e2e": {"train_tokens_per_s_per_chip":
                         tokens / (t1 - t0) / self.chips}}
@@ -229,8 +212,8 @@ def reference_readings(cfg, mix, seed, batch, n, mode="f32",
 
     gc.collect()  # an earlier reference's state goes before this one comes
     ids, labels = traffic.train_batches(mix, seed, batch, cfg["vocab_size"])
-    ref = reference.RefTrainer(cfg, seed, cfg["optimizer"],
-                               cfg["torch_dtype"], mode)
+    ref = families.of(cfg).RefTrainer(cfg, seed, cfg["optimizer"],
+                                      cfg["torch_dtype"], mode)
     losses, grad = [], None
     for i in range(n):
         loss, g = ref.step(ids[i % ids.shape[0]][rows],

@@ -41,9 +41,15 @@ def test_names_units_and_files(bench):
     for m in bench["end_to_end"] + bench["per_layer"]:
         assert UNIT.match(m["unit"]) and m["better"] in ("lower", "higher")
         assert set(m.get("workloads", cells)) <= cells
+    from harness import families
+
     for c in bench["configs"]:
         assert os.path.isfile(os.path.join(tiny.REPO, c["file"]))
         assert any(w["config"] == c["name"] for w in bench["workloads"])
+        with open(os.path.join(tiny.REPO, c["file"])) as f:
+            family = families.of(json.load(f))  # no key, no module: raises
+        missing = [n for n in tiny.FAMILY_NAMES if not hasattr(family, n)]
+        assert not missing, (c["name"], family.__name__, missing)
     for w in bench["workloads"]:
         for sub, name in (("traffic", w["traffic"]), ("limits", w["name"])):
             assert os.path.isfile(os.path.join(

@@ -45,10 +45,11 @@ def test_training_steps_match_the_program(tmp_path, kv_heads):
                                            (2, True)],
                          ids=["mha-untied", "gqa-untied", "gqa-tied"])
 def test_logits_match_the_program(tmp_path, kv_heads, tied):
-    """The serving decoder's full forward against forward_logits."""
+    """The serving decoder's full forward, the seed's weights loaded by
+    the family, against the family's forward_logits on the same weights."""
     import jax.numpy as jnp
 
-    from harness import reference, serve
+    from harness import families
 
     tiny.make_root(tmp_path, num_key_value_heads=kv_heads,
                    tie_word_embeddings=tied)
@@ -56,20 +57,13 @@ def test_logits_match_the_program(tmp_path, kv_heads, tied):
 
     _, cell = run.load_cell(str(tmp_path), "serve-decode-closed")
     cfg = cell["config"]
-    from tools.serve_bench import build_decoder
-
-    model = build_decoder(dict(
-        vocab_size=cfg["vocab_size"], hidden_size=cfg["hidden_size"],
-        num_layers=cfg["num_hidden_layers"],
-        num_heads=cfg["num_attention_heads"], num_kv_heads=kv_heads,
-        intermediate_size=cfg["intermediate_size"], max_seq_len=64,
-        dropout=0.0, tie_embeddings=tied), seed=0, bf16=False)
-    w = reference.make_weights(cfg, 5, jnp.float32)
+    family = families.of(cfg)
+    model = family.serving_model(cfg, 5)
+    w = family.make_weights(cfg, 5, jnp.float32)
     assert ("head" in w) == (not tied)
-    serve.load_weights(model, w)
     ids = np.random.default_rng(0).integers(1, cfg["vocab_size"], 24)
     got = np.asarray(model(jnp.asarray(ids[None], jnp.int32))._data)[0]
-    want = np.asarray(reference.forward_logits(
+    want = np.asarray(family.forward_logits(
         w, jnp.asarray(ids, jnp.int32), cfg))
     assert np.abs(got - want).max() < 1e-4 * np.abs(want).max()
 

@@ -12,6 +12,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, os.path.join(REPO, "benchmark"))
 from harness import roofline, trace  # noqa: E402
+from harness.families import dense_gqa  # noqa: E402
 
 US = 1000  # ns
 
@@ -92,9 +93,9 @@ def test_flops_per_token_by_hand():
     i = _cfg("internlm2-1.8b-train.json")
     per_layer = 2 * 2048 * 2048 + 2 * 2048 * 1024 + 3 * 2048 * 8192
     n = 24 * per_layer + 92544 * 2048
-    assert roofline.matmul_params(i) == n == 1_699_479_552
+    assert dense_gqa.matmul_params(i) == n == 1_699_479_552
     want = 6 * n + 6 * 24 * 2048 * 4096
-    assert roofline.train_flops_per_token(i, 4096) == want
+    assert dense_gqa.train_flops_per_token(i, 4096) == want
     assert want / 1e9 == pytest.approx(11.4, abs=0.01)
     # Mistral-7B-v0.1's published widths at 10 of its 32 layers
     m = dict(hidden_size=4096, intermediate_size=14336,
@@ -102,13 +103,15 @@ def test_flops_per_token_by_hand():
              num_key_value_heads=8, vocab_size=32000)
     per_layer = 2 * 4096 * 4096 + 2 * 4096 * 1024 + 3 * 4096 * 14336
     n = 10 * per_layer + 32000 * 4096
-    assert roofline.train_flops_per_token(m, 2048) == \
+    assert dense_gqa.train_flops_per_token(m, 2048) == \
         6 * n + 6 * 10 * 4096 * 2048
-    assert roofline.train_flops_per_token(m, 2048) / 1e9 == \
+    assert dense_gqa.train_flops_per_token(m, 2048) / 1e9 == \
         pytest.approx(14.4, abs=0.05)
     # serving: 2 N a token and 4 L h per position of context
-    assert roofline.serve_flops(i, [0, 10]) == \
-        2 * 2 * roofline.matmul_params(i) + 4 * 24 * 2048 * 10
+    assert dense_gqa.serve_flops(i, [0, 10]) == \
+        2 * 2 * dense_gqa.matmul_params(i) + 4 * 24 * 2048 * 10
+    # the cache: K and V of 8 heads x 128, two bytes each, in 24 layers
+    assert dense_gqa.cache_bytes_per_token(i) == 24 * 8 * 128 * 2 * 2 == 98_304
 
 
 def test_rooflines_and_mfu():
@@ -116,7 +119,7 @@ def test_rooflines_and_mfu():
     pk = roofline.peaks("TPU v5 lite")
     with pytest.raises(KeyError):
         roofline.peaks("TPU v9")
-    fl, nb = roofline.flash_fwd_work(i, 1, 4096)
+    fl, nb = dense_gqa.flash_fwd_work(i, 1, 4096)
     assert fl == 2 * 4096 * 4096 * 2048
     assert nb == 2 * 4096 * (2 * 2048 + 2 * 1024) + 4 * 4096 * 16
     tr = {"ops": {"flash_fwd": [24, 24 * 2 * fl / pk["bf16_flops"]]},

@@ -19,6 +19,12 @@ TRAIN_LIMITS = {"loss1_gap": {"max": 1e-5}, "loss2_gap": {"max": 1e-5},
                 "change_norm_gap": {"max": 1e-3},
                 "last_loss_finite": {"max": 0}}
 SERVE_LIMITS = {"gap_max": {"max": 1e-4}, "served_compared": {"min": 8}}
+#: what the harness asks of a family (harness/families/__init__.py)
+FAMILY_NAMES = ("make_weights", "forward_logits", "RefTrainer",
+                "serving_model", "training_model", "TRAIN_PARAMS",
+                "load_training_weights", "seed_param", "matmul_params",
+                "train_flops_per_token", "serve_flops",
+                "cache_bytes_per_token", "KERNEL_WORK")
 
 
 def _dump(obj, path):
@@ -29,7 +35,8 @@ def _dump(obj, path):
 
 def make_root(root, **over):
     """Write the tiny tree under ``root``; returns BENCHMARK.json's dict.
-    ``over`` overrides keys of every configuration (MHA, untied, ...)."""
+    ``over`` overrides keys of every configuration (MHA, untied, another
+    ``family``: the key is carried through as every other)."""
     root = str(root)
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
