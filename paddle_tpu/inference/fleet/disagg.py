@@ -151,8 +151,8 @@ class DisaggregatedEngine:
                 # returned completions
                 continue
             req = eng.extract(i)
-            size = (_kv_nbytes(req.swapped["k"])
-                    + _kv_nbytes(req.swapped["v"]))
+            size = sum(_kv_nbytes(req.swapped[name])
+                       for name in eng.cache_names)
             self.handoffs += 1
             self.handoff_bytes += size
             _HANDOFFS.inc()

@@ -76,9 +76,14 @@ class DraftRunner:
         draft depth too)."""
         from ..serving import _run_layer_stack
 
-        return _run_layer_stack(self.engine._scan_layers,
-                                self._weights["layers"], x, layer_fn,
-                                kc, vc)
+        def over_pools(lp, li, x, cache):
+            x, kc, vc = layer_fn(lp, li, x, *cache)
+            return x, (kc, vc)
+
+        x, (kc, vc) = _run_layer_stack(self.engine._scan_layers,
+                                       self._weights["layers"], x,
+                                       over_pools, (kc, vc))
+        return x, kc, vc
 
     def _layer_forward(self, lp, x, pos0, attend):
         """THE draft decoder-layer body: projections + rope +
@@ -88,6 +93,7 @@ class DraftRunner:
         is exactly what collapses speculative acceptance)."""
         jax, jnp = self._jax, self._jnp
         from ...models.gpt import _rms_pure
+        from ..serving import _rope
 
         ln1, wq, wk, wv, wo, ln2, wg, wu, wd = lp
         B, S = x.shape[:2]
@@ -95,7 +101,7 @@ class DraftRunner:
         q = (h @ wq).reshape(B, S, self.cfg.num_heads, self.hd)
         k = (h @ wk).reshape(B, S, self.hkv, self.hd)
         v = (h @ wv).reshape(B, S, self.hkv, self.hd)
-        q, k = self.engine._rope(q, pos0), self.engine._rope(k, pos0)
+        q, k = _rope(q, pos0), _rope(k, pos0)
         o = attend(q, k, v)                              # [B, S, Hq, D]
         x = x + o.reshape(B, S, -1).astype(x.dtype) @ wo
         h2 = _rms_pure(x, ln2)

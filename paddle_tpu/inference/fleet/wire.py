@@ -312,12 +312,19 @@ def request_to_wire(req, clock=time.perf_counter):
     }
     if req.swapped is not None:
         s = req.swapped
+        # one entry a pool under the pool's name ("k", "v"; "latent")
         d["swapped"] = {
-            "k": s["k"], "v": s["v"], "n": int(s["n"]),
+            **_snapshot_pools(s), "n": int(s["n"]),
             "prefill_pos": int(s["prefill_pos"]),
             "length": int(s["length"]),
         }
     return d
+
+
+def _snapshot_pools(snap):
+    """The pools of a host KV snapshot: every entry but its counters."""
+    return {k: v for k, v in snap.items()
+            if k not in ("n", "prefill_pos", "length")}
 
 
 def request_from_wire(d, clock=time.perf_counter):
@@ -334,7 +341,7 @@ def request_from_wire(d, clock=time.perf_counter):
         req.deadline = clock() + float(d["deadline_remaining"])
     s = d.get("swapped")
     if s is not None:
-        req.swapped = {"k": s["k"], "v": s["v"], "n": int(s["n"]),
+        req.swapped = {**_snapshot_pools(s), "n": int(s["n"]),
                        "prefill_pos": int(s["prefill_pos"]),
                        "length": int(s["length"])}
     return req

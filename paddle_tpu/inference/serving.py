@@ -103,6 +103,12 @@ _WEIGHT_BYTES = _telemetry.gauge(
     "resident packed decode-weight bytes per storage dtype "
     "(docs/QUANT.md: int8-packed replicas report the reduced footprint)",
     labelnames=("dtype",))
+_CACHE_BYTES = _telemetry.gauge(
+    "serving_cache_bytes",
+    "bytes of the paged cache: 'pool' is what its pools take on the "
+    "device, 'algorithm' what the model kind must keep for as many "
+    "tokens (a latent row of 576 values lies in 640 lanes)",
+    labelnames=("kind",))
 _PROGRAM_TEMP_BYTES = _telemetry.gauge(
     "serving_program_temp_bytes",
     "temporary device bytes of each compiled serving program, from its "
@@ -357,18 +363,23 @@ def _weight_nbytes(weights):
     return out
 
 
-def _run_layer_stack(scan_layers, layers, x, layer_fn, kc, vc):
-    """THE scan-or-unrolled walker over a [L, ...]-stacked weight tuple:
-    ``layer_fn(lp, li, x, kc, vc) -> (x, kc, vc)`` over the WHOLE
-    stacked caches. Shared by the engine's decode/prefill/verify
-    programs AND the spec-decode DraftRunner, so the pool discipline
-    cannot drift between target and draft. Scanned: the caches ride the
-    scan's CARRY beside ``x`` and only the weights and the layer
-    counter are ``xs`` (a scan cannot alias ``xs`` to ``ys``: caches
-    there cost a second pool and a slab copied in and out per layer);
-    compile flat in depth (the replica cold-start win). Unrolled
-    (``PTPU_SCAN_LAYERS=0``): ``li`` is a python int and the same
-    ``layer_fn`` runs; bitwise identical, compile linear in depth."""
+def _run_layer_stack(scan_layers, layers, x, layer_fn, cache, base=0):
+    """THE scan-or-unrolled walker over one GROUP of like layers, a
+    [n, ...]-stacked weight tuple: ``layer_fn(lp, li, x, cache) ->
+    (x, cache)`` over the WHOLE stacked pools (``cache`` is the tuple of
+    them: K and V for the dense decoder, one latent pool for a latent
+    model). ``li`` is the layer's index in the pools: ``base`` is where
+    this group starts, so the groups of a stack of unlike layers are
+    walked one after another and the pool's layer index runs on. Shared
+    by the engine's decode/prefill/verify programs AND the spec-decode
+    DraftRunner, so the pool discipline cannot drift between target and
+    draft. Scanned: the pools ride the scan's CARRY beside ``x`` (any
+    pytree) and only the weights and the layer counter are ``xs`` (a
+    scan cannot alias ``xs`` to ``ys``: caches there cost a second pool
+    and a slab copied in and out per layer); compile flat in depth (the
+    replica cold-start win). Unrolled (``PTPU_SCAN_LAYERS=0``): ``li``
+    is a python int and the same ``layer_fn`` runs; bitwise identical,
+    compile linear in depth."""
     import jax
     import jax.numpy as jnp
 
@@ -378,13 +389,14 @@ def _run_layer_stack(scan_layers, layers, x, layer_fn, kc, vc):
             lp, li = per
             return layer_fn(lp, li, *carry), None
 
-        (x, kc, vc), _ = jax.lax.scan(
-            step, (x, kc, vc), (layers, jnp.arange(n, dtype=jnp.int32)))
-        return x, kc, vc
+        (x, cache), _ = jax.lax.scan(
+            step, (x, cache),
+            (layers, jnp.arange(base, base + n, dtype=jnp.int32)))
+        return x, cache
     for li in range(n):
-        x, kc, vc = layer_fn(tuple(_layer_slice(w, li) for w in layers),
-                             li, x, kc, vc)
-    return x, kc, vc
+        x, cache = layer_fn(tuple(_layer_slice(w, li) for w in layers),
+                            base + li, x, cache)
+    return x, cache
 
 
 def _pack_weights_stacked(model):
@@ -503,6 +515,265 @@ def _sample_rows(jax, jnp, logits, temps, top_ks, top_ps, key):
     return jnp.where(temps <= 0.0, greedy, sampled)
 
 
+def _rope(x, pos):
+    """Shared framework rope (models/gpt.py) — serving stays
+    bit-identical to training/generate."""
+    from ..models.gpt import _rope_at_positions
+
+    return _rope_at_positions(x, pos)
+
+
+class DenseDecoderServing:
+    """What the engine asks of a model kind (docs/SERVING.md "Model
+    kinds and cache geometry"), answered for the dense rmsnorm + swiglu +
+    rope decoder, MHA or GQA: the cache's geometry (two pools, K and V
+    per head), the packed weights (one group of like layers), the
+    layer's mathematics and the attention over the pools — a decode
+    tick, a prefill chunk, a speculative verify window and the eager
+    group prefill. Any model with the decode contract
+    (``_decode_params()``, or natively stacked) is served through it; a
+    model of another kind hands over its own ``serving_arch()``
+    (models/latent_moe.LatentMoEServing). A pool that comes as a
+    (codes, scales) pair is the int8 page format."""
+
+    cache_names = ("k", "v")
+    #: engine features this model kind refuses at construction, by name
+    refuses = {}
+
+    def __init__(self, model):
+        self.model, self.cfg = model, model.config
+        self.hd = self.cfg.hidden_size // self.cfg.num_heads
+        self.hkv = self.cfg.num_kv_heads
+
+    # -- geometry and weights ---------------------------------------------
+    def cache_shapes(self, num_pages, page):
+        """K and V in the stacked KERNEL layout [L, Hkv, num_pages,
+        page, D]: a layer's pages are exactly what paged_attention
+        consumes (no per-step transposes), and the leading L axis is
+        what the layer scan counts."""
+        shape = (self.cfg.num_layers, self.hkv, num_pages + 1, page,
+                 self.hd)
+        return shape, shape
+
+    def cache_token_bytes(self, itemsize):
+        """Bytes the algorithm must keep a token, over all layers."""
+        return 2 * self.cfg.num_layers * self.hkv * self.hd * itemsize
+
+    def pack(self, int8_weights=False):
+        """{"layers": 9 LEADING-AXIS-STACKED arrays [L, ...] in _block
+        order, "embed", "fnorm", "head"}. Stacked models
+        (GPTForCausalLMPipe / StackedDecoder) pack ZERO-COPY; per-layer
+        models stack their slices (one transient per-layer copy during
+        the stack, then only the stacked copy is retained).
+        ``int8_weights``: the 7 projection slabs are re-packed as
+        (codes int8 [L, h, n], scales f32 [L, 1, n]) tuples — embed,
+        norms and head stay exact (embed also fixes the engine's KV
+        dtype); the zero-copy reference is given up for ~4x less
+        resident bytes."""
+        w = _pack_weights_stacked(self.model)
+        if int8_weights:
+            from ..quant import quantize_weight_cols_int8
+
+            w["layers"] = tuple(
+                quantize_weight_cols_int8(arr)
+                if name in _QUANT_WEIGHT_NAMES else arr
+                for name, arr in zip(_DECODE_WEIGHT_NAMES, w["layers"]))
+        return w
+
+    def groups(self, weights):
+        """[(stacked leaves, layer forward)]: one group."""
+        return [(weights["layers"], self.layer_forward)]
+
+    def carry_in(self, x):
+        """The walker's carry: the hidden state alone."""
+        return x
+
+    def carry_out(self, x):
+        """(hidden state, the program's counts for the host: none)."""
+        return x, None
+
+    # -- layer mathematics ------------------------------------------------
+    def layer_forward(self, li, lp, x, pos0, attend):
+        """One decoder layer: projections + rope + ``attend(li, q, k,
+        v)`` (which owns cache writes and the attention math) + MLP.
+        Shared by the compiled programs and the eager group prefill so
+        their numerics can never diverge."""
+        import jax
+
+        from ..models.gpt import _rms_pure
+
+        ln1, wq, wk, wv, wo, ln2, wg, wu, wd = lp
+        B, S = x.shape[:2]
+        h = _rms_pure(x, ln1)
+        q = _wmat(h, wq).reshape(B, S, self.cfg.num_heads, self.hd)
+        k = _wmat(h, wk).reshape(B, S, self.hkv, self.hd)
+        v = _wmat(h, wv).reshape(B, S, self.hkv, self.hd)
+        q, k = _rope(q, pos0), _rope(k, pos0)
+        o = attend(li, q, k, v)                       # [B, S, Hq, D]
+        x = x + _wmat(o.reshape(B, S, -1), wo)
+        h2 = _rms_pure(x, ln2)
+        return x + _wmat(jax.nn.silu(_wmat(h2, wg)) * _wmat(h2, wu), wd)
+
+    # -- attention over the K and V pools ---------------------------------
+    def paged_attend(self, q, kc, vc, li, tables, lens):
+        """Single-position paged attention over layer ``li`` of the
+        stacked caches: q [B, Hq, D] -> [B, Hq, D]. Exact caches take
+        the Pallas paged kernel, which reads its pages out of the pool
+        by (layer, page); int8 caches take the int8-page Pallas kernel
+        (``paged_attention_int8``: (codes, scales) dequantized in VMEM
+        per fetched page — the PR 12 named follow-up) when the device
+        gate allows, else gather the owned pages, dequantize in HBM,
+        and run the masked reference attention (docs/SERVING.md). Both
+        int8 paths read the SAME codes*scales values; the int8 mode
+        itself engages only behind the quantizer parity gate
+        (``int8_kv_enabled``)."""
+        import jax
+        import jax.numpy as jnp
+
+        if not isinstance(kc, tuple):
+            from ..ops.pallas.decode_attention import paged_attention
+
+            return paged_attention(q, kc, vc, tables, lens, layer=li)
+        mode = _int8_paged_kernel_mode()
+        if mode != "off":
+            from ..ops.pallas.decode_attention import paged_attention_int8
+
+            return paged_attention_int8(
+                q, *kc, *vc, tables, lens, layer=li,
+                interpret=True if mode == "interpret" else None)
+        b, hq, hd = q.shape
+        S = tables.shape[1] * kc[0].shape[3]
+        ck = _kv_gather_rows(kc, li, tables, q.dtype).reshape(
+            self.hkv, b, S, hd)
+        cv = _kv_gather_rows(vc, li, tables, q.dtype).reshape(
+            self.hkv, b, S, hd)
+        rep = hq // self.hkv
+        if rep > 1:
+            ck = jnp.repeat(ck, rep, 0)
+            cv = jnp.repeat(cv, rep, 0)
+        scale = 1.0 / math.sqrt(hd)
+        logits = jnp.einsum("bhd,hbsd->bhs",
+                            (q * scale).astype(jnp.float32),
+                            ck.astype(jnp.float32))
+        mask = jnp.arange(S)[None, None, :] < lens[:, None, None]
+        logits = jnp.where(mask, logits, -1e30)
+        probs = jax.nn.softmax(logits, -1)
+        o = jnp.einsum("bhs,hbsd->bhd", probs, cv.astype(jnp.float32))
+        return o.astype(q.dtype)
+
+    def decode_attend(self, tables, lens):
+        """A decode tick's attention: this token's KV row written, then
+        read back with the rest."""
+        def attend(li, q, k, v, cache):
+            kc, vc = cache
+            kc = _kv_write_run(kc, li, tables, lens, 1, k)
+            vc = _kv_write_run(vc, li, tables, lens, 1, v)
+            o = self.paged_attend(q[:, 0], kc, vc, li, tables, lens + 1)
+            return o[:, None], (kc, vc)               # [B, 1, Hq, D]
+
+        return attend
+
+    def verify_attend(self, tables, lens, C):
+        """A speculative verify window's attention: the C rows written,
+        then the SAME per-position `paged_attend` a plain decode tick at
+        that position would run — position i reads lens+i+1 valid rows,
+        the earlier window rows having just been written with the
+        identical values sequential ticks would have written."""
+        import jax.numpy as jnp
+
+        def attend(li, q, k, v, cache):
+            kc, vc = cache
+            kc = _kv_write_run(kc, li, tables, lens, C, k)
+            vc = _kv_write_run(vc, li, tables, lens, C, v)
+            o = [self.paged_attend(q[:, i], kc, vc, li, tables,
+                                   lens + i + 1) for i in range(C)]
+            return jnp.stack(o, 1), (kc, vc)          # [B, C, Hq, D]
+
+        return attend
+
+    def chunk_attend(self, hist, pos0, nvalid, chunk, page):
+        """A prefill chunk's attention ([B, chunk] positions from
+        ``pos0``): chunk rows attend to [cached prefix + own chunk]
+        causally, against the gathered history."""
+        import jax
+        import jax.numpy as jnp
+
+        B = hist.shape[0]
+        S = hist.shape[1] * page
+        scale = 1.0 / math.sqrt(self.hd)
+        rep = self.cfg.num_heads // self.hkv
+        row_pos = pos0[:, None] + jnp.arange(chunk)[None, :]  # [B, c]
+        cols = jnp.arange(S)
+        mask = cols[None, None, :] <= row_pos[:, :, None]     # [B, c, S]
+
+        def attend(li, q, k, v, cache):
+            # write the chunk's kv FIRST, then gather the prefix back
+            # (one source of truth for the attention operands; in int8
+            # mode both the own-chunk and prefix reads come back
+            # dequantized — identical to what decode will see)
+            kc, vc = cache
+            kc = _kv_write_run(kc, li, hist, pos0, nvalid, k)
+            vc = _kv_write_run(vc, li, hist, pos0, nvalid, v)
+            ck = _kv_gather_rows(kc, li, hist, q.dtype).reshape(
+                self.hkv, B, S, self.hd)
+            cv = _kv_gather_rows(vc, li, hist, q.dtype).reshape(
+                self.hkv, B, S, self.hd)
+            if rep > 1:
+                ck = jnp.repeat(ck, rep, 0)
+                cv = jnp.repeat(cv, rep, 0)
+            logits = jnp.einsum("bchd,hbsd->bhcs",
+                                (q * scale).astype(jnp.float32),
+                                ck.astype(jnp.float32))
+            logits = jnp.where(mask[:, None], logits, -1e30)
+            probs = jax.nn.softmax(logits, -1)
+            o = jnp.einsum("bhcs,hbsd->bchd", probs,
+                           cv.astype(jnp.float32))
+            return o.astype(q.dtype), (kc, vc)           # [B, c, Hq, D]
+
+        return attend
+
+    def group_attend(self, tables, nvalid, S):
+        """The eager group prefill's attention: every prompt from
+        position 0, padded to ``S``, causal over its own rows; each
+        prompt's valid k/v rows go into the pages it owns."""
+        import jax
+        import jax.numpy as jnp
+
+        scale = 1.0 / math.sqrt(self.hd)
+        rep = self.cfg.num_heads // self.hkv
+        mask = jnp.tril(jnp.ones((S, S), bool))
+        pos0 = jnp.zeros(nvalid.shape, jnp.int32)
+
+        def attend(li, q, k, v, cache):
+            kc, vc = cache
+            if isinstance(kc, tuple):
+                # round-trip k/v through the page quantizer BEFORE both
+                # the attention math and the cache write: group prefill,
+                # chunked prefill, and decode all read the SAME
+                # quantized KV (re-quantizing a round-tripped row is
+                # exact — the absmax element always maps to code 127,
+                # so the recomputed scale is identical)
+                from ..memory import (dequantize_rows_int8,
+                                      quantize_rows_int8)
+
+                k = dequantize_rows_int8(*quantize_rows_int8(k), k.dtype)
+                v = dequantize_rows_int8(*quantize_rows_int8(v), v.dtype)
+            ck = jnp.repeat(k, rep, 2) if rep > 1 else k
+            cv = jnp.repeat(v, rep, 2) if rep > 1 else v
+            logits = jnp.einsum("bthd,bshd->bhts",
+                                (q * scale).astype(jnp.float32),
+                                ck.astype(jnp.float32))
+            logits = jnp.where(mask[None, None], logits, -1e30)
+            probs = jax.nn.softmax(logits, -1)
+            o = jnp.einsum("bhts,bshd->bthd", probs,
+                           cv.astype(jnp.float32)).astype(q.dtype)
+            kc = _kv_write_run(kc, li, tables, pos0, nvalid, k)
+            vc = _kv_write_run(vc, li, tables, pos0, nvalid, v)
+            return o, (kc, vc)
+
+        return attend
+
+
 class ContinuousBatchingEngine:
     def __init__(self, model, max_slots=4, page_size=64, num_pages=None,
                  max_seq_len=None, max_new_tokens=32, eos_token_id=None,
@@ -528,8 +799,23 @@ class ContinuousBatchingEngine:
         # prefill routes padded rows' cache writes there
         self._trash_page = num_pages
 
-        hd = cfg.hidden_size // cfg.num_heads
-        self.hd, self.hkv = hd, cfg.num_kv_heads
+        # Model kinds (docs/SERVING.md "Model kinds and cache geometry"):
+        # the engine asks the model's ``serving_arch()`` for the cache's
+        # geometry, the packed weights by group of like layers, each
+        # group's layer mathematics and the attention over its pools;
+        # a model with the dense decoder's decode contract is answered
+        # for by :class:`DenseDecoderServing`. A feature a kind does not
+        # have refuses here, by name.
+        self._arch = self._arch_of(model)
+        asked = {"int8_kv": int8_kv, "int8_weights": int8_weights,
+                 "draft_model": draft_model is not None,
+                 "group prefill (prefill_chunk=None)":
+                     prefill_chunk is None}
+        for name, why in self._arch.refuses.items():
+            if asked.get(name):
+                raise ValueError(
+                    f"{type(model).__name__} does not serve with "
+                    f"{name}: {why}")
 
         # int8 resident weights (docs/QUANT.md): the 7 projection slabs
         # pack as per-output-column int8 codes + f32 scales and every
@@ -540,7 +826,8 @@ class ContinuousBatchingEngine:
         # the pack below, which reads the flag.
         from ..quant import int8_weights_enabled
 
-        self.int8_weights = int8_weights_enabled(int8_weights)
+        self.int8_weights = ("int8_weights" not in self._arch.refuses
+                             and int8_weights_enabled(int8_weights))
 
         self._model = model
         self._weights = self._pack_weights(model)
@@ -560,24 +847,35 @@ class ContinuousBatchingEngine:
         # fp32 per-row scales riding in the page table, ~half the exact
         # mode's KV HBM. Engages only behind the parity probe;
         # PTPU_INT8_KV=0 is the exact escape hatch.
-        self.int8_kv = int8_kv_enabled(int8_kv)
+        self.int8_kv = ("int8_kv" not in self._arch.refuses
+                        and int8_kv_enabled(int8_kv))
 
-        # paged caches, stacked KERNEL layout [L, Hkv, num_pages, page, D]
-        # (per-layer slices are exactly what paged_attention consumes —
-        # no per-step transposes; the leading L axis is what the layer
-        # scan iterates)
+        # paged caches: ``self.cache`` is the tuple of the model kind's
+        # pools, named by ``self.cache_names`` (K and V per head for the
+        # dense decoder, one pool of latent rows for a latent model),
+        # each [L, *, num_pages + 1, page, *]. Every pool is addressed
+        # by the same page tables, and every consumer (programs, swap,
+        # handoff, prefix export) goes through the tuple. int8 pools are
+        # (codes, fp32 per-row scales) pairs.
         dt = self._weights["embed"].dtype
-        self._kv_dtype = dt
-        cache_shape = (cfg.num_layers, self.hkv, num_pages + 1,
-                       page_size, hd)
+        self.cache_names = tuple(self._arch.cache_names)
+        shapes = self._arch.cache_shapes(num_pages, page_size)
         if self.int8_kv:
-            self.kc = (jnp.zeros(cache_shape, jnp.int8),
-                       jnp.zeros(cache_shape[:-1] + (1,), jnp.float32))
-            self.vc = (jnp.zeros(cache_shape, jnp.int8),
-                       jnp.zeros(cache_shape[:-1] + (1,), jnp.float32))
+            self.cache = tuple(
+                (jnp.zeros(shape, jnp.int8),
+                 jnp.zeros(shape[:-1] + (1,), jnp.float32))
+                for shape in shapes)
         else:
-            self.kc = jnp.zeros(cache_shape, dt)
-            self.vc = jnp.zeros(cache_shape, dt)
+            self.cache = tuple(jnp.zeros(shape, dt) for shape in shapes)
+        token_bytes = self._arch.cache_token_bytes(
+            1 if self.int8_kv else dt.itemsize)
+        # what the pools take on the device, and what the algorithm must
+        # keep for as many tokens (a pool's padding to the tile shows as
+        # the difference)
+        _CACHE_BYTES.set(float(sum(_kv_nbytes(c) for c in self.cache)),
+                         labels=("pool",))
+        _CACHE_BYTES.set(float(token_bytes * (num_pages + 1) * page_size),
+                         labels=("algorithm",))
 
         # prefill_only: this engine is the PREFILL half of a
         # disaggregated pair (fleet.disagg) — step() admits and prefills
@@ -593,8 +891,8 @@ class ContinuousBatchingEngine:
         self._next_rid = int(rid_base)
         # weights are argument 0 — NOT closed-over jit constants — so a
         # reload on a live engine feeds the already-compiled step
-        self._decode_jit = jax.jit(self._decode_step, donate_argnums=(4, 5),
-                                   static_argnums=(10,))
+        self._decode_jit = jax.jit(self._decode_step, donate_argnums=(4,),
+                                   static_argnums=(9,))
         self.prefill_batches = 0      # observability: admission group count
         self.preemptions = 0          # pages reclaimed from the youngest
         self._admit_counter = 0
@@ -658,7 +956,7 @@ class ContinuousBatchingEngine:
         # compiles ONCE; swap-in donates the caches (no double buffering)
         self._swap_out_jit = jax.jit(self._swap_gather)
         self._swap_in_jit = jax.jit(self._swap_scatter,
-                                    donate_argnums=(0, 1))
+                                    donate_argnums=(0,))
         # chunked prefill (vLLM-style): admit immediately, write the
         # prompt's KV `prefill_chunk` tokens per TICK so long prompts
         # don't stall the decode latency of running requests
@@ -672,8 +970,12 @@ class ContinuousBatchingEngine:
         # (VERDICT r3 item 7 — the eager per-request chunk loop paid the
         # ~2.5ms/dispatch host cost per layer per request)
         self._prefill_jit = jax.jit(self._prefill_chunk_step,
-                                    donate_argnums=(5, 6))
+                                    donate_argnums=(5,))
         self.prefill_chunk_steps = 0  # observability: jitted pass count
+        self._greedy_consts = None
+        self._first_token_jit = jax.jit(self._first_token_step,
+                                        static_argnums=(6,))
+        self._stats_pending = deque()  # (prefill_tick span, its counts)
         # -- request deadlines / cancellation (docs/SERVING.md) --
         self.cancelled = {}           # rid -> reason, drained by callers
         self.cancellations = 0
@@ -691,7 +993,7 @@ class ContinuousBatchingEngine:
 
             self._draft = DraftRunner(self, draft_model)
             self._verify_jit = jax.jit(self._spec_verify,
-                                       donate_argnums=(4, 5))
+                                       donate_argnums=(4,))
         self.spec_ticks = 0
         self.spec_draft_tokens = 0
         self.spec_accepted_tokens = 0
@@ -715,41 +1017,42 @@ class ContinuousBatchingEngine:
         self.prefill_chunk_cap = None  # L3: per-tick prefill token
                                        #     budget (output-invariant)
 
-    def _pack_weights(self, model):
-        # the decode contract: `_decode_params()` (per-layer weight dicts,
-        # llama.py:66 / gpt.py GPTForCausalLMPipe) + embed/final_norm on
-        # the model or its `.model` core + optional untied `lm_head`.
-        # "layers" is a tuple of 9 LEADING-AXIS-STACKED arrays [L, ...]
-        # in _block order — the tree the layer scan iterates. Stacked
-        # models (GPTForCausalLMPipe / StackedDecoder) pack ZERO-COPY
-        # (the decoder's [L, ...] arrays are referenced as-is); per-layer
-        # models stack their slices (one transient per-layer copy during
-        # the stack, then only the stacked copy is retained).
-        #
-        # int8_weights: the 7 projection slabs are re-packed as
-        # (codes int8 [L, h, n], scales f32 [L, 1, n]) tuples — embed,
-        # norms and head stay exact (embed also fixes the engine's KV
-        # dtype). The stacked zero-copy reference is given up for ~4x
-        # less resident bytes; per-dtype footprint lands in
-        # self.weight_bytes and serving_weight_bytes{dtype}.
-        w = _pack_weights_stacked(model)
-        if self.int8_weights:
-            from ..quant import quantize_weight_cols_int8
+    @staticmethod
+    def _arch_of(model):
+        return (model.serving_arch() if hasattr(model, "serving_arch")
+                else DenseDecoderServing(model))
 
-            w["layers"] = tuple(
-                quantize_weight_cols_int8(arr)
-                if name in _QUANT_WEIGHT_NAMES else arr
-                for name, arr in zip(_DECODE_WEIGHT_NAMES, w["layers"]))
+    def _pack_weights(self, model):
+        # the model kind's packed tree ({"layers", "embed", "fnorm",
+        # "head"}; what "layers" holds is the kind's, see its ``pack``);
+        # the per-dtype footprint lands in self.weight_bytes and
+        # serving_weight_bytes{dtype}
+        if model is not self._arch.model:
+            self._arch = self._arch_of(model)     # a reload's new model
+        w = self._arch.pack(self.int8_weights)
         self.weight_bytes = _weight_nbytes(w)
         for dt, nb in self.weight_bytes.items():
             _WEIGHT_BYTES.set(float(nb), labels=(dt,))
         return w
 
-    @staticmethod
-    def _layer_tuple(weights, li):
-        """Per-layer 9-tuple view of the stacked weight tree
-        (int8-packed entries slice to per-layer (codes, scales))."""
-        return tuple(_layer_slice(w, li) for w in weights["layers"])
+    # -- the pools by name ---------------------------------------------------
+    def _pool(self, name):
+        if name not in self.cache_names:
+            raise AttributeError(
+                f"this engine's cache has no {name!r} pool: its pools are "
+                f"{self.cache_names} (docs/SERVING.md, model kinds)")
+        return self.cache[self.cache_names.index(name)]
+
+    def _set_pool(self, name, value):
+        i = self.cache_names.index(name)
+        self.cache = self.cache[:i] + (value,) + self.cache[i + 1:]
+
+    kc = property(lambda self: self._pool("k"),
+                  lambda self, v: self._set_pool("k", v),
+                  doc="the dense decoder's K pool")
+    vc = property(lambda self: self._pool("v"),
+                  lambda self, v: self._set_pool("v", v),
+                  doc="the dense decoder's V pool")
 
     def reload_weights(self, model=None):
         """Re-read weights from the model (e.g. after an in-place update);
@@ -786,34 +1089,6 @@ class ContinuousBatchingEngine:
             self._cache_admit_floor = self._admit_counter
 
     # -- model math ---------------------------------------------------------
-    @staticmethod
-    def _rope(x, pos):
-        """Shared framework rope (models/gpt.py) — serving stays
-        bit-identical to training/generate."""
-        from ..models.gpt import _rope_at_positions
-
-        return _rope_at_positions(x, pos)
-
-    def _layer_forward(self, li, lp, x, pos0, attend):
-        """One decoder layer of the EAGER prefill paths: projections +
-        rope + `attend(li, q, k, v)` (which owns cache writes and the
-        attention math) + MLP. Shared by group and chunked prefill so
-        their numerics can never diverge."""
-        jax, jnp = self._jax, self._jnp
-        from ..models.gpt import _rms_pure
-
-        ln1, wq, wk, wv, wo, ln2, wg, wu, wd = lp
-        B, S = x.shape[:2]
-        h = _rms_pure(x, ln1)
-        q = _wmat(h, wq).reshape(B, S, self.cfg.num_heads, self.hd)
-        k = _wmat(h, wk).reshape(B, S, self.hkv, self.hd)
-        v = _wmat(h, wv).reshape(B, S, self.hkv, self.hd)
-        q, k = self._rope(q, pos0), self._rope(k, pos0)
-        o = attend(li, q, k, v)                       # [B, S, Hq, D]
-        x = x + _wmat(o.reshape(B, S, -1), wo)
-        h2 = _rms_pure(x, ln2)
-        return x + _wmat(jax.nn.silu(_wmat(h2, wg)) * _wmat(h2, wu), wd)
-
     def _head_tokens(self, last, reqs):
         """final-norm'd last hidden rows [B, H] -> first token per req."""
         jax, jnp = self._jax, self._jnp
@@ -841,7 +1116,7 @@ class ContinuousBatchingEngine:
         the decode loop). Runs eagerly: page-cache writes copy the pool
         once per layer per GROUP; jitting would retrace per padded length
         (bucket lengths first if admission cost ever dominates)."""
-        jax, jnp = self._jax, self._jnp
+        jnp = self._jnp
         from ..models.gpt import _rms_pure
 
         self.prefill_batches += 1
@@ -856,44 +1131,13 @@ class ContinuousBatchingEngine:
         ids = jnp.asarray(ids_np)
         x = w["embed"][ids]                                  # [B, S, H]
         pos0 = jnp.zeros((B,), jnp.int32)
-        scale = 1.0 / math.sqrt(self.hd)
-        rep = self.cfg.num_heads // self.hkv
-        mask = jnp.tril(jnp.ones((S, S), bool))
-
-        tables = jnp.asarray(self._table_rows(reqs))
-        nvalid = jnp.asarray(lens, jnp.int32)
-
-        def attend(li, q, k, v):
-            if self.int8_kv:
-                # round-trip k/v through the page quantizer BEFORE both
-                # the attention math and the cache write: group prefill,
-                # chunked prefill, and decode all read the SAME
-                # quantized KV (re-quantizing a round-tripped row is
-                # exact — the absmax element always maps to code 127,
-                # so the recomputed scale is identical)
-                from ..memory import (dequantize_rows_int8,
-                                      quantize_rows_int8)
-
-                k = dequantize_rows_int8(*quantize_rows_int8(k), k.dtype)
-                v = dequantize_rows_int8(*quantize_rows_int8(v), v.dtype)
-            ck = jnp.repeat(k, rep, 2) if rep > 1 else k
-            cv = jnp.repeat(v, rep, 2) if rep > 1 else v
-            logits = jnp.einsum("bthd,bshd->bhts",
-                                (q * scale).astype(jnp.float32),
-                                ck.astype(jnp.float32))
-            logits = jnp.where(mask[None, None], logits, -1e30)
-            probs = jax.nn.softmax(logits, -1)
-            o = jnp.einsum("bhts,bshd->bthd", probs,
-                           cv.astype(jnp.float32)).astype(q.dtype)
-            # each prompt's valid k/v rows into the pages it owns
-            self.kc = _kv_write_run(self.kc, li, tables, pos0, nvalid, k)
-            self.vc = _kv_write_run(self.vc, li, tables, pos0, nvalid, v)
-            return o
-
-        for li in range(self.cfg.num_layers):
-            x = self._layer_forward(li, self._layer_tuple(w, li), x, pos0,
-                                    attend)
-        x = _rms_pure(x, w["fnorm"])
+        attend = self._arch.group_attend(
+            jnp.asarray(self._table_rows(reqs)),
+            jnp.asarray(lens, jnp.int32), S)
+        # eagerly and unrolled (a scan would compile a padded length)
+        x, self.cache = self._run_layers(w, self._arch.carry_in(x), pos0,
+                                         self.cache, attend, scan=False)
+        x = _rms_pure(self._arch.carry_out(x)[0], w["fnorm"])
         last = x[jnp.arange(B), jnp.asarray(lens - 1)]       # [B, H]
         toks = self._head_tokens(last, reqs)
         for i, r in enumerate(reqs):
@@ -907,99 +1151,69 @@ class ContinuousBatchingEngine:
             self._draft.prefill(reqs, [r.seq_tokens for r in reqs])
         return toks
 
-    def _run_layers(self, weights, x, pos0, kc, vc, attend):
-        """Run every decoder layer of a compiled program over the whole
-        stacked caches through the shared :func:`_run_layer_stack`
-        walker (scan-over-layers per the models.gpt resolver;
-        ``PTPU_SCAN_LAYERS=0`` unrolls bitwise — docs/SERVING.md).
-        ``attend(li, q, k, v, kc, vc) -> (o, kc, vc)`` owns the layer's
-        cache writes and its attention; the rest of the layer is
-        `_layer_forward`, shared with the eager prefill so a program's
-        numerics can never drift from prefill's."""
-        def layer_fn(lp, li, x, kc, vc):
-            def inner(li, q, k, v):
-                nonlocal kc, vc
-                o, kc, vc = attend(li, q, k, v, kc, vc)
-                return o
+    def _run_layers(self, weights, x, pos0, cache, attend, scan=None):
+        """Run every decoder layer over the whole stacked pools through
+        the shared :func:`_run_layer_stack` walker (scan-over-layers per
+        the models.gpt resolver; ``PTPU_SCAN_LAYERS=0``, and the eager
+        group prefill's ``scan=False``, unroll bitwise —
+        docs/SERVING.md), one GROUP of like layers after another, the
+        pools' layer index running on. ``x`` is the model kind's carry
+        (``carry_in``). ``attend(li, *operands, cache) -> (o, cache)``
+        owns the layer's cache writes and its attention; the rest of
+        the layer is the group's forward, the model kind's own, shared
+        by every program so their numerics can never drift apart."""
+        scan = self._scan_layers if scan is None else scan
+        base = 0
+        for layers, forward in self._arch.groups(weights):
+            def layer_fn(lp, li, x, cache, forward=forward):
+                def inner(li, *operands):
+                    nonlocal cache
+                    o, cache = attend(li, *operands, cache)
+                    return o
 
-            x = self._layer_forward(li, lp, x, pos0, inner)
-            return x, kc, vc
+                x = forward(li, lp, x, pos0, inner)
+                return x, cache
 
-        return _run_layer_stack(self._scan_layers, weights["layers"], x,
-                                layer_fn, kc, vc)
+            x, cache = _run_layer_stack(scan, layers, x, layer_fn, cache,
+                                        base)
+            base += layers[0].shape[0]
+        return x, cache
 
-    def _paged_attend(self, q, kc, vc, li, tables, lens):
-        """Single-position paged attention over layer ``li`` of the
-        stacked caches: q [B, Hq, D] -> [B, Hq, D]. Exact caches take
-        the Pallas paged kernel, which reads its pages out of the pool
-        by (layer, page); int8 caches take the int8-page Pallas kernel
-        (``paged_attention_int8``: (codes, scales) dequantized in VMEM
-        per fetched page — the PR 12 named follow-up) when the device
-        gate allows, else gather the owned pages, dequantize in HBM,
-        and run the masked reference attention (docs/SERVING.md). Both
-        int8 paths read the SAME codes*scales values; the int8 mode
-        itself engages only behind the quantizer parity gate
-        (``int8_kv_enabled``)."""
+    def _head_logits(self, weights, x):
+        return (x @ weights["head"] if weights["head"] is not None
+                else x @ weights["embed"].T)
+
+    def _choose(self, lg, temps, top_ks, top_ps, key, do_sample):
+        """A token a row from its logits, inside a compiled program."""
         jax, jnp = self._jax, self._jnp
-        if not isinstance(kc, tuple):
-            from ..ops.pallas.decode_attention import paged_attention
+        if do_sample:
+            return _sample_rows(jax, jnp, lg, temps, top_ks, top_ps, key)
+        # greedy-only: skip the full-vocab sort/cumsum entirely
+        return jnp.argmax(lg.astype(jnp.float32), -1).astype(jnp.int32)
 
-            return paged_attention(q, kc, vc, tables, lens, layer=li)
-        mode = _int8_paged_kernel_mode()
-        if mode != "off":
-            from ..ops.pallas.decode_attention import paged_attention_int8
-
-            return paged_attention_int8(
-                q, *kc, *vc, tables, lens, layer=li,
-                interpret=True if mode == "interpret" else None)
-        b, hq, hd = q.shape
-        dt = self._kv_dtype
-        S = self.pages_per_seq * self.page
-        ck = _kv_gather_rows(kc, li, tables, dt).reshape(self.hkv, b, S, hd)
-        cv = _kv_gather_rows(vc, li, tables, dt).reshape(self.hkv, b, S, hd)
-        rep = hq // self.hkv
-        if rep > 1:
-            ck = jnp.repeat(ck, rep, 0)
-            cv = jnp.repeat(cv, rep, 0)
-        scale = 1.0 / math.sqrt(hd)
-        logits = jnp.einsum("bhd,hbsd->bhs",
-                            (q * scale).astype(jnp.float32),
-                            ck.astype(jnp.float32))
-        mask = jnp.arange(S)[None, None, :] < lens[:, None, None]
-        logits = jnp.where(mask, logits, -1e30)
-        probs = jax.nn.softmax(logits, -1)
-        o = jnp.einsum("bhs,hbsd->bhd", probs, cv.astype(jnp.float32))
-        return o.astype(q.dtype)
-
-    def _decode_step(self, weights, tokens, lens, tables, kc, vc,
+    def _decode_step(self, weights, tokens, lens, tables, cache,
                      temps, top_ks, top_ps, key, do_sample=False):
         """ONE batched decode: tokens [B] (last emitted), lens [B] tokens
         already cached, tables [B, pages_per_seq]. Returns (next [B],
-        new kc, new vc)."""
-        jax, jnp = self._jax, self._jnp
+        the pools). What the model kind counts of a tick (``carry_out``:
+        an expert layer's routed pairs) is appended to ``next``: it
+        comes to the host in the tokens' own fetch."""
+        jnp = self._jnp
         from ..models.gpt import _rms_pure
 
         x = weights["embed"][tokens][:, None]                # [B, 1, H]
-
-        def attend(li, q, k, v, kc, vc):
-            # write this token's KV row, then read it back with the rest
-            kc = _kv_write_run(kc, li, tables, lens, 1, k)
-            vc = _kv_write_run(vc, li, tables, lens, 1, v)
-            o = self._paged_attend(q[:, 0], kc, vc, li, tables, lens + 1)
-            return o[:, None], kc, vc                 # [B, 1, Hq, D]
-
-        x, kc, vc = self._run_layers(weights, x, lens, kc, vc, attend)
+        attend = self._arch.decode_attend(tables, lens)
+        x, cache = self._run_layers(weights, self._arch.carry_in(x), lens,
+                                    cache, attend)
+        x, stats = self._arch.carry_out(x)
         x = _rms_pure(x, weights["fnorm"])[:, 0]
-        lg = (x @ weights["head"] if weights["head"] is not None
-              else x @ weights["embed"].T)
-        if do_sample:
-            nxt = _sample_rows(jax, jnp, lg, temps, top_ks, top_ps, key)
-        else:
-            # greedy-only tick: skip the full-vocab sort/cumsum entirely
-            nxt = jnp.argmax(lg.astype(jnp.float32), -1).astype(jnp.int32)
-        return nxt, kc, vc
+        nxt = self._choose(self._head_logits(weights, x), temps, top_ks,
+                           top_ps, key, do_sample)
+        if stats is not None:
+            nxt = jnp.concatenate([nxt, stats])
+        return nxt, cache
 
-    def _spec_verify(self, weights, toks, lens, tables, kc, vc):
+    def _spec_verify(self, weights, toks, lens, tables, cache):
         """Speculative-decoding verify: ONE target forward over the
         C = K+1 token window [carry, d1..dK] at positions
         lens..lens+K, returning the target's greedy token at EVERY
@@ -1008,7 +1222,7 @@ class ContinuousBatchingEngine:
         Bitwise-greedy-exact by construction (the acceptance contract,
         docs/SERVING.md): projections/norms/rope/MLP are row-local ops
         (batching over positions cannot change a row's value), and
-        attention runs the SAME per-position `_paged_attend` with the
+        attention runs the SAME per-position `paged_attend` with the
         same operands a plain decode tick at that position would see —
         position i reads lens+i+1 valid rows, the earlier window rows
         having just been written with the identical values sequential
@@ -1018,20 +1232,12 @@ class ContinuousBatchingEngine:
 
         C = toks.shape[1]
         x = weights["embed"][toks]                           # [B, C, H]
-
-        def attend(li, q, k, v, kc, vc):
-            kc = _kv_write_run(kc, li, tables, lens, C, k)
-            vc = _kv_write_run(vc, li, tables, lens, C, v)
-            o = [self._paged_attend(q[:, i], kc, vc, li, tables,
-                                    lens + i + 1) for i in range(C)]
-            return jnp.stack(o, 1), kc, vc            # [B, C, Hq, D]
-
-        x, kc, vc = self._run_layers(weights, x, lens, kc, vc, attend)
+        attend = self._arch.verify_attend(tables, lens, C)
+        x, cache = self._run_layers(weights, x, lens, cache, attend)
         x = _rms_pure(x, weights["fnorm"])                   # [B, C, H]
-        lg = (x @ weights["head"] if weights["head"] is not None
-              else x @ weights["embed"].T)
+        lg = self._head_logits(weights, x)
         t = jnp.argmax(lg.astype(jnp.float32), -1).astype(jnp.int32)
-        return t, kc, vc
+        return t, cache
 
     # -- engine surface -----------------------------------------------------
     def submit(self, prompt_ids, temperature=0.0, top_k=0, top_p=1.0,
@@ -1204,13 +1410,13 @@ class ContinuousBatchingEngine:
                 # scratch page, so their uninitialized contents are
                 # irrelevant; the padded h2d volume is the price of the
                 # compile-once scatter)
-                kh = self._swap_stage(snap["k"], n)
-                vh = self._swap_stage(snap["v"], n)
-                self.kc, self.vc = self._swap_in_jit(
-                    self.kc, self.vc,
-                    self._padded_page_vec(req.pages[:n]),
-                    _kv_map(self._jnp.asarray, kh),
-                    _kv_map(self._jnp.asarray, vh))
+                staged = tuple(
+                    _kv_map(self._jnp.asarray,
+                            self._swap_stage(snap[name], n))
+                    for name in self.cache_names)
+                self.cache = self._swap_in_jit(
+                    self.cache, self._padded_page_vec(req.pages[:n]),
+                    staged)
                 req.prefill_pos = snap["prefill_pos"]
                 req.length = snap["length"]
                 req.swapped = None
@@ -1294,55 +1500,28 @@ class ContinuousBatchingEngine:
                 self._emit(req, tok)
         # chunked mode: KV fills incrementally in step()
 
-    def _prefill_chunk_step(self, weights, ids, pos0, nvalid, hist, kc, vc):
+    def _prefill_chunk_step(self, weights, ids, pos0, nvalid, hist, cache):
         """ONE jitted fixed-shape chunk pass over ALL prefilling slots:
         ids [B, c] chunk tokens (zero-padded), pos0 [B] absolute start,
         nvalid [B] real tokens this chunk (0 for a slot with none: its
         writes go to the scratch page), hist [B, pages_per_seq] page
-        tables. Returns (final-normed last-valid hidden [B, H],
-        new kc, new vc). Shapes are engine constants (max_slots x
-        prefill_chunk x pages_per_seq), so this compiles ONCE."""
-        jax, jnp = self._jax, self._jnp
+        tables. Returns (final-normed last-valid hidden [B, H], the
+        pools), and after the hidden rows the model kind's counts if it
+        keeps any.
+        Shapes are engine constants (max_slots x prefill_chunk x
+        pages_per_seq), so this compiles ONCE."""
+        jnp = self._jnp
         from ..models.gpt import _rms_pure
 
         B, c = ids.shape
-        S = self.pages_per_seq * self.page
-        scale = 1.0 / math.sqrt(self.hd)
-        rep = self.cfg.num_heads // self.hkv
         x = weights["embed"][ids]                            # [B, c, H]
-        row_pos = pos0[:, None] + jnp.arange(c)[None, :]     # [B, c]
-        cols = jnp.arange(S)
-        # chunk rows attend to [cached prefix + own chunk] causally
-        mask = cols[None, None, :] <= row_pos[:, :, None]    # [B, c, S]
-        dt = self._kv_dtype
-
-        def attend(li, q, k, v, kc, vc):
-            # write the chunk's kv FIRST, then gather the prefix back
-            # (one source of truth for the attention operands; in int8
-            # mode both the own-chunk and prefix reads come back
-            # dequantized — identical to what decode will see)
-            kc = _kv_write_run(kc, li, hist, pos0, nvalid, k)
-            vc = _kv_write_run(vc, li, hist, pos0, nvalid, v)
-            ck = _kv_gather_rows(kc, li, hist, dt).reshape(
-                self.hkv, B, S, self.hd)
-            cv = _kv_gather_rows(vc, li, hist, dt).reshape(
-                self.hkv, B, S, self.hd)
-            if rep > 1:
-                ck = jnp.repeat(ck, rep, 0)
-                cv = jnp.repeat(cv, rep, 0)
-            logits = jnp.einsum("bchd,hbsd->bhcs",
-                                (q * scale).astype(jnp.float32),
-                                ck.astype(jnp.float32))
-            logits = jnp.where(mask[:, None], logits, -1e30)
-            probs = jax.nn.softmax(logits, -1)
-            o = jnp.einsum("bhcs,hbsd->bchd", probs,
-                           cv.astype(jnp.float32))
-            return o.astype(q.dtype), kc, vc             # [B, c, Hq, D]
-
-        x, kc, vc = self._run_layers(weights, x, pos0, kc, vc, attend)
+        attend = self._arch.chunk_attend(hist, pos0, nvalid, c, self.page)
+        x, cache = self._run_layers(weights, self._arch.carry_in(x), pos0,
+                                    cache, attend)
+        x, stats = self._arch.carry_out(x)
         last_rows = jnp.clip(nvalid - 1, 0, c - 1)
-        last = x[jnp.arange(B), last_rows]                   # [B, H]
-        return _rms_pure(last, weights["fnorm"]), kc, vc
+        last = _rms_pure(x[jnp.arange(B), last_rows], weights["fnorm"])
+        return (last, cache) if stats is None else (last, stats, cache)
 
     def _prefill_tick(self, span):
         """Chunked prefill: advance EVERY prefilling slot by up to
@@ -1380,9 +1559,13 @@ class ContinuousBatchingEngine:
             span.annotate(rows=len(reqs), valid_tokens=int(nvalid.sum()),
                           computed_tokens=B * c)
         with _trace.span("prefill_launch", cat="serve"):
-            last, self.kc, self.vc = self._prefill_jit(
+            last, *stats, self.cache = self._prefill_jit(
                 self._weights, jnp.asarray(ids_np), jnp.asarray(pos0),
-                jnp.asarray(nvalid), jnp.asarray(hist), self.kc, self.vc)
+                jnp.asarray(nvalid), jnp.asarray(hist), self.cache)
+        if stats:
+            # the model kind's counts of a pass are read once the pass
+            # has ended, at a later fetch: no tick waits for them
+            self._stats_pending.append((span, stats[0]))
         self.prefill_chunk_steps += 1
         completed = []
         for i, r in enumerate(reqs):
@@ -1391,8 +1574,8 @@ class ContinuousBatchingEngine:
                 completed.append((i, r))
         if completed:
             with _trace.span("first_token_fetch", cat="serve"):
-                rows = last[jnp.asarray([i for i, _ in completed])]
-                toks = self._head_tokens(rows, [r for _, r in completed])
+                toks = self._first_tokens(last, completed)
+            self._drain_stats()
             for (i, r), tok in zip(completed, toks):
                 self.prefills_completed += 1
                 r.length = len(r.seq_tokens)
@@ -1402,20 +1585,20 @@ class ContinuousBatchingEngine:
                 self._draft.prefill(done_reqs,
                                     [r.seq_tokens for r in done_reqs])
 
-    def _swap_gather(self, kc, vc, pages):
-        """Every layer's rows for `pages` -> [L, Hkv, P, page, D]
-        (P = pages_per_seq, trash-padded; int8 caches yield a
-        (codes, scales) leaf pair). One jitted dispatch per swap-out,
-        then a single host transfer."""
+    def _swap_gather(self, cache, pages):
+        """Every layer's rows for `pages`, of every pool -> a tuple of
+        [L, Hkv, P, page, D] (P = pages_per_seq, trash-padded; int8
+        caches yield a (codes, scales) leaf pair). One jitted dispatch
+        per swap-out, then a single host transfer."""
         g = lambda c: c[:, :, pages]
-        return _kv_map(g, kc), _kv_map(g, vc)
+        return tuple(_kv_map(g, c) for c in cache)
 
-    def _swap_scatter(self, kc, vc, pages, k, v):
-        """Scatter a host snapshot back into the caches at `pages`
-        (trash-padded rows land in the scratch page — harmless by
-        definition). Donates kc/vc."""
+    def _swap_scatter(self, cache, pages, snap):
+        """Scatter a host snapshot (one entry a pool) back into the
+        pools at `pages` (trash-padded rows land in the scratch page —
+        harmless by definition). Donates the pools."""
         sc = lambda c, s: c.at[:, :, pages].set(s)
-        return _kv_map2(sc, kc, k), _kv_map2(sc, vc, v)
+        return tuple(_kv_map2(sc, c, s) for c, s in zip(cache, snap))
 
     def _padded_page_vec(self, pages):
         pad = np.full(self.pages_per_seq, self._trash_page, np.int32)
@@ -1432,14 +1615,15 @@ class ContinuousBatchingEngine:
         mid-prefill victim's untouched prompt pages and grown-but-empty
         decode pages never leave the device; restore re-allocates the
         full reservation from prefill_pos/length bookkeeping)."""
-        k, v = self._swap_out_jit(self.kc, self.vc,
-                                  self._padded_page_vec(r.pages))
+        got = self._swap_out_jit(self.cache, self._padded_page_vec(r.pages))
         written = max(r.length, r.prefill_pos)
         n = min((written + self.page - 1) // self.page, len(r.pages))
         cut = lambda c: np.asarray(c[:, :, :n])
-        r.swapped = {"k": _kv_map(cut, k), "v": _kv_map(cut, v),
-                     "n": n, "prefill_pos": r.prefill_pos,
-                     "length": r.length}
+        # one entry a pool, under the pool's name ("k" and "v" for the
+        # dense decoder, "latent" for a latent model)
+        r.swapped = {name: _kv_map(cut, g)
+                     for name, g in zip(self.cache_names, got)}
+        r.swapped.update(n=n, prefill_pos=r.prefill_pos, length=r.length)
         return r.swapped
 
     # -- prefix cache (content-addressed KV pages) --------------------------
@@ -1680,8 +1864,9 @@ class ContinuousBatchingEngine:
             return self._step()
 
     def _step(self):
-        jax, jnp = self._jax, self._jnp
+        jax = self._jax
         newly = {}
+        self._drain_stats()
         with _trace.span("retire", cat="serve"):
             # deadlines sweep FIRST: an expired request must not occupy
             # a slot (or pages) for even one more tick
@@ -1737,25 +1922,39 @@ class ContinuousBatchingEngine:
             host = (
                 np.asarray([r.generated[-1] for r in rows], np.int32),
                 np.asarray([r.length for r in rows], np.int32),
-                self._table_rows(rows),
-                np.asarray([r.temperature for r in rows], np.float32),
-                np.asarray([r.top_k for r in rows], np.int32),
-                np.asarray([r.top_p for r in rows], np.float32))
+                self._table_rows(rows))
+            if do_sample:
+                host += (
+                    np.asarray([r.temperature for r in rows], np.float32),
+                    np.asarray([r.top_k for r in rows], np.int32),
+                    np.asarray([r.top_p for r in rows], np.float32))
         with _trace.span("decode_upload", cat="serve"):
-            tokens, lens, tables, temps, top_ks, top_ps = (
-                jnp.asarray(a) for a in host)
-            self._key, sub = jax.random.split(self._key)
+            # one batched transfer of what the tick reads. A greedy tick
+            # reads no sampling operand and draws no key: it is handed
+            # the same device constants every tick (the host's serial
+            # work is what a decode tick of a few ms waits on)
+            if do_sample:
+                tokens, lens, tables, temps, top_ks, top_ps = (
+                    jax.device_put(host))
+                self._key, sub = jax.random.split(self._key)
+            else:
+                tokens, lens, tables = jax.device_put(host)
+                temps, top_ks, top_ps, sub = self._greedy_operands()
         with _trace.span("decode_tick",
                          {"live": len(live)} if _trace.enabled() else None,
-                         cat="serve"):
+                         cat="serve") as tick_span:
             with _trace.span("decode_launch", cat="serve"):
-                nxt, self.kc, self.vc = self._decode_jit(
-                    self._weights, tokens, lens, tables, self.kc,
-                    self.vc, temps, top_ks, top_ps, sub, do_sample)
+                nxt, self.cache = self._decode_jit(
+                    self._weights, tokens, lens, tables, self.cache,
+                    temps, top_ks, top_ps, sub, do_sample)
             # the host fetch is the tick's real sync point — inside the
             # decode_tick span so its wall time includes device work
             with _trace.span("decode_fetch", cat="serve"):
                 nxt = np.asarray(nxt)
+            if len(nxt) > pad_to:
+                # the model kind's counts rode behind the tokens
+                self._note_stats(tick_span, nxt[pad_to:])
+                self._drain_stats()
         if self._draft is not None:
             # fallback tick under a draft: mirror the carry token into
             # the draft's KV (proposal discarded) so the draft cache
@@ -1768,6 +1967,52 @@ class ContinuousBatchingEngine:
                 r.length += 1
                 self._emit(r, int(nxt[j]))
         return newly
+
+    def _first_token_step(self, weights, last, temps, top_ks, top_ps, key,
+                          do_sample=False):
+        """The head and the choice of a first token for EVERY row of a
+        prefill pass ([B, H] final-normed hidden rows), one fixed shape:
+        the host keeps the rows that finished their prompt."""
+        return self._choose(self._head_logits(weights, last), temps, top_ks,
+                            top_ps, key, do_sample)
+
+    def _first_tokens(self, last, completed):
+        """First tokens of the rows ``completed`` ([(row, request)]) of a
+        chunked prefill pass, through ONE compiled program over all rows
+        (not an eager program a count of finished rows, each with its
+        own compiles: 64 slots were 580 of them)."""
+        jax = self._jax
+        do_sample = any(r.temperature > 0.0 for _, r in completed)
+        if do_sample:
+            b = self.max_slots
+            temps, top_ks = np.zeros(b, np.float32), np.zeros(b, np.int32)
+            top_ps = np.ones(b, np.float32)
+            for i, r in completed:
+                temps[i], top_ks[i], top_ps[i] = (r.temperature, r.top_k,
+                                                  r.top_p)
+            self._key, sub = jax.random.split(self._key)
+            operands = (*jax.device_put((temps, top_ks, top_ps)), sub)
+        else:
+            operands = self._greedy_operands()
+        toks = np.asarray(self._first_token_jit(
+            self._weights, last, *operands, do_sample))
+        return [int(toks[i]) for i, _ in completed]
+
+    def _drain_stats(self):
+        """Note the counts of the prefill passes that have ended (their
+        arrays are ready: nothing is waited for)."""
+        while self._stats_pending and self._stats_pending[0][1].is_ready():
+            span, stats = self._stats_pending.popleft()
+            self._note_stats(span, np.asarray(stats))
+
+    def _note_stats(self, span, stats):
+        """What a program of the model kind counted of itself (it rode
+        behind the tokens, or beside a prefill pass's hidden rows): the
+        kind names and checks its own counts and keeps its own
+        counters; the engine puts them on the tick's span."""
+        attrs = self._arch.note_stats(stats)
+        if _trace.enabled():
+            span.annotate(**attrs)
 
     def _table_rows(self, rows):
         """Fixed-shape [B, pages_per_seq] page tables (zero-padded; the
@@ -1814,9 +2059,8 @@ class ContinuousBatchingEngine:
         with _trace.span("spec_verify",
                          attrs={"live": len(live), "k": K},
                          cat="serve"):
-            t_out, self.kc, self.vc = self._verify_jit(
-                self._weights, jnp.asarray(toks), lens, tables,
-                self.kc, self.vc)
+            t_out, self.cache = self._verify_jit(
+                self._weights, jnp.asarray(toks), lens, tables, self.cache)
             t_np = np.asarray(t_out)
         accepted_total = 0
         for j, (i, r) in enumerate(live):
@@ -1929,13 +2173,13 @@ class ContinuousBatchingEngine:
         for start in range(0, len(keys), self.pages_per_seq):
             chunk = keys[start: start + self.pages_per_seq]
             pages = [self._prefix_cache[k] for k in chunk]
-            k, v = self._swap_out_jit(self.kc, self.vc,
-                                      self._padded_page_vec(pages))
+            got = self._swap_out_jit(self.cache,
+                                     self._padded_page_vec(pages))
             for i, key in enumerate(chunk):
                 cut = lambda c, i=i: np.asarray(c[:, :, i: i + 1])
-                entries.append({"key": bytes(key),
-                                "k": _kv_map(cut, k),
-                                "v": _kv_map(cut, v)})
+                entries.append({"key": bytes(key), **{
+                    name: _kv_map(cut, g)
+                    for name, g in zip(self.cache_names, got)}})
         self.prefix_pages_exported += len(entries)
         return entries
 
@@ -1957,8 +2201,8 @@ class ContinuousBatchingEngine:
                 break
             pg = self.pool.alloc(1)[0]
             pages = self._jnp.asarray(np.asarray([pg], np.int32))
-            self.kc, self.vc = self._swap_scatter(
-                self.kc, self.vc, pages, e["k"], e["v"])
+            self.cache = self._swap_scatter(
+                self.cache, pages, tuple(e[n] for n in self.cache_names))
             self._prefix_cache[key] = pg
             self._cached_pages.add(pg)
             self._page_ref[pg] = 0
@@ -1966,19 +2210,28 @@ class ContinuousBatchingEngine:
         self.prefix_pages_imported += n
         return n
 
+    def _greedy_operands(self):
+        """The sampling operands of a greedy tick (unread by its
+        program): device constants, made once."""
+        if self._greedy_consts is None:
+            jax, jnp = self._jax, self._jnp
+            b = self.max_slots
+            self._greedy_consts = (
+                jnp.zeros((b,), jnp.float32), jnp.zeros((b,), jnp.int32),
+                jnp.ones((b,), jnp.float32),
+                jax.random.PRNGKey(0))  # never touches self._key's stream
+        return self._greedy_consts
+
     def _dummy_decode_operands(self, do_sample=False):
         """Full-width decode-tick operands whose cache writes land in the
         scratch page — what :meth:`warmup` compiles against."""
-        jax, jnp = self._jax, self._jnp
+        jnp = self._jnp
         b = self.max_slots
         return (self._weights, jnp.zeros((b,), jnp.int32),
                 jnp.zeros((b,), jnp.int32),
                 jnp.full((b, self.pages_per_seq), self._trash_page,
                          jnp.int32),
-                self.kc, self.vc, jnp.zeros((b,), jnp.float32),
-                jnp.zeros((b,), jnp.int32), jnp.ones((b,), jnp.float32),
-                jax.random.PRNGKey(0),  # never touches self._key's stream
-                do_sample)
+                self.cache, *self._greedy_operands(), do_sample)
 
     def decode_program_text(self):
         """StableHLO text of the greedy decode tick (trace + lower, no
@@ -2026,24 +2279,28 @@ class ContinuousBatchingEngine:
         modes = () if self.prefill_only else (
             (False, True) if sample else (False,))
         for do_sample in modes:
-            nxt, self.kc, self.vc = self._warm(
+            nxt, self.cache = self._warm(
                 "decode_sample" if do_sample else "decode",
                 self._decode_jit, *self._dummy_decode_operands(do_sample))
             np.asarray(nxt)           # block: compile + first dispatch
         if self.prefill_chunk is not None:
             B, c = self.max_slots, self.prefill_chunk
-            last, self.kc, self.vc = self._warm(
+            last, *_stats, self.cache = self._warm(
                 "prefill", self._prefill_jit,
                 self._weights, jnp.zeros((B, c), jnp.int32),
                 jnp.zeros((B,), jnp.int32), jnp.zeros((B,), jnp.int32),
-                tables, self.kc, self.vc)
+                tables, self.cache)
             np.asarray(last)
+            # the first-token program holds no pool: compiled and run, not
+            # among ``program_bytes``
+            np.asarray(self._first_token_jit(
+                self._weights, last, *self._greedy_operands(), False))
         if self._draft is not None and not self.prefill_only:
-            t_out, self.kc, self.vc = self._warm(
+            t_out, self.cache = self._warm(
                 "verify", self._verify_jit,
                 self._weights,
                 jnp.zeros((b, self.spec_tokens + 1), jnp.int32),
-                lens, tables, self.kc, self.vc)
+                lens, tables, self.cache)
             np.asarray(t_out)
             self._draft.warmup(tables)
         self.build_seconds = time.perf_counter() - t0

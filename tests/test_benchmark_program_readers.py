@@ -188,4 +188,7 @@ def test_each_new_metric_names_a_reader_and_its_cell(name):
     reader = getattr(importlib.import_module(f"harness.{mod}"), fn)
     # its arguments fit the reader, and an empty window reads as nothing
     assert reader(ctx_of(), **spec["args"]) is None
-    assert [m["name"] for m in bench["per_layer"]][-6:] == list(NEW)
+    # appended together, in this order (later PRs append after them)
+    names = [m["name"] for m in bench["per_layer"]]
+    at = names.index(NEW[0])
+    assert names[at:at + len(NEW)] == list(NEW)

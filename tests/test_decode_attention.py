@@ -362,12 +362,12 @@ class TestPagedAttentionInt8:
     @pytest.mark.parametrize("li", [0, 1, 2])
     def test_serving_paged_attend_kernel_vs_gather_path(self, monkeypatch,
                                                         li):
-        """The engine's int8 `_paged_attend` with the kernel forced
+        """The dense decoder's int8 `paged_attend` with the kernel forced
         (PTPU_PAGED_INT8_KERNEL=interpret) matches the default HBM
         gather+dequant reference path on the same (codes, scales), at
         every layer of a stacked pool."""
         from paddle_tpu.inference.serving import (
-            ContinuousBatchingEngine, _int8_paged_kernel_active)
+            DenseDecoderServing, _int8_paged_kernel_active)
 
         assert not _int8_paged_kernel_active()  # CPU default: off
         monkeypatch.setenv("PTPU_PAGED_INT8_KERNEL", "interpret")
@@ -375,14 +375,12 @@ class TestPagedAttentionInt8:
         monkeypatch.setenv("PTPU_PAGED_INT8_KERNEL", "0")
         assert not _int8_paged_kernel_active()
 
-        # drive the engine method directly on a synthetic cache
+        # drive the model kind's method directly on a synthetic cache
         from paddle_tpu.memory import quantize_rows_int8
 
         class _Shim:
-            _jax, _jnp = jax, jnp
             hkv, page, pages_per_seq = 2, 8, 4
-            _kv_dtype = jnp.float32
-            _paged_attend = ContinuousBatchingEngine._paged_attend
+            _paged_attend = DenseDecoderServing.paged_attend
 
         shim = _Shim()
         b, hq, d = 2, 4, 64

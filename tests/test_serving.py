@@ -20,6 +20,19 @@ def _tiny_model(seed=0):
     return LlamaForCausalLM(cfg)
 
 
+#: the engine's model kinds (docs/SERVING.md): a feature's test runs on
+#: both where the feature is not the dense decoder's alone
+MODEL_KINDS = ("dense", "latent")
+
+
+def _model_of(kind, seed=0):
+    if kind == "dense":
+        return _tiny_model(seed)
+    from _latent_tiny import latent_model
+
+    return latent_model(seed)
+
+
 class TestPagePool:
     def test_alloc_free_cycle(self):
         p = PagePool(4)
@@ -319,11 +332,12 @@ def test_engine_rejects_bad_inputs():
 class TestDeadlinesAndCancel:
     """ISSUE 12 satellite: a stuck client must not hold pages forever."""
 
-    def test_deadline_cancels_queued_and_running(self):
+    @pytest.mark.parametrize("kind", MODEL_KINDS)
+    def test_deadline_cancels_queued_and_running(self, kind):
         import paddle_tpu.telemetry as telemetry
 
         telemetry.enable()
-        model = _tiny_model()
+        model = _model_of(kind)
         rng = np.random.default_rng(8)
         eng = ContinuousBatchingEngine(model, max_slots=1, page_size=16,
                                        max_seq_len=64, max_new_tokens=8,
@@ -349,11 +363,14 @@ class TestDeadlinesAndCancel:
         series = snap["counters"].get("serving_cancellations_total", {})
         assert any("deadline" in k for k in series), series
 
-    def test_cancel_running_request_frees_pages(self):
-        model = _tiny_model()
+    @pytest.mark.parametrize("kind", MODEL_KINDS)
+    def test_cancel_running_request_frees_pages(self, kind):
+        model = _model_of(kind)
         rng = np.random.default_rng(9)
-        eng = ContinuousBatchingEngine(model, max_slots=2, page_size=16,
-                                       max_seq_len=64, max_new_tokens=8)
+        # the latent model prefills by chunks only
+        eng = ContinuousBatchingEngine(
+            model, max_slots=2, page_size=16, max_seq_len=64,
+            max_new_tokens=8, prefill_chunk=None if kind == "dense" else 8)
         keep = eng.submit(rng.integers(1, 96, (5,)).tolist())
         drop = eng.submit(rng.integers(1, 96, (7,)).tolist())
         eng.step()
@@ -364,7 +381,8 @@ class TestDeadlinesAndCancel:
         assert eng.cancelled == {drop: "user"}
         assert eng.pool.available == eng.pool.num_pages
 
-    def test_deadline_on_finished_request_still_completes(self):
+    @pytest.mark.parametrize("kind", MODEL_KINDS)
+    def test_deadline_on_finished_request_still_completes(self, kind):
         """A request whose FINAL token was already delivered must
         retire as a completion even if its deadline expires in the
         tick gap before the retire loop runs (code-review round 2: the
@@ -372,7 +390,7 @@ class TestDeadlinesAndCancel:
         cancelled)."""
         import time as _t
 
-        model = _tiny_model()
+        model = _model_of(kind)
         rng = np.random.default_rng(12)
         eng = ContinuousBatchingEngine(model, max_slots=1, page_size=16,
                                        max_seq_len=64, max_new_tokens=1,
@@ -384,11 +402,12 @@ class TestDeadlinesAndCancel:
         done = eng.step()
         assert rid in done and rid not in eng.cancelled
 
-    def test_cancelled_prefix_pages_still_register(self):
+    @pytest.mark.parametrize("kind", MODEL_KINDS)
+    def test_cancelled_prefix_pages_still_register(self, kind):
         """A cancelled request's COMPLETED prefix pages hold valid KV —
         they register into the prefix cache and a follow-up request
         reuses them."""
-        model = _tiny_model()
+        model = _model_of(kind)
         system = list(range(1, 13))            # 3 full pages @4
         eng = ContinuousBatchingEngine(model, max_slots=1, page_size=4,
                                        max_seq_len=48, max_new_tokens=6,
@@ -408,8 +427,9 @@ class TestScanDecode:
     scan-over-layers body (depth-flat replica cold start); the
     unrolled escape hatch is bitwise."""
 
-    def test_scan_vs_unrolled_bitwise(self, monkeypatch):
-        model = _tiny_model()
+    @pytest.mark.parametrize("kind", MODEL_KINDS)
+    def test_scan_vs_unrolled_bitwise(self, monkeypatch, kind):
+        model = _model_of(kind)
         rng = np.random.default_rng(21)
         prompts = [rng.integers(1, 96, (n,)).tolist() for n in (5, 9)]
 
@@ -425,8 +445,9 @@ class TestScanDecode:
 
         assert serve("1") == serve("0")
 
-    def test_warmup_records_build_seconds(self):
-        model = _tiny_model()
+    @pytest.mark.parametrize("kind", MODEL_KINDS)
+    def test_warmup_records_build_seconds(self, kind):
+        model = _model_of(kind)
         eng = ContinuousBatchingEngine(model, max_slots=2, page_size=16,
                                        max_seq_len=64, max_new_tokens=4,
                                        prefill_chunk=8)
@@ -690,11 +711,12 @@ def test_batched_prefill_single_compile_and_throughput():
     assert sizes == 1, sizes
 
 
-def test_batched_prefill_advances_all_slots_together():
+@pytest.mark.parametrize("kind", MODEL_KINDS)
+def test_batched_prefill_advances_all_slots_together(kind):
     """Two long prompts admitted together finish prefill on the same tick
     count a single request would need (they share the batched pass), not
     2x (the r3 one-request-per-tick behavior)."""
-    model = _tiny_model(seed=4)
+    model = _model_of(kind, seed=4)
     eng = ContinuousBatchingEngine(model, max_slots=4, page_size=16,
                                    max_new_tokens=2, prefill_chunk=4)
     prompt = list(range(1, 17))          # 16 tokens -> 4 chunks of 4
