@@ -160,21 +160,78 @@ def _table_page(tables, bi, j, num_pages):
     return jnp.clip(tables[bi, j], jnp.int32(0), jnp.int32(num_pages - 1))
 
 
-def _pool_spec(page, width, num_pages):
-    """One page of one kv head of one layer, read where it lies in the
-    stacked pool: the layer axis is squeezed (the kernel sees
-    ``[1, 1, page, width]``), the block table picks the page."""
+# The dense walk. A grid step is one row x ``group`` pages x EVERY kv head:
+# a page's block is all heads' rows of it ([Hkv, 1, page, D] of the stacked
+# pool, 128 KB at 8 heads x 64 x 128 x bf16), ``group`` such blocks a step
+# (each an operand of its own, as the latent kernel passes its pool), and
+# the step's ``group * page`` tokens go through one pair of head-batched
+# matmuls. A step's fixed cost and a page's DMA are paid once for all
+# heads, and a step past the row's length is skipped whole.
 
-    def index(bi, h, j, tables, lens, layer):
-        return (layer[0], h, _table_page(tables, bi, j, num_pages), 0, 0)
+# what a step's double-buffered K and V blocks may take of scoped VMEM (a
+# quarter of a v5e's 16 MiB; the step's tiles and scores take as much again)
+_STEP_VMEM = 4 * 1024 * 1024
 
-    return pl.BlockSpec((None, 1, 1, page, width), index)
+
+def _pages_per_step(hkv, page, d, itemsize, pages_per_seq):
+    """How many pages a grid step takes: the most whose K and V blocks,
+    double-buffered (``4 * group * hkv * page * d * itemsize`` bytes), fit
+    :data:`_STEP_VMEM`; at most the table's columns, at least one. 8 at 8
+    heads x 64 x 128 x bf16, 2 at 32 heads."""
+    return int(max(1, min(pages_per_seq,
+                          _STEP_VMEM // (4 * hkv * page * d * itemsize))))
 
 
-def _paged_kernel(tables_ref, len_ref, layer_ref, q_ref, k_ref, v_ref,
-                  o_ref, acc, m_scr, l_scr, *, scale, page, npages):
+def _fetch_table(tables, lengths, page, group, num_pages):
+    """The block table as the grid fetches it, ``[B, steps * group]``:
+    column ``j * group + g`` is the page operand ``g`` names in row b's
+    step ``j``. A column the row's length reaches names its page (clamped
+    into the pool: entries past the length may be garbage). A column past
+    the length names what operand ``g`` named in the grid step before, and
+    so on back across rows, so the pipeline sees an unchanged block index
+    and fetches nothing: not the pages past a row's length, not the
+    ``group - n`` operands of a row with ``n`` live pages, nothing at all
+    for an empty slot."""
+    b, pps = tables.shape
+    steps = -(-pps // group)
+    i32 = jnp.int32
+    t = jnp.clip(tables, i32(0), i32(num_pages - 1))
+    t = jnp.pad(t, ((0, 0), (0, steps * group - pps)))
+    live = (jnp.arange(steps * group, dtype=i32)[None] * i32(page)
+            < lengths[:, None])
+    # operand g's blocks in grid order are column g of [B * steps, group]:
+    # fill each dead entry forward from the last live one above it
+    t, live = (a.reshape(b * steps, group) for a in (t, live))
+    at = jnp.where(live, jnp.arange(b * steps, dtype=i32)[:, None], i32(0))
+    at = jax.lax.cummax(at, axis=0)
+    return jnp.take_along_axis(t, at, axis=0).reshape(b, steps * group)
+
+
+def _exact_page(ref):
+    """A page as it lies in the pool: ``[Hkv, page, D]``."""
+    return ref[:, 0]
+
+
+def _int8_page(codes, scales):
+    """A page of ``memory.quantize_rows_int8`` codes times its lane-dense
+    scales (``[Hkv, 1, 8, page]``, see :func:`paged_attention_int8`),
+    float32: the codes * scales product of the gather path, so only a
+    quarter of the exact cache's bytes cross HBM -> VMEM."""
+    return (codes[:, 0].astype(jnp.float32)
+            * scales[:, 0, 0][:, :, None])
+
+
+def _paged_kernel(fetch_ref, len_ref, layer_ref, q_ref, *refs, scale, page,
+                  steps, group, load, width):
+    """One row's walk over its pages, ``group`` a step, for all kv heads.
+    ``load`` makes a page's ``[Hkv, page, D]`` of its ``width`` operands
+    (the exact rows; int8 codes and their scales); the K pages' operands
+    come first, then the V pages', then the output and the running
+    softmax state ``[Hkv, rep, *]``."""
+    n = group * width
+    o_ref, acc, m_scr, l_scr = refs[2 * n:]
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _():
@@ -183,84 +240,152 @@ def _paged_kernel(tables_ref, len_ref, layer_ref, q_ref, k_ref, v_ref,
         l_scr[:] = jnp.zeros_like(l_scr)
 
     length = len_ref[b]
+    start = j * group * page
 
-    @pl.when(j * page < length)
+    def tile(pool):
+        # the step's pages as one [Hkv, group * page, D] tile (an operand
+        # past the length holds some stale page, masked by its positions)
+        pages = [load(*pool[g * width:(g + 1) * width])
+                 for g in range(group)]
+        return pages[0] if group == 1 else jnp.concatenate(pages, 1)
+
+    @pl.when(start < length)           # a step past the length: nothing
     def _():
-        q = q_ref[0, 0]                # [rep, d]
-        k = k_ref[0, 0]                # [page, d]
-        v = v_ref[0, 0]
+        k, v = tile(refs[:n]), tile(refs[n:2 * n])
+        q = q_ref[0]                   # [Hkv, rep, D]
+        if width > 1:                  # dequantized pages are float32
+            q = q.astype(k.dtype)
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale                      # [rep, page]
-        pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + j * page
+            q, k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * scale   # [Hkv, rep, T]
+        pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2) + start
         s = jnp.where(pos < length, s, NEG_INF)
 
-        m_prev = m_scr[:, 0:1]
+        m_prev = m_scr[:, :, 0:1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_scr[:, 0:1] = alpha * l_scr[:, 0:1] + jnp.sum(p, -1, keepdims=True)
+        l_scr[:, :, 0:1] = (alpha * l_scr[:, :, 0:1]
+                            + jnp.sum(p, -1, keepdims=True))
         acc[:] = acc[:] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_scr[:, 0:1] = m_new
+            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)           # [Hkv, rep, D]
+        m_scr[:, :, 0:1] = m_new
 
-    @pl.when(j == npages - 1)
+    @pl.when(j == steps - 1)
     def _():
-        l = l_scr[:, 0:1]
-        o_ref[0, 0] = (acc[:] / jnp.where(l == 0.0, ONE_F32, l)).astype(o_ref.dtype)
+        l = l_scr[:, :, 0:1]
+        o_ref[0] = (acc[:] / jnp.where(l == 0.0, ONE_F32, l)).astype(
+            o_ref.dtype)
 
 
-def _paged_int8_kernel(tables_ref, len_ref, layer_ref, q_ref, kc_ref,
-                       ks_ref, vc_ref, vs_ref, o_ref, acc, m_scr, l_scr,
-                       *, scale, page, npages):
-    """Paged decode over int8 KV pages: dequantize (codes, scales)
-    INSIDE the kernel, so only ~1/4 of the exact cache's bytes cross
-    HBM->VMEM per token (int8 codes + one f32 scale per head_dim row vs
-    f32/bf16 rows) — the serving int8_kv mode's gather+dequantize-in-HBM
-    path becomes a streaming read (docs/SERVING.md)."""
-    b = pl.program_id(0)
-    j = pl.program_id(2)
+def _page_spec(hkv, rows, lanes, g, group, sliced=False):
+    """Operand ``g``'s block of a pool ``[L, Hkv, P, rows, lanes]``: every
+    kv head's rows of one page of one layer, read where it lies (the layer
+    axis squeezed: the kernel sees ``[Hkv, 1, rows, lanes]``); the fetch
+    table picks the page. ``sliced``: the layer was sliced out already,
+    the pool is a stack of one."""
 
-    @pl.when(j == 0)
-    def _():
-        acc[:] = jnp.zeros_like(acc)
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
+    def index(bi, j, fetch, lens, layer):
+        return (0 if sliced else layer[0], 0, fetch[bi, j * group + g], 0, 0)
 
-    length = len_ref[b]
+    return pl.BlockSpec((None, hkv, 1, rows, lanes), index)
 
-    @pl.when(j * page < length)
-    def _():
-        q = q_ref[0, 0]                # [rep, d]
-        # per-row dequant: codes [page, d] int8 * scale [page] f32 —
-        # the quantize_rows_int8 grid (block = the head_dim row the
-        # page table already addresses)
-        k = kc_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0, 0][:, None]
-        v = vc_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0, 0][:, None]
-        s = jax.lax.dot_general(
-            q.astype(jnp.float32), k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32
-        ) * scale                      # [rep, page]
-        pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + j * page
-        s = jnp.where(pos < length, s, NEG_INF)
 
-        m_prev = m_scr[:, 0:1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[:, 0:1] = alpha * l_scr[:, 0:1] + jnp.sum(p, -1, keepdims=True)
-        acc[:] = acc[:] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_scr[:, 0:1] = m_new
+# jitted so that a step's operands, the same pool ``group`` times over, are
+# one argument of one program also when the call is made eagerly (op by
+# op each would be a parameter of its own, counted against the device's
+# memory ``group`` times); under a caller's jit this is inlined
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def _paged_call(q, pools, block_tables, lengths, layer, *, scale, interpret):
+    """The dense walk over layer ``layer`` (i32[1]) of stacked ``pools``:
+    ``(K, V)`` exact pages, or ``(K codes, K scales, V codes, V scales)``
+    int8 ones."""
+    width = len(pools) // 2            # operands a page a pool
+    int8 = width == 2
+    b, hq, d = q.shape
+    _, hkv, num_pages, page, _ = pools[0].shape
+    rep = hq // hkv
+    pps = block_tables.shape[1]
+    # an int8 page is float32 once dequantized: that is what a step holds
+    group = _pages_per_step(hkv, page, d,
+                            4 if int8 else pools[0].dtype.itemsize, pps)
+    steps = -(-pps // group)
+    scale = scale if scale is not None else 1.0 / (d ** 0.5)
+    lengths = lengths.astype(jnp.int32)
+    if int8:
+        # the codes are read where they lie; the scales cannot be. Held
+        # as [.., page, 1] columns, Mosaic would want each padded to 128
+        # lanes, a relayout of the whole pool of them. So the layer's
+        # scales (1/32 of its codes' bytes at D=128) are sliced out and
+        # laid [1, Hkv, P, 8, page]: page along the lanes, sublane-padded
+        # (the lse8 pattern: Mosaic blocks need >= 8 sublanes)
+        def lane_dense(scales):
+            s = jax.lax.dynamic_index_in_dim(scales, layer[0], 0,
+                                             keepdims=False)
+            return jnp.broadcast_to(s.reshape(1, hkv, num_pages, 1, page),
+                                    (1, hkv, num_pages, 8, page))
 
-    @pl.when(j == npages - 1)
-    def _():
-        l = l_scr[:, 0:1]
-        o_ref[0, 0] = (acc[:] / jnp.where(l == 0.0, ONE_F32, l)).astype(o_ref.dtype)
+        pools = (pools[0], lane_dense(pools[1]),
+                 pools[2], lane_dense(pools[3]))
+
+    def specs(g):
+        codes = _page_spec(hkv, page, d, g, group)
+        return ([codes, _page_spec(hkv, 8, page, g, group, sliced=True)]
+                if int8 else [codes])
+
+    def row(bi, j, fetch, lens, li):
+        return (bi, 0, 0, 0)
+
+    kern = functools.partial(
+        _paged_kernel, scale=np.float32(scale), page=page, steps=steps,
+        group=group, load=_int8_page if int8 else _exact_page, width=width)
+    with jax.enable_x64(False):
+        fetch = _fetch_table(block_tables.astype(jnp.int32), lengths, page,
+                             group, num_pages)
+        out = pl.pallas_call(
+            kern,
+            # the trace's name: kernel.paged_attention_roofline.decode
+            # reads the operations that start with it
+            name="paged_attention_int8" if int8 else "paged_attention",
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=3,
+                grid=(b, steps),
+                in_specs=[pl.BlockSpec((1, hkv, rep, d), row)]
+                + 2 * [s for g in range(group) for s in specs(g)],
+                out_specs=pl.BlockSpec((1, hkv, rep, d), row),
+                scratch_shapes=[
+                    pltpu.VMEM((hkv, rep, d), jnp.float32),
+                    pltpu.VMEM((hkv, rep, 128), jnp.float32),
+                    pltpu.VMEM((hkv, rep, 128), jnp.float32),
+                ],
+            ),
+            out_shape=jax.ShapeDtypeStruct((b, hkv, rep, d), q.dtype),
+            interpret=interpret,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary")),
+            cost_estimate=pl.CostEstimate(
+                flops=4 * b * hq * pps * page * d,
+                bytes_accessed=2 * b * hq * d * q.dtype.itemsize
+                + 2 * b * hkv * pps * page * (d + 4 if int8 else
+                                              d * pools[0].dtype.itemsize),
+                transcendentals=b * hq * pps * page,
+            ),
+        )(fetch, lengths, layer, q.reshape(b, hkv, rep, d),
+          *(p for pool in (pools[:width], pools[width:])
+            for g in range(group) for p in pool))
+    return out.reshape(b, hq, d)
+
+
+def _paged_walk(q, pools, block_tables, lengths, layer, scale, interpret):
+    """What both entries do before the walk: the pools stacked, the layer
+    an operand, ``interpret`` settled."""
+    from . import use_interpret
+
+    pools, layer = _stacked(pools, layer)
+    return _paged_call(
+        q, pools, block_tables, lengths, layer, scale=scale,
+        interpret=use_interpret() if interpret is None else bool(interpret))
 
 
 def paged_attention_int8(q, k_codes, k_scales, v_codes, v_scales,
@@ -270,80 +395,16 @@ def paged_attention_int8(q, k_codes, k_scales, v_codes, v_scales,
     ``int8_kv=True`` storage: ``memory.quantize_rows_int8`` codes
     ``[L, Hkv, NumPages, PageSize, D]`` int8 + scales
     ``[L, Hkv, NumPages, PageSize, 1]`` f32, read at ``layer``; or one
-    layer's four-dimensional pages with ``layer`` left out). The codes
-    are addressed in place by (layer, page); only the layer's scales
-    are sliced out, to be laid along the lanes.
-    Dequantization happens in VMEM per fetched page — numerically
-    identical to gathering the owned pages and dequantizing in HBM
-    (same codes * scales product), without ever materializing the
-    dequantized cache.
+    layer's four-dimensional pages with ``layer`` left out). The walk is
+    :func:`paged_attention`'s, over pages dequantized in VMEM as they
+    arrive: numerically identical to gathering the owned pages and
+    dequantizing in HBM (same codes * scales product, float32
+    throughout), without ever materializing the dequantized cache. The
+    codes are addressed in place by (layer, page); only the layer's
+    scales are sliced out, to be laid along the lanes.
     """
-    from . import use_interpret
-
-    if interpret is None:
-        interpret = use_interpret()
-    (k_codes, k_scales, v_codes, v_scales), layer = _stacked(
-        (k_codes, k_scales, v_codes, v_scales), layer)
-    li = layer[0]
-    b, hq, d = q.shape
-    _, hkv, num_pages, page, _ = k_codes.shape
-    rep = hq // hkv
-    pages_per_seq = block_tables.shape[1]
-    scale = scale if scale is not None else 1.0 / (d ** 0.5)
-
-    qg = q.reshape(b, hkv, rep, d)
-    codes = _pool_spec(page, d, num_pages)
-
-    def lane_dense(scales):
-        # the codes are read where they lie; the scales cannot be. Held
-        # as [.., page, 1] columns, Mosaic would want each padded to 128
-        # lanes, a relayout of the whole pool of them. So the layer's
-        # scales (1/32 of its codes' bytes at D=128) are sliced out and
-        # laid [Hkv, P, 8, page]: page along the lanes, sublane-padded
-        # (the lse8 pattern: Mosaic blocks need >= 8 sublanes)
-        s = jax.lax.dynamic_index_in_dim(scales, li, 0, keepdims=False)
-        return jnp.broadcast_to(s.reshape(hkv, num_pages, 1, page),
-                                (hkv, num_pages, 8, page))
-
-    scales = pl.BlockSpec(
-        (1, 1, 8, page), lambda bi, h, j, tables, lens, layer: (
-            h, _table_page(tables, bi, j, num_pages), 0, 0))
-    kern = functools.partial(_paged_int8_kernel, scale=scale, page=page,
-                             npages=pages_per_seq)
-    with jax.enable_x64(False):
-        out = pl.pallas_call(
-            kern,
-            name="paged_attention_int8",
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=3,
-                grid=(b, hkv, pages_per_seq),
-                in_specs=[
-                    pl.BlockSpec((1, 1, rep, d),
-                                 lambda bi, h, j, T, L, li: (bi, h, 0, 0)),
-                    codes, scales, codes, scales,
-                ],
-                out_specs=pl.BlockSpec(
-                    (1, 1, rep, d), lambda bi, h, j, T, L, li: (bi, h, 0, 0)),
-                scratch_shapes=[
-                    pltpu.VMEM((rep, d), jnp.float32),
-                    pltpu.VMEM((rep, 128), jnp.float32),
-                    pltpu.VMEM((rep, 128), jnp.float32),
-                ],
-            ),
-            out_shape=jax.ShapeDtypeStruct((b, hkv, rep, d), q.dtype),
-            interpret=interpret,
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")),
-            cost_estimate=pl.CostEstimate(
-                flops=4 * b * hq * pages_per_seq * page * d,
-                bytes_accessed=(b * hq * d * q.dtype.itemsize
-                                + 2 * b * hkv * pages_per_seq * page
-                                * (d + 4)),
-                transcendentals=b * hq * pages_per_seq * page,
-            ),
-        )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32), layer,
-          qg, k_codes, lane_dense(k_scales), v_codes, lane_dense(v_scales))
-    return out.reshape(b, hq, d)
+    return _paged_walk(q, (k_codes, k_scales, v_codes, v_scales),
+                       block_tables, lengths, layer, scale, interpret)
 
 
 def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
@@ -355,59 +416,14 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
     over the whole pool and never slice a layer out of it), or one
     layer's pages [Hkv, NumPages, PageSize, D] with ``layer`` left out;
     block_tables [B, PagesPerSeq] (page ids per sequence, row-major);
-    lengths [B] valid kv length. The BlockSpec index map reads the layer
-    and the block table via scalar prefetch, so only the pages a
-    sequence actually owns are fetched from HBM.
+    lengths [B] valid kv length. A grid step is one row, every kv head
+    and as many pages as :func:`_pages_per_step` allows, put through one
+    pair of head-batched matmuls; the index maps read the layer and the
+    block table via scalar prefetch, so only the pages a row's length
+    reaches are fetched from HBM, and steps past it are skipped.
     """
-    from . import use_interpret
-
-    if interpret is None:
-        interpret = use_interpret()
-    (k_pages, v_pages), layer = _stacked((k_pages, v_pages), layer)
-    b, hq, d = q.shape
-    _, hkv, num_pages, page, _ = k_pages.shape
-    rep = hq // hkv
-    pages_per_seq = block_tables.shape[1]
-    scale = scale if scale is not None else 1.0 / (d ** 0.5)
-
-    qg = q.reshape(b, hkv, rep, d)
-    pages = _pool_spec(page, d, num_pages)
-    kern = functools.partial(_paged_kernel, scale=scale, page=page,
-                             npages=pages_per_seq)
-    with jax.enable_x64(False):
-        out = pl.pallas_call(
-            kern,
-            name="paged_attention",
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=3,
-                grid=(b, hkv, pages_per_seq),
-                in_specs=[
-                    pl.BlockSpec((1, 1, rep, d),
-                                 lambda bi, h, j, T, L, li: (bi, h, 0, 0)),
-                    pages, pages,
-                ],
-                out_specs=pl.BlockSpec(
-                    (1, 1, rep, d), lambda bi, h, j, T, L, li: (bi, h, 0, 0)),
-                scratch_shapes=[
-                    pltpu.VMEM((rep, d), jnp.float32),
-                    pltpu.VMEM((rep, 128), jnp.float32),
-                    pltpu.VMEM((rep, 128), jnp.float32),
-                ],
-            ),
-            out_shape=jax.ShapeDtypeStruct((b, hkv, rep, d), q.dtype),
-            interpret=interpret,
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")),
-            cost_estimate=pl.CostEstimate(
-                flops=4 * b * hq * pages_per_seq * page * d,
-                bytes_accessed=(b * hq * d
-                                + 2 * b * hkv * pages_per_seq * page * d)
-                * q.dtype.itemsize,
-                transcendentals=b * hq * pages_per_seq * page,
-            ),
-        )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32), layer,
-          qg, k_pages, v_pages)
-    return out.reshape(b, hq, d)
+    return _paged_walk(q, (k_pages, v_pages), block_tables, lengths, layer,
+                       scale, interpret)
 
 
 # ----------------------------------------------------------------- latent

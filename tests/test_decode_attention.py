@@ -456,3 +456,194 @@ class TestStackedPool:
         with pytest.raises(ValueError, match="have none"):
             kernel(q, *(p[0] for p in pools), tables, lens, layer=0,
                    interpret=True)
+
+
+class TestPagedWalk:
+    """The dense walk's grid (ISSUE 31): one row x a group of pages x
+    every kv head a step, pages past a row's length neither fetched nor
+    computed. Exact and int8 pools against the dense float32 reference,
+    at the edges of a page, of a group and of the table."""
+
+    L, PAGE, D = 2, 8, 64
+
+    def _pools(self, pool, hkv, num_pages, page=None, d=None, seed=40):
+        from paddle_tpu.memory import quantize_rows_int8
+        from paddle_tpu.ops.pallas.decode_attention import (
+            paged_attention_int8)
+
+        shape = (self.L, hkv, num_pages, page or self.PAGE, d or self.D)
+        k, v = _rand(shape, seed=seed), _rand(shape, seed=seed + 1)
+        if pool == "exact":
+            return paged_attention, (k, v), k, v
+        kq, ks = quantize_rows_int8(k)
+        vq, vs = quantize_rows_int8(v)
+        return (paged_attention_int8, (kq, ks, vq, vs),
+                kq.astype(jnp.float32) * ks, vq.astype(jnp.float32) * vs)
+
+    @staticmethod
+    def _ref(q, k, v, tables, lengths):
+        """One layer's [Hkv, P, page, D] float32 pages gathered dense;
+        a row of length 0 reads zeros."""
+        b = q.shape[0]
+        hkv, num_pages, _, d = k.shape
+        t = np.clip(np.asarray(tables), 0, num_pages - 1)
+        kd = jnp.swapaxes(k[:, t].reshape(hkv, b, -1, d), 0, 1)
+        vd = jnp.swapaxes(v[:, t].reshape(hkv, b, -1, d), 0, 1)
+        out = ref_decode(q, kd, vd, lengths)
+        return jnp.where(lengths[:, None, None] > 0, out, 0.0)
+
+    def _check(self, kernel, pools, k, v, q, tables, lengths, li=1):
+        out = kernel(q, *pools, tables, lengths, layer=li, interpret=True)
+        ref = self._ref(q, k[li], v[li], tables, lengths)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=2e-5, atol=2e-5)
+
+    @pytest.mark.parametrize("pool", ["exact", "int8"])
+    @pytest.mark.parametrize("case", ["zero", "one", "page-1", "page",
+                                      "page+1", "full-1", "full", "mixed"])
+    def test_lengths_at_the_edges(self, case, pool):
+        pps, hq, hkv = 6, 4, 2
+        full = pps * self.PAGE
+        lens = {"zero": [0, 0, 0], "one": [1, 1, 1],
+                "page-1": [self.PAGE - 1] * 3, "page": [self.PAGE] * 3,
+                "page+1": [self.PAGE + 1] * 3, "full-1": [full - 1] * 3,
+                "full": [full] * 3,
+                "mixed": [0, full, 1, self.PAGE + 1, 0, full - 1]}[case]
+        b = len(lens)
+        num_pages = b * pps + 2
+        kernel, pools, k, v = self._pools(pool, hkv, num_pages)
+        tables = jnp.asarray(np.random.default_rng(41).permutation(
+            num_pages)[: b * pps].reshape(b, pps).astype(np.int32))
+        self._check(kernel, pools, k, v, _rand((b, hq, self.D), seed=42),
+                    tables, jnp.asarray(lens, jnp.int32))
+
+    @pytest.mark.parametrize("pool", ["exact", "int8"])
+    @pytest.mark.parametrize("pps", [5, 13])
+    def test_table_not_a_multiple_of_the_group(self, pps, pool):
+        """At 8 heads x 64 x 128 x float32 the rule takes 4 pages a
+        step: 5 columns are 4 + 1, 13 are 3 x 4 + 1."""
+        from paddle_tpu.ops.pallas.decode_attention import _pages_per_step
+
+        b, hq, hkv, page, d = 2, 8, 8, 64, 128
+        assert _pages_per_step(hkv, page, d, 4, pps) == 4
+        num_pages = b * pps + 1
+        kernel, pools, k, v = self._pools(pool, hkv, num_pages, page, d)
+        tables = jnp.asarray(np.random.default_rng(43).permutation(
+            num_pages)[: b * pps].reshape(b, pps).astype(np.int32))
+        lens = jnp.asarray([pps * page - 3, 4 * page + 1], jnp.int32)
+        self._check(kernel, pools, k, v, _rand((b, hq, d), seed=44),
+                    tables, lens)
+
+    @pytest.mark.parametrize("pool", ["exact", "int8"])
+    @pytest.mark.parametrize("poison", [10**6, -7, 2**31 - 1])
+    def test_garbage_table_entries_past_the_length(self, poison, pool):
+        b, pps, hq, hkv = 3, 6, 4, 2
+        num_pages = b * pps
+        kernel, pools, k, v = self._pools(pool, hkv, num_pages)
+        tables = np.random.default_rng(45).permutation(
+            num_pages).reshape(b, pps).astype(np.int32)
+        lens = np.asarray([self.PAGE, 0, 2 * self.PAGE + 3], np.int32)
+        clean = jnp.asarray(tables)
+        for i, n in enumerate(lens):
+            tables[i, -(-n // self.PAGE):] = poison
+        q = _rand((b, hq, self.D), seed=46)
+        out = kernel(q, *pools, jnp.asarray(tables), jnp.asarray(lens),
+                     layer=0, interpret=True)
+        ref = self._ref(q, k[0], v[0], clean, jnp.asarray(lens))
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=2e-5, atol=2e-5)
+
+    @pytest.mark.parametrize("pool", ["exact", "int8"])
+    @pytest.mark.parametrize("rep", [1, 2, 4, 8])
+    def test_query_heads_a_kv_head(self, rep, pool):
+        b, pps, hkv = 2, 4, 2
+        num_pages = b * pps + 1
+        kernel, pools, k, v = self._pools(pool, hkv, num_pages)
+        tables = jnp.asarray(np.random.default_rng(47).permutation(
+            num_pages)[: b * pps].reshape(b, pps).astype(np.int32))
+        self._check(kernel, pools, k, v,
+                    _rand((b, hkv * rep, self.D), seed=48), tables,
+                    jnp.asarray([3 * self.PAGE + 5, 9], jnp.int32))
+
+    @pytest.mark.parametrize("pool", ["exact", "int8"])
+    @pytest.mark.parametrize("how", ["int", "scan", "four-dimensional"])
+    def test_the_layer_named_three_ways(self, how, pool):
+        b, pps, hq, hkv = 2, 4, 4, 2
+        num_pages = b * pps
+        kernel, pools, k, v = self._pools(pool, hkv, num_pages)
+        tables = jnp.asarray(np.random.default_rng(49).permutation(
+            num_pages).reshape(b, pps).astype(np.int32))
+        lens = jnp.asarray([2 * self.PAGE, self.PAGE + 1], jnp.int32)
+        q = _rand((b, hq, self.D), seed=50)
+        if how == "scan":       # the serving walk: a traced layer counter
+            _, outs = jax.lax.scan(
+                lambda c, li: (c, kernel(q, *pools, tables, lens, layer=li,
+                                         interpret=True)),
+                0, jnp.arange(self.L, dtype=jnp.int32))
+        elif how == "int":
+            outs = [kernel(q, *pools, tables, lens, layer=li, interpret=True)
+                    for li in range(self.L)]
+        else:
+            outs = [kernel(q, *(p[li] for p in pools), tables, lens,
+                           interpret=True) for li in range(self.L)]
+        for li in range(self.L):
+            np.testing.assert_allclose(
+                np.asarray(outs[li]),
+                np.asarray(self._ref(q, k[li], v[li], tables, lens)),
+                rtol=2e-5, atol=2e-5)
+
+    def test_bfloat16_pool(self):
+        b, pps, hq, hkv = 2, 4, 4, 2
+        num_pages = b * pps
+        shape = (self.L, hkv, num_pages, 16, 128)
+        k = _rand(shape, jnp.bfloat16, seed=51)
+        v = _rand(shape, jnp.bfloat16, seed=52)
+        tables = jnp.asarray(np.random.default_rng(53).permutation(
+            num_pages).reshape(b, pps).astype(np.int32))
+        lens = jnp.asarray([61, 17], jnp.int32)
+        q = _rand((b, hq, 128), jnp.bfloat16, seed=54)
+        out = paged_attention(q, k, v, tables, lens, layer=1, interpret=True)
+        ref = self._ref(q, k[1].astype(jnp.float32),
+                        v[1].astype(jnp.float32), tables, lens)
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(ref, np.float32),
+                                   rtol=3e-2, atol=3e-2)
+
+    @pytest.mark.parametrize("hkv,itemsize,pps,want", [
+        (8, 2, 32, 8),      # the served pool: 128 KB a page, 4 MB a step
+        (32, 2, 32, 2),     # a 32-head MHA pool
+        (8, 4, 32, 4),      # float32 pages (and dequantized int8 ones)
+        (8, 2, 5, 5),       # never more than the table has columns
+        (64, 4, 32, 1),     # never less than one
+    ])
+    def test_pages_a_step_follow_the_shapes(self, hkv, itemsize, pps, want):
+        from paddle_tpu.ops.pallas.decode_attention import _pages_per_step
+
+        assert _pages_per_step(hkv, 64, 128, itemsize, pps) == want
+
+    @pytest.mark.parametrize("group", [1, 3, 4])
+    def test_fetch_table_names_no_new_page_past_a_length(self, group):
+        """A live column names its page, clamped into the pool; a column
+        past the length names what its operand named the grid step
+        before (across rows), which is what makes the pipeline skip it."""
+        from paddle_tpu.ops.pallas.decode_attention import _fetch_table
+
+        page, num_pages, pps = 8, 50, 7
+        lens = np.asarray([0, 17, 0, 56, 1, 0], np.int32)
+        tables = np.random.default_rng(55).integers(
+            -5, 80, (len(lens), pps)).astype(np.int32)
+        got = np.asarray(_fetch_table(jnp.asarray(tables),
+                                      jnp.asarray(lens), page, group,
+                                      num_pages))
+        steps = -(-pps // group)
+        assert got.shape == (len(lens), steps * group)
+        assert got.min() >= 0 and got.max() < num_pages
+        flat = got.reshape(-1, group)
+        for b, n in enumerate(lens):
+            for c in range(steps * group):
+                at, g = b * steps + c // group, c % group
+                if c * page < n:
+                    assert got[b, c] == np.clip(tables[b, c], 0,
+                                                num_pages - 1)
+                elif at:
+                    assert got[b, c] == flat[at - 1, g], (b, c)
