@@ -33,7 +33,7 @@ def main():
                     help="resilience StepGuard around the 3-axis compiled "
                     "step: nonfinite/spike updates are discarded in-graph "
                     "(skip-only here — attach a per-model CheckpointManager "
-                    "as bench.py does to get the rewind rung; the eager "
+                    "to get the rewind rung; the eager "
                     "train_batch loop is not guarded, docs/RESILIENCE.md)")
     args = ap.parse_args()
 
@@ -122,7 +122,7 @@ def main():
 
         # skip-only policy here: `manager` holds the FIRST model's steps,
         # which must not be restored into model3 — attach a per-model
-        # CheckpointManager (like bench.py's per-model subroot) to get
+        # CheckpointManager (a per-model subroot) to get
         # the rollback rung of the escalation ladder
         guard3 = StepGuard(step3, manager=None)
         gstep = 1

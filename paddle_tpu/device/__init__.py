@@ -131,7 +131,7 @@ def device_record():
 
 # -- chip peaks ---------------------------------------------------------------
 #: Published per-chip peaks, keyed by ``jax.Device.device_kind``. ONE table:
-#: MFU/roofline denominators (bench.py, jit cost summaries), the memory
+#: MFU/roofline denominators (jit cost summaries), the memory
 #: planner's HBM fallback and the layout autotuner's link term all read it.
 #: v5e: Google Cloud documentation, "TPU v5e" system architecture — 197
 #: TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM2e at 819 GB/s, 1,600 Gbit/s

@@ -155,8 +155,8 @@ REASON_TEXT = {
                        "scaled GEMMs stay wide",
     Reason.QUANT_SEAM: "engaged tp seams own the row/col matmul layouts "
                        "(PR 6/7 precedence)",
-    Reason.QUANT_FUSED_FFN: "a fused FFN kernel (swiglu_down / _ffn_i8) "
-                            "owns these GEMMs",
+    Reason.QUANT_FUSED_FFN: "the fused FFN kernel (swiglu_down) owns "
+                            "this GEMM",
     Reason.QUANT_PIPELINE: "pipeline stage_fn does not thread amax state",
     Reason.QUANT_COMPOSED: "composed manual region does not thread amax "
                            "state",
@@ -285,9 +285,8 @@ def pipeline_schedule_env():
 
 def pipeline_schedule_disabled():
     """True when ``PTPU_PIPELINE_SCHEDULE`` spells the escape hatch —
-    the ONE place the accepted off-spellings live (bench.py's
-    ``disabled_by_knob`` and the :data:`Reason.PIPELINE_OFF` decline
-    both call this, so they can never drift apart)."""
+    the ONE place the accepted off-spellings live (the
+    :data:`Reason.PIPELINE_OFF` decline calls this)."""
     return pipeline_schedule_env() in ("0", "off", "false")
 
 

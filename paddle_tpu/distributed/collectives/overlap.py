@@ -16,7 +16,7 @@ backward compute instead of after it.
 Caveat (honest): for the scan-over-layers ``StackedDecoder`` every
 stacked parameter's gradient finishes only when the backward scan
 completes, so cross-layer overlap needs the unrolled path
-(``PTPU_UNROLL_LAYERS``); bucket separation still overlaps the embedding
+(``PTPU_SCAN_LAYERS=0``); bucket separation still overlaps the embedding
 /head/norm reduces with the decoder backward, and caps the collective's
 working-set vs one tree-sized fusion.
 

@@ -21,9 +21,7 @@ with blockwise-int8 wire format and EXACT integer accumulation:
   (vs 2 for bf16, 4 for f32) at the cost of a second quantization
   round-trip. Requires a FULLY-manual region (every mesh axis manual),
   which is where ReduceScatter/AllGather lower correctly here — the
-  eager collective API's 1-D group meshes qualify, and on TPU runtimes
-  whose partitioner handles manual subgroups it is the preferred
-  in-step lowering too (``PTPU_QUANT_IMPL=rsag``).
+  eager collective API's 1-D group meshes qualify.
 
 Both kernels bound the per-element error by ``block_absmax / 127`` per
 quantization phase (one phase for the psum kernel, two for rs+ag); the
@@ -76,13 +74,8 @@ def quantize_shared_scale_int8(x, axis_names, block=QUANT_BLOCK):
 def _pack_lanes_default():
     """Lane packing halves the AllReduce payload on a real interconnect
     but is pure extra arithmetic when the "wire" is an in-process memcpy
-    — default ON for accelerator backends, OFF for the CPU host-platform
-    simulation. ``PTPU_QUANT_PACK=1/0`` forces."""
-    import os
-
-    env = os.environ.get("PTPU_QUANT_PACK", "")
-    if env:
-        return env not in ("0", "off")
+    — ON for accelerator backends, OFF for the CPU host-platform
+    simulation."""
     return jax.default_backend() not in ("cpu",)
 
 

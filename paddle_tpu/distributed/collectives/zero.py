@@ -247,7 +247,7 @@ class ZeroPlan:
         return out
 
     def zero_summary(self):
-        """JSON-able shape for the bench ``"zero"`` block."""
+        """JSON-able shape of the ``"zero"`` block."""
         return {
             "stage": self.stage,
             "shard_axis": self.shard_axis,

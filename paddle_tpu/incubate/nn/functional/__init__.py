@@ -19,8 +19,8 @@ def fused_rms_norm(x, norm_weight, norm_bias=None, epsilon=1e-6, begin_norm_axis
     Matches the reference contract (incubate/nn/functional/fused_rms_norm.py:59):
     with ``residual`` the op returns ``(out, residual_out)`` where
     ``residual_out = x (+bias) + residual`` is the updated residual stream;
-    without it, just ``out``. On TPU the residual+norm path runs the fused
-    Pallas kernel (ops/pallas/add_rms_norm.py — one VMEM pass emits both)."""
+    without it, just ``out``. On TPU the norm runs the Pallas rms kernel
+    (ops/pallas/rms_norm.py)."""
     def _frms(a, w, b, bias_in, res):
         if bias_in is not None:
             a = a + bias_in
@@ -33,17 +33,12 @@ def fused_rms_norm(x, norm_weight, norm_bias=None, epsilon=1e-6, begin_norm_axis
         fast = (ax == a.ndim - 1 and b is None and rows % 8 == 0
                 and on_tpu_device())
         if res is not None:
-            if fast:
-                from ....ops.pallas.add_rms_norm import add_rms_norm
-
-                y, out = add_rms_norm(a, res, w, epsilon)
-                return out, y
             a = a + res
         if fast:
-            # res is always None here (the fast+residual case returned above)
             from ....ops.pallas import rms_norm as _pallas_rms
 
-            return _pallas_rms(a, w, epsilon)
+            out = _pallas_rms(a, w, epsilon)
+            return (out, a) if res is not None else out
         axes = tuple(range(ax, a.ndim))
         var = jnp.mean(jnp.square(a.astype(jnp.float32)), axis=axes, keepdims=True)
         out = (a.astype(jnp.float32) * jax.lax.rsqrt(var + epsilon)).astype(a.dtype)
@@ -236,15 +231,13 @@ def fused_linear_cross_entropy(x, weight, labels, transpose_y=True,
     embedding layout) or [H, V]; labels: [...] int.
     """
     def _flce(h, w, y):
-        import os as _os
-
         H = h.shape[-1]
         hf = h.reshape(-1, H)
         yf = y.reshape(-1).astype(jnp.int32)
         n = hf.shape[0]
-        # perf knob: bigger chunks = fewer serialized lax.map steps, more
-        # logits resident (O(chunk * vocab) fp32)
-        c = min(max(1, int(_os.environ.get("PTPU_CE_CHUNK", chunk_size))), n)
+        # bigger chunks = fewer serialized lax.map steps, more logits
+        # resident (O(chunk * vocab) fp32)
+        c = min(max(1, int(chunk_size)), n)
         pad = (-n) % c
         if pad:
             hf = jnp.concatenate([hf, jnp.zeros((pad, H), hf.dtype)])

@@ -421,8 +421,8 @@ def fleet_soak(model, n_replicas, workload, *, policy="least_loaded",
                timeline_path=None):
     """Build ``n_replicas`` engines (or disaggregated pairs) over
     ``model``, route them (FleetRouter when n>1), drive ``workload``,
-    return the soak stats. One entry point for tools/serve_bench.py and
-    ``bench.py --serve``. ``overload`` passes an
+    return the soak stats. The entry point of tools/serve_bench.py.
+    ``overload`` passes an
     :class:`.overload.OverloadConfig` to the router; ``chaos_wrap`` is
     an optional ``{replica_idx: fn}`` map wrapping chosen engines in a
     fault injector (``paddle_tpu.testing.chaos.ChaosReplica``) before

@@ -34,8 +34,8 @@ _TRAIN_STEPS = _telemetry.counter(
 # -- compile-phase telemetry (docs/TELEMETRY.md, docs/SCAN.md) --------------
 # Wall seconds of the newest program build, split by phase, plus the
 # serialized HLO module size — the measurement behind the scan-over-layers
-# "compile time and program size flat in depth" claim (bench.py "compile"
-# block; tools/bench_gate.py gates regressions).
+# "compile time and program size flat in depth" claim (the "compile"
+# block that tools/bench_gate.py gates).
 _TRACE_SECONDS = _telemetry.gauge(
     "trace_seconds", "jax tracing wall seconds of the newest program "
     "build for this function", labelnames=("function",))
@@ -468,10 +468,6 @@ class StaticFunction:
 
     def _eager_call(self, args, kwargs):
         fn = self._fn if self._fn is not None else self._layer
-        import os
-
-        if os.environ.get("PTPU_NO_SEGMENTS"):
-            return fn(*args, **kwargs)
         # Partial-graph capture around graph breaks — ops compile as
         # segments (prefix up to the .item()/bool(), host branch, suffix),
         # the SOT-granularity answer (function_graph.py) without bytecode

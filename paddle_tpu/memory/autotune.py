@@ -29,8 +29,7 @@ instead of per-config folklore):
    budget, grids, every engagement-affecting env knob).
 
 Entry point :func:`autotune_train_step` returns the BUILT
-``ShardedTrainStep`` for the winning layout plus the decision;
-``bench.py --autotune`` routes both headline lines through it
+``ShardedTrainStep`` for the winning layout plus the decision
 (docs/AUTOTUNE.md).
 
 Knobs:
@@ -396,16 +395,16 @@ def _build_candidate(layout, model_factory):
 
 def flagship_gpt_factory(cfg_factory, *, lr=1e-3, seed=0,
                          optimizer_factory=None, amp_bf16=False):
-    """``model_factory`` for GPTForCausalLMPipe flagships — the shape
-    bench.py and the MULTICHIP dryrun share. ``cfg_factory()`` returns
+    """``model_factory`` for GPTForCausalLMPipe flagships — the
+    MULTICHIP dryrun's shape. ``cfg_factory()`` returns
     a fresh GPTConfig per call; the factory applies the layout's remat/
     head-chunk/schedule axes to it, the layout's placements to the
     decoder (pipeline placements when pp > 1, tp placements when only
     mp > 1), and the ``group_sharded_parallel`` level matching the
-    ZeRO stage. ``amp_bf16=True`` mirrors bench.py's TPU build: the
-    model constructs under O2 autocast and its params cast to bf16 —
-    without it a searched program would be priced in f32 while the
-    measured run executes bf16."""
+    ZeRO stage. ``amp_bf16=True`` mirrors ``bench.build_model``'s TPU
+    build: the model constructs under O2 autocast and its params cast
+    to bf16 — without it a searched program would be priced in f32
+    while the measured run executes bf16."""
     def factory(layout, mesh):
         import paddle_tpu as paddle
         from ..distributed.parallel_step import group_sharded_parallel
@@ -474,8 +473,7 @@ def _layout_key(chip, ndev, budget, cache_extra, layouts, baseline,
     base = (tuple(sorted(baseline.as_json().items()))
             if baseline is not None else None)
     knobs = tuple((k, os.environ.get(k, "")) for k in LAYOUT_ENV_KNOBS)
-    scan_mode = ("scan" if scan_layers_enabled() else "unrolled",
-                 os.environ.get("PTPU_UNROLL_LAYERS", "1"))
+    scan_mode = "scan" if scan_layers_enabled() else "unrolled"
     return hashlib.sha1(repr(
         (chip, ndev, budget, tuple(cache_extra), grid, base, require_fit,
          scan_mode, knobs, _quant_knobs())

@@ -2,7 +2,7 @@
 
 The r3-r5 MFU climb was funded by HBM headroom bought by hand — factored
 Adam, the int8 LM head, hand-picked ``save_only_these_names`` lists, and
-"b5 OOMs" batch caps in bench.py. Every bf16 activation a remat policy
+hand-set batch caps. Every bf16 activation a remat policy
 saves costs ``2 * B * S * dim`` bytes per layer; EQuARX-style blockwise
 int8 (arXiv:2506.17615) stores the same residual at ~half that (1 byte of
 mantissa + one fp32 scale per 256-elem block) with negligible quality
@@ -139,12 +139,12 @@ def int8_checkpoint(x, name, block=INT8_BLOCK):
     return _int8_ckpt_fn(str(name), int(block))(x)
 
 
-#: anchors tagged INSIDE custom kernels' vjps (pallas flash / rms /
-#: add_rms) — their save points are not routeable through
-#: ``int8_checkpoint``, so an ``int8:`` request would silently drop the
-#: real save (the anchor recomputes every backward) while claiming the
-#: memory win. Reject loudly instead.
-KERNEL_ANCHORS = frozenset({"attn_res", "attn_lse", "rms_rstd", "addrms_y"})
+#: anchors tagged INSIDE custom kernels' vjps (pallas flash / rms) —
+#: their save points are not routeable through ``int8_checkpoint``, so
+#: an ``int8:`` request would silently drop the real save (the anchor
+#: recomputes every backward) while claiming the memory win. Reject
+#: loudly instead.
+KERNEL_ANCHORS = frozenset({"attn_res", "attn_lse", "rms_rstd"})
 
 
 def parse_save_names(spec):

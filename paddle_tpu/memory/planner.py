@@ -1,14 +1,14 @@
 """XLA-memory-driven batch/remat auto-planner.
 
 Every round since r3 hand-tuned the bench batch and remat name list
-against OOMs ("b5 OOMs" comments in bench.py). But
+against OOMs. But
 ``jit(...).lower().compile().memory_analysis()`` tells us the exact HBM
 budget of any candidate (batch, remat-policy) TrainStep WITHOUT executing
 it — the same buffer-assignment numbers the XLA weight-update-sharding
 work (arXiv:2004.13336) converts into throughput. The planner lowers the
 candidate grid ahead of time, rejects configs whose peak exceeds the chip
-budget, and picks the best fit by a throughput estimate — so bench.py
-stops carrying hand-set caps and a chip upgrade re-plans itself.
+budget, and picks the best fit by a throughput estimate — so no caller
+carries hand-set caps and a chip upgrade re-plans itself.
 
 Planning cost is compile time (one AOT compile per candidate evaluated,
 highest-score first, stopping at the first fit); decisions are cached on
@@ -320,7 +320,7 @@ def zero_hbm_savings(zero):
 def default_program_key(cand):
     """The candidate axes that change the traced program, conservatively:
     every grid axis. Callers that KNOW two candidates lower to the same
-    program pass a coarser ``program_key_fn`` — e.g. bench.py resolves
+    program pass a coarser ``program_key_fn`` — e.g. one that resolves
     the EFFECTIVE CE head chunk (fused_cross_entropy.resolve_vocab_chunk
     clamps to the vocab), so head_chunk values that clamp to the same
     chunk share one lowering instead of re-compiling per spelling."""
@@ -390,8 +390,7 @@ def plan_train_step(step_factory, candidates, *, budget_bytes=None,
     # (lazy import: no cycle — models.gpt pulls memory only in-function)
     from ..models.gpt import scan_layers_enabled
 
-    scan_mode = ("scan" if scan_layers_enabled() else "unrolled",
-                 os.environ.get("PTPU_UNROLL_LAYERS", "1"))
+    scan_mode = "scan" if scan_layers_enabled() else "unrolled"
     savings = zero_hbm_savings(zero)
     zero_key = (tuple(sorted((k, int(v or 0)) for k, v in zero.items()))
                 if zero else None)

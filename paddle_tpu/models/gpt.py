@@ -14,8 +14,6 @@ from __future__ import annotations
 import math
 import os
 
-import jax
-
 import paddle_tpu as paddle
 from paddle_tpu import nn
 from paddle_tpu.incubate.nn import functional as FF
@@ -337,10 +335,6 @@ class GPTModel(nn.Layer):
             if quant_buf is not None:
                 amax = params[-1]
                 params = params[:-1]
-            tables = (_rope_tables(x.shape[1],
-                                   cfg.hidden_size // cfg.num_heads)
-                      if cfg.rope and os.environ.get("PTPU_ROPE_HOIST")
-                      else None)
             policy, int8_names = (_resolve_remat(cfg) if cfg.recompute
                                   else (None, frozenset()))
             q_sites, q_dtype = _resolve_quant(cfg)
@@ -349,7 +343,7 @@ class GPTModel(nn.Layer):
 
                 amax = jnp.zeros((L, len(_quant.GEMM_SITES), 2,
                                   _quant.amax_hist_len()), jnp.float32)
-            block = _make_block(cfg, tables=tables, int8_names=int8_names,
+            block = _make_block(cfg, int8_names=int8_names,
                                 policy=policy, quant_sites=q_sites,
                                 quant_dtype=q_dtype)
             n = len(_BLOCK_PARAM_FIELDS)
@@ -457,31 +451,17 @@ def _rope_rotate(x, sin, cos):
 def _rope_tables_at(p, d, base=10000.0):
     """sin/cos tables for an ARBITRARY position vector ``p`` [T],
     broadcast-ready for [B, T, H, D] activations: [1, T, 1, d/2] each.
-    The ONE frequency formula every table consumer shares —
-    :func:`_rope_tables` (positions 0..t-1) and the ring-attention
-    region's zigzag-global-position tables
-    (collectives/ring_attention.RingContext.rope_tables) both delegate
-    here, so an engaged ring step can never rotate by different angles
-    than the single-device program."""
+    The frequency formula of :func:`_rope_at_positions`, for the
+    ring-attention region's zigzag-global-position tables
+    (collectives/ring_attention.RingContext.rope_tables), so an engaged
+    ring step can never rotate by different angles than the
+    single-device program."""
     import jax.numpy as jnp
 
     inv = base ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     freqs = p.astype(jnp.float32)[:, None] * inv   # [T, d/2]
     return (jnp.sin(freqs)[None, :, None, :],
             jnp.cos(freqs)[None, :, None, :])
-
-
-def _rope_tables(t, d, base=10000.0):
-    """sin/cos tables for positions 0..t-1, broadcast-ready for
-    [B, T, H, D] activations: shape [1, T, 1, d/2] each.
-
-    Hoisting these out of the layer scan (computed ONCE per step instead
-    of per layer per pass) removes 2 * L * (fwd + remat) transcendental
-    sweeps from the train step — sin/cos of a [T, d/2] grid is ~1MB and
-    becomes a saved checkpoint input, never recomputed in backward."""
-    import jax.numpy as jnp
-
-    return _rope_tables_at(jnp.arange(t, dtype=jnp.float32), d, base)
 
 
 def _rope_pure(x, base=10000.0, tables=None):
@@ -517,68 +497,6 @@ def _rms_pure(x, w, eps=1e-6):
     return ((x.astype(jnp.float32) * jax.lax.rsqrt(var + eps)).astype(x.dtype)) * w
 
 
-@jax.custom_vjp
-def _ffn_i8(h2, wg, wu, wd):
-    """Whole swiglu FFN (down(silu(h2@wg) * (h2@wu))) whose backward reads
-    int8-saved gate/up instead of re-running the two big matmuls.
-
-    Forward numerics are EXACT (the real bf16 gate/up feed silu/mul/down);
-    the int8 round-trip only enters the BACKWARD — inside the silu'/mul
-    factors and the wd weight-grad contraction — the same wide-backward
-    discipline as the int8 LM head
-    (incubate/nn/functional/__init__.py:_int8_head_core). Residuals are
-    tagged (ffn_gate_q8 etc.) so a save_only_these_names remat policy
-    keeps the int8 copies at HALF the HBM of bf16 saves (which OOM at
-    1.3B/b4, docs/ROUND4_IDEAS.md:7-13). The down-proj lives INSIDE the
-    vjp so its wgrad reconstructs silu(gate)*up from the saved int8 —
-    nothing in this block's backward re-runs a forward matmul.
-
-    Capability slot: the reference's recompute pass offers no middle
-    ground between save-full and re-run
-    (distributed/passes/auto_parallel_recompute.py); TPU-native extension."""
-    return (jax.nn.silu(h2 @ wg) * (h2 @ wu)) @ wd
-
-
-def _ffn_i8_fwd(h2, wg, wu, wd):
-    from jax.ad_checkpoint import checkpoint_name
-
-    from paddle_tpu.incubate.nn.functional import _quantize_rows_int8
-
-    gate = h2 @ wg
-    up = h2 @ wu
-    qg, sg = _quantize_rows_int8(gate)
-    qu, su = _quantize_rows_int8(up)
-    qg = checkpoint_name(qg, "ffn_gate_q8")
-    sg = checkpoint_name(sg, "ffn_gate_q8_s")
-    qu = checkpoint_name(qu, "ffn_up_q8")
-    su = checkpoint_name(su, "ffn_up_q8_s")
-    return (jax.nn.silu(gate) * up) @ wd, (h2, wg, wu, wd, qg, sg, qu, su)
-
-
-def _ffn_i8_bwd(res, g):
-    import jax.numpy as jnp
-
-    h2, wg, wu, wd, qg, sg, qu, su = res
-    gate = (qg.astype(jnp.float32) * sg)
-    up = (qu.astype(jnp.float32) * su)
-    sig = jax.nn.sigmoid(gate)
-    silu = gate * sig
-    dsilu = sig * (1.0 + gate * (1.0 - sig))
-    ffn = (silu * up).astype(h2.dtype)
-    dffn = g @ wd.T
-    dwd = jnp.einsum("bsm,bsh->mh", ffn, g).astype(wd.dtype)
-    gf = dffn.astype(jnp.float32)
-    dgate = (gf * up * dsilu).astype(h2.dtype)
-    dup = (gf * silu).astype(h2.dtype)
-    dh2 = dgate @ wg.T + dup @ wu.T
-    dwg = jnp.einsum("bsh,bsm->hm", h2, dgate).astype(wg.dtype)
-    dwu = jnp.einsum("bsh,bsm->hm", h2, dup).astype(wu.dtype)
-    return dh2, dwg, dwu, dwd
-
-
-_ffn_i8.defvjp(_ffn_i8_fwd, _ffn_i8_bwd)
-
-
 def scan_layers_enabled():
     """``PTPU_SCAN_LAYERS`` master switch (docs/SCAN.md): the default
     (unset/1) runs the decoder stack as ONE ``lax.scan`` body over a
@@ -592,41 +510,22 @@ def scan_layers_enabled():
 
 
 def _fused_ffn_active(tp_seams):
-    """norm→ffn seam megakernel gate (``PTPU_FUSED_FFN``, or the
-    umbrella ``PTPU_FUSED_SEAMS`` that also engages the addrms attn→norm
-    seam). Precedence mirrors the PR 6 rules: engaged tp seams own the
-    row/col matmul layouts (the megakernel's plain-matmul reads would
-    force mid-block reshards against the seq-sharded residual), and
-    ``PTPU_INT8_FFN`` keeps its own whole-FFN vjp."""
+    """norm→ffn seam megakernel gate (``PTPU_FUSED_FFN``). Precedence
+    mirrors the PR 6 rules: engaged tp seams own the row/col matmul
+    layouts (the megakernel's plain-matmul reads would force mid-block
+    reshards against the seq-sharded residual)."""
     if tp_seams is not None:
         return False
-    if os.environ.get("PTPU_INT8_FFN"):
-        return False
-    env = (os.environ.get("PTPU_FUSED_FFN")
-           or os.environ.get("PTPU_FUSED_SEAMS") or "")
+    env = os.environ.get("PTPU_FUSED_FFN", "")
     if env in ("", "0"):
         return False
-    # device gate (mirrors _sdpa_pure/_addrms_active): off-TPU the
-    # kernel would run in the Pallas INTERPRETER — orders of magnitude
-    # slower than the unfused XLA seam. "interpret" opts in explicitly
-    # (parity tests drive the real kernel code on the CPU mesh).
+    # device gate (mirrors _sdpa_pure): off-TPU the kernel would run in
+    # the Pallas INTERPRETER — orders of magnitude slower than the
+    # unfused XLA seam. "interpret" opts in explicitly (parity tests
+    # drive the real kernel code on the CPU mesh).
     from paddle_tpu.ops.pallas import on_tpu_device
 
     return on_tpu_device() or env == "interpret"
-
-
-def _addrms_active(tp_seams, q_shape):
-    """attn→norm seam: the fused residual-add+rms Pallas pass
-    (``PTPU_FUSED_ADDRMS``, or the ``PTPU_FUSED_SEAMS`` umbrella)."""
-    if tp_seams is not None:
-        return False
-    env = (os.environ.get("PTPU_FUSED_ADDRMS")
-           or os.environ.get("PTPU_FUSED_SEAMS") or "")
-    if env in ("", "0"):
-        return False
-    from paddle_tpu.nn.functional.flash_attention import _use_pallas
-
-    return _use_pallas(q_shape)
 
 
 def _sdpa_pure(q, k, v, causal=True):
@@ -661,8 +560,7 @@ def _sdpa_pure(q, k, v, causal=True):
 
 
 def _block_pure(p, x, num_heads, num_kv_heads, use_rope=True,
-                rope_tables=None, int8_names=frozenset(), tp_seams=None,
-                quant=None):
+                int8_names=frozenset(), tp_seams=None, quant=None):
     """One decoder block on arrays. p = (ln1, wq, wk, wv, wo, ln2, wg, wu, wd).
 
     ``int8_names``: anchors whose save point is routed through
@@ -730,18 +628,13 @@ def _block_pure(p, x, num_heads, num_kv_heads, use_rope=True,
     # engaged ring-attention region (docs/ATTENTION.md): this block sees
     # ONE sep shard's zigzag token slice, so rope must rotate by the
     # GLOBAL positions of those tokens (from the region's sep ordinal),
-    # not 0..s — and hoisted local-position tables must not apply
+    # not 0..s
     from paddle_tpu.distributed.collectives import ring_attention as _ringmod
 
     _ring_ctx = _ringmod.active_ring_context()
     if use_rope:
-        if _ring_ctx is not None:
-            rope_tables = _ring_ctx.rope_tables(s, hd)
-        elif sq != s:
-            # composed-seam path: the gathered attention stream covers
-            # the FULL sequence; hoisted local-position tables (built
-            # for the seq shard) must not apply
-            rope_tables = None
+        rope_tables = (_ring_ctx.rope_tables(s, hd)
+                       if _ring_ctx is not None else None)
         q = _rope_pure(q, tables=rope_tables)
         k = _rope_pure(k, tables=rope_tables)
     # remat anchors (inert under policies that don't name them): saving
@@ -761,34 +654,12 @@ def _block_pure(p, x, num_heads, num_kv_heads, use_rope=True,
 
     if _ring_ctx is None and not _use_pallas(q.shape):
         o = _save(o, "attn_out")
-    if _addrms_active(tp_seams, q.shape):
-        # fused residual-add + rms in one Pallas pass (named residuals
-        # addrms_y/rms_rstd make the backward reuse, not re-run, it).
-        # Engaged tp seams take precedence: mixing one plain-matmul
-        # all-reduce seam into a seq-sharded block forces reshards
-        # between the layouts and forfeits the seam win (docs/COMMS.md)
-        from ..ops.pallas.add_rms_norm import add_rms_norm
-
-        wo_out = (quant.gemm(o, wo, "wo") if quant is not None else o @ wo)
-        x, h2 = add_rms_norm(wo_out, x, ln2)
-    else:
-        # anchors: resid_mid skips the o-proj re-run; ln2_out feeds the
-        # gate/up recompute without re-running rms2. On the fused-seam
-        # path _row returns the attn output SEQ-SHARDED, so the
-        # residual add and rms below run on 1/tp of the rows
-        x = _save(x + _row(o, wo, "wo"), "resid_mid")
-        h2 = _save(_rms_pure(x, ln2), "ln2_out")
-    if os.environ.get("PTPU_INT8_FFN") and tp_seams is None:
-        # (seam precedence as above: _ffn_i8's plain matmuls would break
-        # the seq-sharded layout mid-block)
-        # int8-saved gate/up: exact forward, backward dequantises instead
-        # of re-running the two matmuls (~9 TFLOP/step at 1.3B/b4).
-        # MEASURED LOSING on v5e-16G (0.523-0.528 vs 0.547 baseline, r4:
-        # quant bandwidth + fusion breakage > the FLOPs saved) and
-        # SUPERSEDED in r5 by factored-AdamW freeing enough HBM to save
-        # gate/up in bf16 outright (the ffn_gate/ffn_up names below).
-        # Kept for memory-floor configs only.
-        return x + _ffn_i8(h2, wg, wu, wd)
+    # anchors: resid_mid skips the o-proj re-run; ln2_out feeds the
+    # gate/up recompute without re-running rms2. On the fused-seam
+    # path _row returns the attn output SEQ-SHARDED, so the
+    # residual add and rms below run on 1/tp of the rows
+    x = _save(x + _row(o, wo, "wo"), "resid_mid")
+    h2 = _save(_rms_pure(x, ln2), "ln2_out")
     # per-projection anchors: saving gate/up outputs individually lets a
     # policy trade ~67MB/layer (b4) for skipping that matmul's re-run
     gate = _save(_col(h2, wg, "wg"), "ffn_gate")
@@ -896,8 +767,8 @@ def _resolve_quant(cfg, *, tp_seams=None, composed=False, pipelined=False,
 
     Precedence mirrors the PR 6/7 rules: engaged tp seams own the
     row/col matmul layouts; the pipeline stage_fn and composed manual
-    region don't thread amax state; a fused FFN kernel (``_ffn_i8`` /
-    ``swiglu_down``) owns its GEMMs, dropping just those sites; and with
+    region don't thread amax state; the fused FFN kernel
+    (``swiglu_down``) owns its GEMM, dropping just that site; and with
     ``PTPU_QUANT_COMPUTE`` unset the int8-head-style parity gate (CPU
     default-off) must pass."""
     from paddle_tpu import quant as _quant
@@ -921,12 +792,7 @@ def _resolve_quant(cfg, *, tp_seams=None, composed=False, pipelined=False,
         return _decline(_compose.Reason.QUANT_SEAM)
     if not _quant.quant_compute_enabled(requested=True):
         return _decline(_compose.Reason.QUANT_GATE)
-    if os.environ.get("PTPU_INT8_FFN"):
-        owned = sites & {"wg", "wu", "wd"}
-        if owned:
-            note("quant_gemm", _compose.Reason.QUANT_FUSED_FFN)
-            sites = sites - owned
-    elif _fused_ffn_active(tp_seams) and "wd" in sites:
+    if _fused_ffn_active(tp_seams) and "wd" in sites:
         # the swiglu_down megakernel consumes wd (and declines
         # pre-quantized operands — its VMEM stream is bf16-shaped);
         # gate/up stay quantizable, they feed the kernel post-GEMM
@@ -962,9 +828,8 @@ def _quant_buffer_state(config):
     return Tensor(jnp.asarray(_quant.init_amax_state(config.num_layers)))
 
 
-def _make_block(cfg, tables=None, int8_names=frozenset(), tp_seams=None,
-                policy=None, gather=None, quant_sites=frozenset(),
-                quant_dtype=None):
+def _make_block(cfg, int8_names=frozenset(), tp_seams=None, policy=None,
+                gather=None, quant_sites=frozenset(), quant_dtype=None):
     """One remat-wrapped decoder block over arrays: the scan body. With
     ``cfg.recompute`` each body is a ``jax.checkpoint`` — the remat
     policy (including int8:<anchor> saves) applies PER LAYER whether the
@@ -994,9 +859,8 @@ def _make_block(cfg, tables=None, int8_names=frozenset(), tp_seams=None,
         if gather is not None:
             p = gather(p)
         out = _block_pure(p, x, cfg.num_heads, cfg.num_kv_heads,
-                          cfg.rope, rope_tables=tables,
-                          int8_names=int8_names, tp_seams=tp_seams,
-                          quant=qctx)
+                          cfg.rope, int8_names=int8_names,
+                          tp_seams=tp_seams, quant=qctx)
         if qctx is not None:
             return out, qctx.collect()
         return out
@@ -1017,29 +881,23 @@ def _scan_blocks(block, x, stacked, min_unroll=1, amax=None):
     (``_make_block(quant_sites=...)``)."""
     import jax
 
-    # PTPU_UNROLL_LAYERS=N statically unrolls the scan N-wide: the
-    # per-iteration dynamic-slice of every stacked weight (a real HBM
-    # copy — profiled at >20% of device ops, r4) becomes a
-    # constant-offset slice XLA can alias. Costs compile time linear
-    # in N. ``min_unroll`` floors it: the ZeRO just-in-time gather path
-    # asks for >= 2 so consecutive (gather_l, block_l) pairs share one
-    # loop body and XLA's scheduler can issue layer l+1's slab gather
-    # while layer l computes (the fsdp prefetch, docs/ZERO.md).
-    unroll = max(int(os.environ.get("PTPU_UNROLL_LAYERS", "1")),
-                 int(min_unroll))
+    # ``min_unroll``: the ZeRO just-in-time gather path asks for >= 2 so
+    # consecutive (gather_l, block_l) pairs share one loop body and
+    # XLA's scheduler can issue layer l+1's slab gather while layer l
+    # computes (the fsdp prefetch, docs/ZERO.md).
+    unroll = max(1, int(min_unroll))
 
     if amax is not None:
         def qstep(x, p):
             out, new_amax_l = block(x, p)
             return out, new_amax_l
 
-        return jax.lax.scan(qstep, x, (tuple(stacked), amax),
-                            unroll=max(1, unroll))
+        return jax.lax.scan(qstep, x, (tuple(stacked), amax), unroll=unroll)
 
     def step(x, p):
         return block(x, p), None
 
-    out, _ = jax.lax.scan(step, x, tuple(stacked), unroll=max(1, unroll))
+    out, _ = jax.lax.scan(step, x, tuple(stacked), unroll=unroll)
     return out
 
 
@@ -1216,10 +1074,7 @@ class StackedDecoder(nn.Layer):
         gather = _zero_jit_gather()
 
         seams = ctx.seams
-        # no hoisted rope tables here: the seq-sharded stream's local
-        # positions are not the attention stream's (the seam gather
-        # restores the full sequence; _block_pure rotates inline)
-        block = _make_block(cfg, tables=None, int8_names=int8_names,
+        block = _make_block(cfg, int8_names=int8_names,
                             tp_seams=seams, policy=policy, gather=gather)
         ctx.decoder_calls += 1
         if seams is not None:
@@ -1270,16 +1125,6 @@ class StackedDecoder(nn.Layer):
             if _ctx is not None:
                 _resolve_quant(cfg, composed=True)
                 return _out(self._run_composed(_ctx, x, params))
-
-            # PTPU_ROPE_HOIST=1 precomputes sin/cos tables once per step
-            # outside the scan. Measured SLOWER on v5e (0.5007 vs 0.5072 MFU
-            # A/B, r3): XLA fuses the inline sin/cos into the rotation's
-            # elementwise kernel for free, while hoisted tables add per-layer
-            # HBM reads. Kept as a knob — the tradeoff may flip at longer
-            # sequences where the table amortises more transcendentals.
-            tables = (_rope_tables(x.shape[1], cfg.hidden_size // cfg.num_heads)
-                      if cfg.rope and os.environ.get("PTPU_ROPE_HOIST")
-                      else None)
 
             policy, int8_names = (_resolve_remat(cfg) if cfg.recompute
                                   else (None, frozenset()))
@@ -1337,7 +1182,7 @@ class StackedDecoder(nn.Layer):
                     (cfg.num_layers, len(_quant.GEMM_SITES), 2,
                      _quant.amax_hist_len()), jnp.float32)
 
-            block = _make_block(cfg, tables=tables, int8_names=int8_names,
+            block = _make_block(cfg, int8_names=int8_names,
                                 tp_seams=tp_seams, policy=policy,
                                 gather=gather, quant_sites=q_sites,
                                 quant_dtype=q_dtype)

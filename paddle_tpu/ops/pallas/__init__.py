@@ -40,8 +40,8 @@ _path_logged = set()
 def log_path_once(op: str, path: str) -> None:
     """One-line record of which implementation served an op (pallas vs xla),
     so benchmarks can prove the fast path engaged. Keyed on (op, path): a
-    mid-run path switch (shape-dependent fallback) is logged too. INFO level
-    — bench.py raises this logger to INFO to record the path."""
+    mid-run path switch (shape-dependent fallback) is logged too. INFO level:
+    a caller raises this logger to INFO to record the path."""
     if (op, path) not in _path_logged:
         _path_logged.add((op, path))
         _log.info("paddle_tpu dispatch path: %s -> %s", op, path)

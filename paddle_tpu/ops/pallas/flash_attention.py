@@ -183,14 +183,8 @@ def _kv_index(b_idx, hq, hk):
 def _fwd(q, k, v, scale, causal, interpret, hq, hk):
     bhq, sq, d = q.shape
     sk = k.shape[1]
-    # PTPU_FA_KBLOCK decouples the streamed k/v tile from the q tile
-    # (with a full-seq q block, a smaller k block keeps the DMA pipeline
-    # ahead of the MXU; falls back to PTPU_FA_BLOCK when unset)
-    import os as _os
-
     bq = _block_for(sq)
-    bk = _block_for(sk, env="PTPU_FA_KBLOCK",
-                    default=int(_os.environ.get("PTPU_FA_BLOCK", "1024")))
+    bk = _block_for(sk)
     if bq is None or bk is None:
         raise ValueError(
             f"flash_attention: seq lens ({sq}, {sk}) not tileable — pad to a "

@@ -302,8 +302,8 @@ class StepGuard:
 
     # -- reporting -----------------------------------------------------------
     def summary(self) -> dict:
-        """JSON-able run totals (bench.py attaches this as the
-        "resilience" block; tools/bench_gate.py gates on it)."""
+        """JSON-able run totals (the "resilience" block that
+        tools/bench_gate.py gates on)."""
         return {
             "enabled": True,
             "anomalies": dict(self.anomalies),

@@ -222,7 +222,7 @@ class TimeSeriesRecorder:
     # -- background cadence --------------------------------------------------
     def start(self, interval=1.0):
         """Sample every ``interval`` seconds on a daemon thread until
-        :meth:`stop` (idempotent; bench.py --record uses this)."""
+        :meth:`stop` (idempotent)."""
         if self._thread is None:
             self._stop.clear()
 

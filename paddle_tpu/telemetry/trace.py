@@ -4,8 +4,8 @@ The registry (:mod:`.registry`) aggregates; this module keeps the
 timeline: thread-aware spans (``trace.span("bwd")`` context manager,
 ``trace.traced`` decorator), instants, and async request events, ring-
 buffered per thread and exported as Chrome/Perfetto trace-event JSON or
-a compact JSONL. One flag (``PTPU_TRACE=1`` / ``bench.py --trace``)
-turns a bench step from one opaque ``train_step_seconds`` sample into a
+a compact JSONL. One flag (``PTPU_TRACE=1``, or ``trace.enable()``)
+turns a step from one opaque ``train_step_seconds`` sample into a
 step anatomy: jit trace/lower/compile phases, per-call dispatch with a
 ``cost_analysis()`` roofline estimate, the collectives a plan issues,
 checkpoint save/restore phases, and serving request span trees.

@@ -35,7 +35,7 @@ class TestPhasesAtTinySize:
             interpret=True)
         assert set(report) == {
             "flash_gqa", "rms_norm", "swiglu_down_tiny",
-            "paged_attention", "add_rms_norm", "paged_attention_int8"}
+            "paged_attention", "paged_attention_int8"}
         assert not any("refused" in r for r in report.values())
 
     def test_optional_kernel_refusal_is_reported_required_one_raises(
@@ -46,9 +46,9 @@ class TestPhasesAtTinySize:
         kw = dict(flash_shapes={}, rms_shape=(16, 128), swiglu_shapes={},
                   paged_shape=(2, 4, 2, 64, 8, 2), dtype="float32",
                   tol=1e-4, interpret=True)
-        monkeypatch.setattr(chip_smoke, "check_add_rms_norm", refuse)
+        monkeypatch.setattr(chip_smoke, "check_paged_attention_int8", refuse)
         report = chip_smoke.kernel_phase(**kw)
-        assert "mosaic says no" in report["add_rms_norm"]["refused"]
+        assert "mosaic says no" in report["paged_attention_int8"]["refused"]
         monkeypatch.setattr(chip_smoke, "check_rms_norm", refuse)
         with pytest.raises(RuntimeError, match="mosaic says no"):
             chip_smoke.kernel_phase(**kw)
@@ -91,6 +91,48 @@ class TestPhasesAtTinySize:
             set_mesh(None)
         assert out["devices"] == len(jax.devices())
         assert out["zero"]["engaged"] and out["zero"]["stage"] == 3
+
+
+class TestBenchIsTheBuilders:
+    """bench.py is what benchmark/harness, benchmark/tests and
+    chip_smoke.py import from it, and no runner (ISSUE 32)."""
+
+    def _taken(self):
+        """Every ``bench.<name>`` in the code of the files that import
+        it (the syntax tree's, so the harness's span names, the strings
+        "bench.tick" and the like, are no part of it)."""
+        import ast
+        import glob
+
+        files = [os.path.join(REPO, "chip_smoke.py")]
+        for sub in ("harness", "tests"):
+            files += glob.glob(os.path.join(REPO, "benchmark", sub, "**",
+                                            "*.py"), recursive=True)
+        taken = {}
+        for path in files:
+            with open(path, encoding="utf-8") as f:
+                tree = ast.parse(f.read())
+            for node in ast.walk(tree):
+                if (isinstance(node, ast.Attribute)
+                        and isinstance(node.value, ast.Name)
+                        and node.value.id == "bench"):
+                    taken.setdefault(node.attr, os.path.relpath(path, REPO))
+        return taken
+
+    def test_every_name_taken_from_bench_resolves(self):
+        import bench
+
+        taken = self._taken()
+        assert {"apply_tpu_defaults", "build_model", "build_optimizer",
+                "DEFAULT_POLICY"} <= set(taken)  # the walk found the users
+        missing = {n: f for n, f in taken.items() if not hasattr(bench, n)}
+        assert not missing, missing
+
+    def test_bench_runs_nothing(self):
+        import bench
+
+        for gone in ("main", "run_model", "run_long_context"):
+            assert not hasattr(bench, gone), gone
 
 
 class TestNoChipMeansFailure:

@@ -194,37 +194,6 @@ def test_flash_attention_gqa_grads():
     np.testing.assert_allclose(np.asarray(g[2]), np.asarray(gr[2]), atol=5e-4, rtol=5e-4)
 
 
-def test_add_rms_norm_forward_and_grads():
-    from paddle_tpu.ops.pallas.add_rms_norm import add_rms_norm
-
-    rng = np.random.RandomState(7)
-    x = jnp.asarray(rng.randn(16, 64), jnp.float32)
-    r = jnp.asarray(rng.randn(16, 64), jnp.float32)
-    w = jnp.asarray(rng.randn(64), jnp.float32)
-
-    def ref(x, r, w):
-        y = x + r
-        var = jnp.mean(jnp.square(y), axis=-1, keepdims=True)
-        return y, y * jax.lax.rsqrt(var + 1e-6) * w
-
-    y, o = add_rms_norm(x, r, w)
-    y_ref, o_ref = ref(x, r, w)
-    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), atol=1e-5, rtol=1e-5)
-    np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref), atol=1e-5, rtol=1e-5)
-
-    def loss(fn):
-        def f(x, r, w):
-            y, o = fn(x, r, w)
-            # use BOTH outputs so the shared dy cotangent path is exercised
-            return jnp.sum(jnp.square(o)) + jnp.sum(y * 0.5)
-        return f
-
-    g = jax.grad(loss(add_rms_norm), argnums=(0, 1, 2))(x, r, w)
-    gr = jax.grad(loss(ref), argnums=(0, 1, 2))(x, r, w)
-    for a, b in zip(g, gr):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4, rtol=1e-4)
-
-
 def test_fused_rms_norm_residual_tuple_contract():
     # reference returns (out, residual_out) when residual is passed
     # (incubate/nn/functional/fused_rms_norm.py:59 overloads)

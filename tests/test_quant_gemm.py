@@ -34,8 +34,7 @@ def _no_ambient_mesh(monkeypatch):
 def _clean_quant_env(monkeypatch):
     """Quant decisions read env at trace time — every test starts from
     an unset knob set so nothing leaks between tests."""
-    for k in qgemm.QUANT_KNOBS + ("PTPU_BENCH_QUANT", "PTPU_SCAN_LAYERS",
-                                  "PTPU_INT8_FFN"):
+    for k in qgemm.QUANT_KNOBS + ("PTPU_SCAN_LAYERS",):
         monkeypatch.delenv(k, raising=False)
     yield
     # trace-time flop-rate latch is module state: drop it so later
@@ -367,15 +366,20 @@ class TestDeclineMatrix:
         assert sites == frozenset()
         assert verdict == ("declined", "quant_parity_gate")
 
-    def test_int8_ffn_owns_its_sites_only(self, monkeypatch):
+    def test_fused_ffn_owns_its_site_only(self, monkeypatch):
         monkeypatch.setenv("PTPU_QUANT_COMPUTE", "1")
-        monkeypatch.setenv("PTPU_INT8_FFN", "1")
+        monkeypatch.setenv("PTPU_FUSED_FFN", "interpret")
         sites, dtype, verdict = self._resolve(monkeypatch)
-        assert sites == frozenset({"wq", "wk", "wv", "wo"})
+        assert sites == frozenset(quant.GEMM_SITES) - {"wd"}
         assert verdict == ("engaged", "engaged")
-        # ffn-only request: everything owned away -> nothing engages
+        # ffn-only request: swiglu_down takes wd, gate/up stay engaged
         sites, dtype, verdict = self._resolve(
             monkeypatch, policy="names:quant:ffn")
+        assert sites == frozenset({"wg", "wu"})
+        assert verdict == ("engaged", "engaged")
+        # the one owned site alone: owned away -> nothing engages
+        sites, dtype, verdict = self._resolve(
+            monkeypatch, policy="names:quant:wd")
         assert sites == frozenset() and dtype is None
         assert verdict == ("declined", "fused_kernel_owns_gemm")
 

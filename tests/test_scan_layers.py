@@ -344,8 +344,6 @@ class TestFusedFfnSeam:
         monkeypatch.setenv("PTPU_FUSED_FFN", "interpret")
         assert _fused_ffn_active(None)
         assert not _fused_ffn_active(object())  # a live TPSeamPlan
-        monkeypatch.setenv("PTPU_INT8_FFN", "1")
-        assert not _fused_ffn_active(None)
 
 
 class TestCheckpointLayoutRoundTrip:

@@ -455,7 +455,7 @@ def main(argv=None):
                     help="diff mode: how many regressed metrics to show")
     ap.add_argument("--timeline", action="store_true",
                     help="the input is a timeline JSONL (recorded by "
-                    "TimeSeriesRecorder / a soak / bench.py --record): "
+                    "TimeSeriesRecorder / a soak): "
                     "print per-metric delta/rate columns between "
                     "consecutive samples")
     args = ap.parse_args(argv)

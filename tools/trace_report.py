@@ -7,9 +7,8 @@ Usage:
 
 Accepts both formats the tracer exports (docs/TELEMETRY.md Tracing):
 
-- Perfetto/Chrome trace-event JSON (``trace.to_perfetto`` /
-  ``bench.py --trace``): ``{"traceEvents": [...]}`` with ``ts``/``dur``
-  in microseconds,
+- Perfetto/Chrome trace-event JSON (``trace.to_perfetto``):
+  ``{"traceEvents": [...]}`` with ``ts``/``dur`` in microseconds,
 - the compact JSONL (``trace.dump_jsonl``): one event per line with
   ``ts``/``dur`` in seconds and a leading ``{"ph": "meta", ...}`` line.
 
