@@ -109,6 +109,11 @@ _CACHE_BYTES = _telemetry.gauge(
     "device, 'algorithm' what the model kind must keep for as many "
     "tokens (a latent row of 576 values lies in 640 lanes)",
     labelnames=("kind",))
+_PREFILL_PASSES = _telemetry.counter(
+    "serving_prefill_passes_total",
+    "chunked prefill passes launched, by the row count the pass was "
+    "compiled at (a step of the engine's ladder, docs/SERVING.md)",
+    labelnames=("rows",))
 _PROGRAM_TEMP_BYTES = _telemetry.gauge(
     "serving_program_temp_bytes",
     "temporary device bytes of each compiled serving program, from its "
@@ -448,6 +453,18 @@ class PagePool:
     @property
     def available(self):
         return len(self._free)
+
+
+def _pass_row_ladder(max_slots):
+    """The row counts a chunked prefill pass is compiled at: the powers
+    of two below ``max_slots``, then ``max_slots`` itself (1, 2, 4, 6
+    for six slots). A pass runs at the smallest step that holds its
+    prefilling rows, so it pays for under twice the rows it has."""
+    steps, n = [], 1
+    while n < max_slots:
+        steps.append(n)
+        n *= 2
+    return (*steps, max_slots)
 
 
 class _Request:
@@ -965,12 +982,15 @@ class ContinuousBatchingEngine:
                 f"prefill_chunk must be >= 1, got {prefill_chunk}")
         self.prefill_chunk = prefill_chunk
         self.prefills_completed = 0   # per-request (both prefill modes)
-        # batched chunked prefill: ONE jitted fixed-shape pass advances
-        # every prefilling slot by up to prefill_chunk tokens per tick
+        # batched chunked prefill: ONE jitted pass advances every
+        # prefilling slot by up to prefill_chunk tokens per tick
         # (VERDICT r3 item 7 — the eager per-request chunk loop paid the
-        # ~2.5ms/dispatch host cost per layer per request)
+        # ~2.5ms/dispatch host cost per layer per request), as wide as
+        # the step of the row ladder that holds the rows with a chunk:
+        # one function, one compiled program a step
         self._prefill_jit = jax.jit(self._prefill_chunk_step,
                                     donate_argnums=(5,))
+        self._pass_rows = _pass_row_ladder(max_slots)
         self.prefill_chunk_steps = 0  # observability: jitted pass count
         self._greedy_consts = None
         self._first_token_jit = jax.jit(self._first_token_step,
@@ -1501,15 +1521,18 @@ class ContinuousBatchingEngine:
         # chunked mode: KV fills incrementally in step()
 
     def _prefill_chunk_step(self, weights, ids, pos0, nvalid, hist, cache):
-        """ONE jitted fixed-shape chunk pass over ALL prefilling slots:
-        ids [B, c] chunk tokens (zero-padded), pos0 [B] absolute start,
-        nvalid [B] real tokens this chunk (0 for a slot with none: its
-        writes go to the scratch page), hist [B, pages_per_seq] page
-        tables. Returns (final-normed last-valid hidden [B, H], the
-        pools), and after the hidden rows the model kind's counts if it
-        keeps any.
-        Shapes are engine constants (max_slots x prefill_chunk x
-        pages_per_seq), so this compiles ONCE."""
+        """ONE jitted chunk pass over the B rows it is handed, the
+        prefilling slots packed into the first of them: ids [B, c] chunk
+        tokens (zero-padded), pos0 [B] absolute start, nvalid [B] real
+        tokens this chunk (0 for a row with none: its writes go to the
+        scratch page), hist [B, pages_per_seq] page tables. Returns
+        (final-normed last-valid hidden [max_slots, H], the pools), and
+        after the hidden rows the model kind's counts if it keeps any.
+        B is a step of the engine's row ladder (``_pass_rows``), chunk
+        and pages_per_seq are engine constants: one compile a step,
+        every one of them made by ``warmup()``. The hidden rows come
+        back at the full ``max_slots`` (zeros past B), so the
+        first-token program has one shape whatever the pass's width."""
         jnp = self._jnp
         from ..models.gpt import _rms_pure
 
@@ -1521,6 +1544,8 @@ class ContinuousBatchingEngine:
         x, stats = self._arch.carry_out(x)
         last_rows = jnp.clip(nvalid - 1, 0, c - 1)
         last = _rms_pure(x[jnp.arange(B), last_rows], weights["fnorm"])
+        if B < self.max_slots:
+            last = jnp.pad(last, ((0, self.max_slots - B), (0, 0)))
         return (last, cache) if stats is None else (last, stats, cache)
 
     def _prefill_tick(self, span):
@@ -1530,15 +1555,19 @@ class ContinuousBatchingEngine:
         incrementally (the reference serving stack's chunked-prefill /
         mixed-batch scheduling over block_multihead_attention; r3's
         eager per-request loop paid the per-dispatch host cost per layer
-        per request). ``span`` is the tick's ``prefill_tick`` span: a
-        pass that launches annotates it with how many of the positions
-        it computes are real."""
+        per request). The pass is as wide as the smallest step of the
+        row ladder that holds the rows with a chunk: a lone prompt runs
+        a one-row program, a full house the ``max_slots``-row one.
+        ``span`` is the tick's ``prefill_tick`` span: a pass that
+        launches annotates it with its width and with how many of the
+        positions it computes are real."""
         jnp = self._jnp
         reqs = [r for r in self._slots
                 if r is not None and r.prefill_pos < len(r.seq_tokens)]
         if not reqs:
             return
-        B, c = self.max_slots, self.prefill_chunk
+        B = next(n for n in self._pass_rows if n >= len(reqs))
+        c = self.prefill_chunk
         with _trace.span("prefill_build", cat="serve"):
             # brownout L3: a live chunk cap shrinks the per-tick prefill
             # token budget WITHOUT recompiling — the jitted pass keeps
@@ -1556,8 +1585,10 @@ class ContinuousBatchingEngine:
                 pos0[i], nvalid[i] = pos, n
                 hist[i, :len(r.pages)] = r.pages[:self.pages_per_seq]
         if _trace.enabled():
-            span.annotate(rows=len(reqs), valid_tokens=int(nvalid.sum()),
+            span.annotate(rows=len(reqs), pass_rows=B,
+                          valid_tokens=int(nvalid.sum()),
                           computed_tokens=B * c)
+        _PREFILL_PASSES.inc(labels=(str(B),))
         with _trace.span("prefill_launch", cat="serve"):
             last, *stats, self.cache = self._prefill_jit(
                 self._weights, jnp.asarray(ids_np), jnp.asarray(pos0),
@@ -1970,17 +2001,20 @@ class ContinuousBatchingEngine:
 
     def _first_token_step(self, weights, last, temps, top_ks, top_ps, key,
                           do_sample=False):
-        """The head and the choice of a first token for EVERY row of a
-        prefill pass ([B, H] final-normed hidden rows), one fixed shape:
-        the host keeps the rows that finished their prompt."""
+        """The head and the choice of a first token for EVERY row a
+        prefill pass hands back ([max_slots, H] final-normed hidden
+        rows, zeros past the pass's own width), one fixed shape: the
+        host keeps the rows that finished their prompt."""
         return self._choose(self._head_logits(weights, last), temps, top_ks,
                             top_ps, key, do_sample)
 
     def _first_tokens(self, last, completed):
         """First tokens of the rows ``completed`` ([(row, request)]) of a
-        chunked prefill pass, through ONE compiled program over all rows
-        (not an eager program a count of finished rows, each with its
-        own compiles: 64 slots were 580 of them)."""
+        chunked prefill pass, through ONE compiled program over
+        ``max_slots`` rows whatever the pass's width (``last`` comes
+        padded from the pass: the head reads its weights once either
+        way), not an eager program a count of finished rows, each with
+        its own compiles: 64 slots were 580 of them."""
         jax = self._jax
         do_sample = any(r.temperature > 0.0 for _, r in completed)
         if do_sample:
@@ -2264,10 +2298,13 @@ class ContinuousBatchingEngine:
         ``self.build_seconds`` — the replica cold-start number the
         serving bench records and bench_gate gates (docs/SERVING.md) —
         and each program's temporary and aliased bytes in
-        ``self.program_bytes``. Greedy programs only unless
+        ``self.program_bytes``. The chunked prefill pass is compiled
+        and run at EVERY step of the row ladder (``prefill`` at
+        ``max_slots`` rows, ``prefill_r<N>`` below it), so that no
+        arrival pattern compiles later. Greedy programs only unless
         ``sample=True`` (the first sampled tick otherwise pays its own
         compile). A ``prefill_only`` engine compiles only its prefill
-        program — the decode/verify programs never run there, and
+        programs — the decode/verify programs never run there, and
         charging their compile into the gated cold-start number would
         overstate real spin-up cost."""
         jax, jnp = self._jax, self._jnp
@@ -2284,13 +2321,15 @@ class ContinuousBatchingEngine:
                 self._decode_jit, *self._dummy_decode_operands(do_sample))
             np.asarray(nxt)           # block: compile + first dispatch
         if self.prefill_chunk is not None:
-            B, c = self.max_slots, self.prefill_chunk
-            last, *_stats, self.cache = self._warm(
-                "prefill", self._prefill_jit,
-                self._weights, jnp.zeros((B, c), jnp.int32),
-                jnp.zeros((B,), jnp.int32), jnp.zeros((B,), jnp.int32),
-                tables, self.cache)
-            np.asarray(last)
+            c = self.prefill_chunk
+            for B in self._pass_rows:
+                last, *_stats, self.cache = self._warm(
+                    "prefill" if B == b else f"prefill_r{B}",
+                    self._prefill_jit,
+                    self._weights, jnp.zeros((B, c), jnp.int32),
+                    jnp.zeros((B,), jnp.int32), jnp.zeros((B,), jnp.int32),
+                    tables[:B], self.cache)
+                np.asarray(last)
             # the first-token program holds no pool: compiled and run, not
             # among ``program_bytes``
             np.asarray(self._first_token_jit(

@@ -374,7 +374,8 @@ class TestEngineFeaturesOnTheLatentModel:
         eng = _engine(latent_model(2), max_slots=4)
         eng.warmup()
         pool = eng.cache[0].nbytes
-        assert set(eng.program_bytes) == {"decode", "prefill"}
+        assert set(eng.program_bytes) == {"decode", "prefill",
+                                          "prefill_r1", "prefill_r2"}
         for name, nb in eng.program_bytes.items():
             assert nb["alias"] >= pool, (name, nb, pool)
 
@@ -435,3 +436,49 @@ class TestEngineFeaturesOnTheLatentModel:
         kinds = snap["gauges"]["serving_cache_bytes"]
         assert len(kinds) == 2
         assert eng.program_bytes == {}          # no warmup here
+
+
+class TestPrefillPassWidth:
+    """ISSUE 33, the latent kind: its chunk attention takes rows in
+    groups and every token of a pass goes through the router, so a
+    narrower pass hands the expert layers fewer pad tokens; a real row
+    comes to the same."""
+
+    @pytest.fixture(scope="class")
+    def served(self):
+        """(model, its subject row through the full-width pass alone)."""
+        import _prefill_width as pw
+
+        model = latent_model(6)
+        return model, pw.serve_beside(model, 0, True)
+
+    @pytest.mark.parametrize("neighbours", [0, 1, 3, 7])
+    def test_a_row_does_not_depend_on_the_rows_beside_it(self, served,
+                                                         neighbours):
+        import _prefill_width as pw
+
+        pw.assert_same_row(served[0], neighbours, served[1])
+
+    def test_a_narrow_pass_routes_fewer_pad_tokens(self):
+        """The counts a pass reports are over the rows it computed: one
+        prompt of one chunk through a one-row pass routes a chunk's
+        tokens, through the full-width pass eight rows of them."""
+        import _prefill_width as pw
+
+        pairs = {}
+        for full in (False, True):
+            eng = _engine(latent_model(6), max_slots=pw.SLOTS,
+                          prefill_only=True)       # no decode tick's counts
+            if full:
+                eng._pass_rows = (pw.SLOTS,)
+            seen = []
+            note = eng._arch.note_stats
+            eng._arch.note_stats = lambda st: seen.append(
+                [int(v) for v in st]) or note(st)
+            eng.submit(list(range(1, 9)))
+            eng.step()
+            eng._drain_stats()
+            (stats,) = seen
+            pairs[full] = stats[0]
+            assert stats[3] == 0                      # nothing dropped
+        assert 0 < pairs[False] < pairs[True]

@@ -555,7 +555,8 @@ class TestKvWriteRun:
             want_s[:, :, :-1].tobytes()
 
 
-_POOL_PROGRAMS = ("decode", "prefill", "verify", "draft_window_c2")
+_POOL_PROGRAMS = ("decode", "prefill", "prefill_r1", "verify",
+                  "draft_window_c2")
 
 
 def _walk_eqns(jaxpr):
@@ -706,9 +707,10 @@ def test_batched_prefill_single_compile_and_throughput():
           f" {toks / dt:.1f} tok/s over {toks} tokens")
     # every prefilling slot advances per tick through ONE jitted pass
     assert eng.prefill_chunk_steps > 0
-    # the pass is fixed-shape: exactly one compilation of the chunk step
+    # the pass's shapes are the row ladder's: one compilation of the
+    # chunk step a step of it, however the arrivals fall
     sizes = eng._prefill_jit._cache_size()
-    assert sizes == 1, sizes
+    assert 1 <= sizes <= len(eng._pass_rows) == 3, sizes
 
 
 @pytest.mark.parametrize("kind", MODEL_KINDS)
@@ -1219,3 +1221,111 @@ class TestPageEconomics:
             f"swap thrash: {eng.swaps_out} round-trips")
         for rid in done:
             assert done[rid] == want[rid], (rid, done[rid], want[rid])
+
+
+# ------------------------------------------ the width of a prefill pass
+class TestPrefillPassWidth:
+    """ISSUE 33: a chunked prefill pass is as wide as the smallest step
+    of the engine's row ladder that holds the rows with a chunk. One
+    jitted function, one compiled program a step, all of them made by
+    ``warmup()``; a row's result does not depend on the width."""
+
+    @pytest.mark.parametrize("slots,want", [
+        (1, (1,)), (4, (1, 2, 4)), (6, (1, 2, 4, 6)),
+        (32, (1, 2, 4, 8, 16, 32)), (64, (1, 2, 4, 8, 16, 32, 64))])
+    def test_ladder(self, slots, want):
+        from paddle_tpu.inference.serving import _pass_row_ladder
+
+        steps = _pass_row_ladder(slots)
+        assert steps == want
+        assert steps[-1] == slots and len(set(steps)) == len(steps)
+        assert list(steps) == sorted(steps)
+        # any count of rows has a step, under twice as wide
+        for rows in range(1, slots + 1):
+            step = next(n for n in steps if n >= rows)
+            assert rows <= step < 2 * rows or step == slots
+
+    @pytest.fixture(scope="class", params=[
+        ("mha", 4, {}), ("gqa", 2, {}), ("int8-pool", 2, {"int8_kv": True})],
+        ids=lambda p: p[0])
+    def dense(self, request):
+        """(model, its subject row through the full-width pass alone,
+        engine arguments) of one dense kind."""
+        import _prefill_width as pw
+
+        _, kv_heads, engine_kw = request.param
+        cfg = LlamaConfig(vocab_size=96, hidden_size=64, num_layers=2,
+                          num_heads=4, num_kv_heads=kv_heads,
+                          max_seq_len=128, dropout=0.0)
+        paddle.seed(11)
+        model = LlamaForCausalLM(cfg)
+        base = pw.serve_beside(model, 0, True, **engine_kw)
+        if engine_kw:
+            assert base[1][0].dtype == np.int8
+        return model, base, engine_kw
+
+    @pytest.mark.parametrize("neighbours", [0, 1, 3, 7])
+    def test_a_row_does_not_depend_on_the_rows_beside_it(self, dense,
+                                                         neighbours):
+        import _prefill_width as pw
+
+        model, base, engine_kw = dense
+        pw.assert_same_row(model, neighbours, base, **engine_kw)
+
+    @staticmethod
+    def _staggered(eng):
+        """Arrivals that put 1, 2, 4 and 8 rows into a pass, in turn:
+        each prompt is long enough to be prefilling when the next come."""
+        rng = np.random.default_rng(3)
+        for more in (1, 1, 2, 4):
+            for _ in range(more):
+                eng.submit(rng.integers(1, 96, 40).tolist())
+            eng.step()
+        return eng.run_until_complete()
+
+    @pytest.mark.parametrize("kind", MODEL_KINDS)
+    def test_no_compile_after_warmup_on_any_step(self, kind):
+        import paddle_tpu.telemetry as telemetry
+        from paddle_tpu.telemetry import trace
+
+        eng = ContinuousBatchingEngine(
+            _model_of(kind, seed=5), max_slots=8, page_size=8,
+            max_seq_len=64, max_new_tokens=3, prefill_chunk=4)
+        eng.warmup()
+        assert set(eng.program_bytes) == {
+            "decode", "prefill", "prefill_r1", "prefill_r2", "prefill_r4"}
+        jits = (eng._prefill_jit, eng._first_token_jit, eng._decode_jit)
+        before = [j._cache_size() for j in jits]
+        assert before == [4, 1, 1]
+        telemetry.enable()
+        trace.enable()
+        trace.reset()
+        try:
+            done = self._staggered(eng)
+            # brownout L3 narrows a pass's valid tokens, not its shapes
+            eng.prefill_chunk_cap = 2
+            done.update(self._staggered(eng))
+            events = trace.events()
+            passes = telemetry.snapshot()["counters"][
+                "serving_prefill_passes_total"]
+        finally:
+            trace.disable()
+            trace.reset()
+            telemetry.disable()
+            telemetry.reset()
+        assert len(done) == 16
+        assert [j._cache_size() for j in jits] == before
+        assert not [e for e in events if e["name"] == "xla_compile"]
+        launched = [e["attrs"] for e in events
+                    if e["name"] == "prefill_tick" and e["attrs"]]
+        assert {a["pass_rows"] for a in launched} == {1, 2, 4, 8}
+        for a in launched:
+            assert a["computed_tokens"] == a["pass_rows"] * 4
+            assert a["rows"] <= a["pass_rows"] < 2 * a["rows"] or (
+                a["pass_rows"] == 8)
+            assert a["valid_tokens"] <= a["rows"] * 4
+        # the counter names each pass by the width it was compiled at
+        assert passes == {
+            f"rows={n}": float(sum(a["pass_rows"] == n for a in launched))
+            for n in (1, 2, 4, 8)}
+        assert sum(passes.values()) == eng.prefill_chunk_steps

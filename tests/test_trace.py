@@ -779,7 +779,11 @@ def test_prefill_tick_attrs_sum_to_the_prompts_tokens():
     assert len(launched) == eng.prefill_chunk_steps < len(spans)
     assert sum(a["valid_tokens"] for a in launched) == sum(
         len(r.prompt) for r in reqs) == 35
-    assert {a["computed_tokens"] for a in launched} == {2 * 8}
+    # a pass is as wide as the ladder's step over its rows (1 or 2 of
+    # two slots), and computes that many rows of a chunk
+    assert all(a["pass_rows"] == a["rows"] for a in launched)
+    assert all(a["computed_tokens"] == a["pass_rows"] * 8 for a in launched)
+    assert {a["pass_rows"] for a in launched} == {1, 2}
     assert all(1 <= a["rows"] <= 2 for a in launched)
     assert all(a["valid_tokens"] <= a["rows"] * 8 for a in launched)
 
