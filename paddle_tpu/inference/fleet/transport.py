@@ -726,7 +726,8 @@ class ReplicaServer:
         """Serialize EVERYTHING queued or running and empty the engine:
         the KV-migration point of a rolling upgrade.  Occupied slots go
         through ``extract()`` (host KV snapshot rides along); waiting
-        requests ship as-is."""
+        requests ship as-is.  ``extract()`` settles the decode tick in
+        flight, so its tokens ride in this reply: no step follows."""
         eng = self.engine
         running = []
         for i, r in enumerate(eng._slots):
@@ -735,9 +736,10 @@ class ReplicaServer:
         waiting = []
         while eng._waiting:
             waiting.append(wire.request_to_wire(eng._waiting.popleft()))
+        events = self._drain_events(a.get("resync"))
         for w in running + waiting:
             self._retire_stream(w["rid"])
-        return {"running": running, "waiting": waiting}
+        return {"running": running, "waiting": waiting, "events": events}
 
     def _rpc_steal(self, a):
         """Pop up to ``n`` WAITING requests off the back of the queue —
@@ -1169,7 +1171,9 @@ class RemoteEngine:
         return int(self.transport.call("inject", {"req": req_wire}))
 
     def drain_requests(self):
-        return self.transport.call("drain", {})
+        reply = self.transport.call("drain", self._resync_args())
+        self._absorb(reply)
+        return reply
 
     def steal_requests(self, n):
         """Pop up to ``n`` waiting requests (KV snapshots ride along)

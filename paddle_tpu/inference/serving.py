@@ -11,7 +11,11 @@ drive it). TPU-native design:
   requests into free slots (prefill writes the prompts' KV into their
   pages), then run ONE batched decode step for every live slot per
   `step()` — new requests join mid-flight without stalling running ones,
-  finished slots free their pages immediately.
+  finished slots free their pages immediately. A decode tick is
+  launched from the device's own next tokens BEFORE the tick ahead of
+  it is fetched, so fetch, emit, retire, admission and the caller's
+  loop run under the device's work (docs/SERVING.md "The step's
+  order").
 - Admission prefills ALL newly admitted prompts as one padded batch —
   one pass over the weights per admission group, not per request.
 - The decode step's attention is the pallas paged kernel
@@ -43,7 +47,7 @@ import math
 import os
 import time
 import warnings
-from collections import deque
+from collections import deque, namedtuple
 
 import numpy as np
 
@@ -88,6 +92,18 @@ _SPEC_TICKS = _telemetry.counter(
     "decode ticks under a draft model: 'spec' ran draft+verify, "
     "'fallback' took the plain single-token path (sampled rows live)",
     labelnames=("mode",))
+_DECODE_TICKS = _telemetry.counter(
+    "serving_decode_ticks_total",
+    "plain decode ticks by how they were launched: 'ahead' while the "
+    "tick before was still unfetched (its tokens went from program to "
+    "program on the device), 'settled' with nothing in flight",
+    labelnames=("mode",))
+_DISCARDED = _telemetry.counter(
+    "serving_decode_discarded_tokens_total",
+    "tokens of a tick launched ahead that were never emitted: the row "
+    "had 'ended' at the tick before (eos, a lowered max_new_cap) or was "
+    "'withdrawn' (cancelled) while the tick was in flight",
+    labelnames=("reason",))
 _SPEC_DRAFTED = _telemetry.counter(
     "serving_spec_draft_tokens_total",
     "draft tokens proposed to the verifier")
@@ -465,6 +481,12 @@ def _pass_row_ladder(max_slots):
         steps.append(n)
         n *= 2
     return (*steps, max_slots)
+
+
+#: a decode tick launched and not yet fetched: its ``nxt`` on the device,
+#: [(slot, request)] of its rows, {id(request): row} (not by rid: a fleet
+#: replays a cancelled request under its old rid), its ``decode_tick`` span
+_Tick = namedtuple("_Tick", "nxt live row_of span")
 
 
 class _Request:
@@ -909,7 +931,22 @@ class ContinuousBatchingEngine:
         # weights are argument 0 — NOT closed-over jit constants — so a
         # reload on a live engine feeds the already-compiled step
         self._decode_jit = jax.jit(self._decode_step, donate_argnums=(4,),
-                                   static_argnums=(9,))
+                                   static_argnums=(11,))
+        # the decode tick launched and not yet fetched, a ``_Tick``
+        # (docs/SERVING.md "The step's order"). The next tick takes its
+        # tokens from that tick's ``nxt`` on the device; ``_settle()``
+        # brings them to the host. With nothing in flight a tick reads
+        # ``_no_tick`` in its place, a vector of nxt's shape (the
+        # tokens, then what the model kind counts of a tick)
+        self._in_flight = None
+        _, counts = jax.eval_shape(
+            lambda x: self._arch.carry_out(self._arch.carry_in(x)),
+            jax.ShapeDtypeStruct((1, 1, 1), jnp.float32))
+        self._no_tick = jnp.zeros(
+            (max_slots + (0 if counts is None else counts.shape[0]),),
+            jnp.int32)
+        self.decode_ticks = {"ahead": 0, "settled": 0}
+        self.discarded_tokens = {"ended": 0, "withdrawn": 0}
         self.prefill_batches = 0      # observability: admission group count
         self.preemptions = 0          # pages reclaimed from the youngest
         self._admit_counter = 0
@@ -1090,7 +1127,9 @@ class ContinuousBatchingEngine:
         the headroom, so a mid-pack failure cannot fall back to the old
         weights — it raises loudly and the engine stays weightless until
         a reload succeeds (serving on half-reloaded state would be
-        worse)."""
+        worse). A tick in flight reads the old weights and is settled
+        first, or the release would free nothing yet."""
+        self._settle()
         self._weights = None
         try:
             self._weights = self._pack_weights(model or self._model)
@@ -1212,15 +1251,20 @@ class ContinuousBatchingEngine:
         return jnp.argmax(lg.astype(jnp.float32), -1).astype(jnp.int32)
 
     def _decode_step(self, weights, tokens, lens, tables, cache,
-                     temps, top_ks, top_ps, key, do_sample=False):
+                     temps, top_ks, top_ps, key, prev, src,
+                     do_sample=False):
         """ONE batched decode: tokens [B] (last emitted), lens [B] tokens
-        already cached, tables [B, pages_per_seq]. Returns (next [B],
-        the pools). What the model kind counts of a tick (``carry_out``:
-        an expert layer's routed pairs) is appended to ``next``: it
-        comes to the host in the tokens' own fetch."""
+        already cached, tables [B, pages_per_seq]. A row whose last
+        token the host has not read yet takes it from ``prev``, the
+        ``next`` of the tick before, at row ``src`` [B] (-1: the host's
+        ``tokens`` holds it). Returns (next [B], the pools). What the
+        model kind counts of a tick (``carry_out``: an expert layer's
+        routed pairs) is appended to ``next``: it comes to the host in
+        the tokens' own fetch."""
         jnp = self._jnp
         from ..models.gpt import _rms_pure
 
+        tokens = jnp.where(src >= 0, prev[src], tokens)
         x = weights["embed"][tokens][:, None]                # [B, 1, H]
         attend = self._arch.decode_attend(tables, lens)
         x, cache = self._run_layers(weights, self._arch.carry_in(x), lens,
@@ -1646,6 +1690,7 @@ class ContinuousBatchingEngine:
         mid-prefill victim's untouched prompt pages and grown-but-empty
         decode pages never leave the device; restore re-allocates the
         full reservation from prefill_pos/length bookkeeping)."""
+        self._settle()
         got = self._swap_out_jit(self.cache, self._padded_page_vec(r.pages))
         written = max(r.length, r.prefill_pos)
         n = min((written + self.page - 1) // self.page, len(r.pages))
@@ -1784,6 +1829,7 @@ class ContinuousBatchingEngine:
         the pages to host first — re-admission restores the KV with zero
         recompute. Correctness is bitwise for greedy decodes under both
         policies (asserted by tests)."""
+        self._settle()
         r = self._slots[slot_idx]
         if self.preempt_policy == "swap" and r.pages:
             # NOTE: the gather materialises [L, Hkv, P, page, D] on device
@@ -1815,7 +1861,7 @@ class ContinuousBatchingEngine:
                              {"policy": self.preempt_policy})
         _trace.async_begin("queue", r.rid, {"requeue": True})
 
-    def _grow_pages(self):
+    def _grow_pages(self, newly):
         """Ensure every decoding slot owns pages for this tick's token.
         On pool exhaustion, preempt the YOUNGEST running request (its
         oldest peers keep their pages and finish first — guaranteed
@@ -1823,14 +1869,19 @@ class ContinuousBatchingEngine:
         feasibility check). Under a draft model the reservation covers
         the whole speculative window (K drafts + carry) instead of one
         token; a prefill-only engine never grows (its admissions reserve
-        every page chunked prefill will write)."""
+        every page chunked prefill will write). A row's length was
+        advanced when its tick was launched, so the pages follow the
+        launches, not the fetches. Before a victim is chosen the tick
+        in flight is settled and what it finished is retired into
+        ``newly``: a row that only waited for its last token gives its
+        pages back instead of being evicted with them."""
         if self.prefill_only:
             return
         while True:
             # oldest-first service order
             live = sorted(
                 ((i, r) for i, r in enumerate(self._slots)
-                 if r is not None and r.length > 0),
+                 if r is not None and self._decodes(r)),
                 key=lambda ir: ir[1].admit_seq)
             short = None
             for i, r in live:
@@ -1851,6 +1902,10 @@ class ContinuousBatchingEngine:
                     break
             if short is None:
                 return
+            if self._in_flight is not None:
+                self._settle()
+                self._retire_finished(newly)
+                continue
             # youngest victim across ALL occupied slots — a just-admitted
             # mid-prefill request is younger than any decoding one, so
             # the oldest running requests keep their pages and finish
@@ -1861,19 +1916,35 @@ class ContinuousBatchingEngine:
             victim = max(occupied, key=lambda ir: ir[1].admit_seq)
             self._preempt(victim[0])
 
-    def _finished(self, r):
+    def _finished(self, r, pending=0):
         """True when a request has nothing left to generate: max_new
         reached, or its newest token is eos. THE completion predicate —
         retire, the decode-tick live filter, and the disagg handoff
-        sweep all share it. A live brownout L1 cap (``max_new_cap``)
-        lowers the limit for every request still generating; restoring
-        the cap to None restores the full budget."""
+        sweep all share it. ``pending`` counts a token launched and not
+        yet fetched (``_pending``): by count, the host knows a row's
+        last tick when it launches it. A live brownout L1 cap
+        (``max_new_cap``) lowers the limit for every request still
+        generating; restoring the cap to None restores the full
+        budget."""
         limit = self.max_new_tokens
         if self.max_new_cap is not None:
             limit = min(limit, self.max_new_cap)
-        return (len(r.generated) >= limit
+        return (len(r.generated) + pending >= limit
                 or (self.eos is not None and bool(r.generated)
                     and r.generated[-1] == self.eos))
+
+    def _pending(self, r):
+        """1 while a token of ``r`` is in flight (launched, unfetched)."""
+        return int(self._in_flight is not None
+                   and id(r) in self._in_flight.row_of)
+
+    def _decodes(self, r):
+        """True for a slot's request that the next decode tick carries:
+        its prompt is cached, and it is not finished by anything the
+        host knows, the count of a token in flight included. Pages grow
+        for these rows and no others."""
+        return (bool(r.generated) and r.length > 0
+                and not self._finished(r, self._pending(r)))
 
     def _retire(self, req: _Request):
         _REQ_LATENCY.observe(time.perf_counter() - req.submit_t)
@@ -1883,11 +1954,27 @@ class ContinuousBatchingEngine:
                              {"generated_tokens": len(req.generated)})
         return req.prompt + req.generated
 
+    def _retire_finished(self, newly):
+        """Retire every slot whose request the host knows to be
+        finished, into ``newly`` ({rid: full ids})."""
+        for i, r in enumerate(self._slots):
+            if r is not None and self._finished(r):
+                newly[r.rid] = self._retire(r)
+                self._slots[i] = None
+
     def step(self):
-        """Admit + one batched decode tick. Returns {rid: full_ids} for
-        requests finishing THIS tick. With the tracer on, the tick is
-        one ``engine_step`` span whose children are its phases
-        (docs/TELEMETRY.md Tracing)."""
+        """Admit, launch one batched decode tick, then bring the tick
+        BEFORE it to the host and emit its tokens (docs/SERVING.md "The
+        step's order"): the device computes a tick while the host reads
+        the last one, so ``on_token`` hears a token at most one tick
+        after the device made it. Returns {rid: full_ids} for the
+        requests retired in this call: a request is retired by the
+        first ``step()`` that begins with its last token on the host,
+        which is the call after the one that emitted it, or the same
+        call when the engine had nothing left to launch (the pipeline
+        drains: an idle engine holds no token back). With the tracer
+        on, the call is one ``engine_step`` span whose children are its
+        phases (docs/TELEMETRY.md Tracing)."""
         self._tick += 1
         with _trace.span("engine_step",
                          {"tick": self._tick} if _trace.enabled() else None,
@@ -1904,25 +1991,21 @@ class ContinuousBatchingEngine:
             self._sweep_deadlines()
             # retire next: a finishing slot frees pages and a slot for
             # this very tick's admissions
-            for i, r in enumerate(list(self._slots)):
-                if r is not None and self._finished(r):
-                    newly[r.rid] = self._retire(r)
-                    self._slots[i] = None
+            self._retire_finished(newly)
         with _trace.span("admission", cat="serve"):
             self._admit()
         if self.prefill_chunk is not None:
             with _trace.span("prefill_tick", cat="serve") as span:
                 self._prefill_tick(span)
         with _trace.span("grow_pages", cat="serve"):
-            self._grow_pages()
+            self._grow_pages(newly)
         # a request that hit max_new/eos at prefill completion THIS
         # tick must not decode once more before next tick's retire —
         # the off-by-one emitted max_new+1 tokens (and a token PAST
         # eos) whenever completion landed on the prefill path
         live = ([] if self.prefill_only else
                 [(i, r) for i, r in enumerate(self._slots)
-                 if r is not None and r.generated and r.length > 0
-                 and not self._finished(r)])
+                 if r is not None and self._decodes(r)])
         if _TELEMETRY_REG.enabled:
             _STEPS.inc()
             _QUEUE_DEPTH.set(len(self._waiting))
@@ -1933,6 +2016,12 @@ class ContinuousBatchingEngine:
             if live:
                 _BATCH_OCCUPANCY.observe(len(live) / self.max_slots)
         if not live:
+            # nothing to launch: the pipeline drains, and what its last
+            # tokens finish is retired in this very call
+            if self._in_flight is not None:
+                self._settle()
+                with _trace.span("retire", cat="serve"):
+                    self._retire_finished(newly)
             return newly
         # static greedy/sampling mode: one retrace per mode, and the
         # default all-greedy workload never pays the vocab sort
@@ -1944,16 +2033,23 @@ class ContinuousBatchingEngine:
             return newly
         if self._draft is not None:
             _SPEC_TICKS.inc(labels=("fallback",))
+        before = self._in_flight
+        ahead = before is not None
         with _trace.span("decode_build", cat="serve"):
             # fixed-width batch: pad with slot 0's state (results
-            # discarded)
+            # discarded). A row of the tick in flight takes its token
+            # from that tick's row on the device (``src``); a row that
+            # joined since has it on the host
             pad_to = self.max_slots
             rows = ([r for _, r in live]
                     + [live[0][1]] * (pad_to - len(live)))
+            came = before.row_of if ahead else {}
+            src = np.asarray([came.get(id(r), -1) for r in rows], np.int32)
             host = (
-                np.asarray([r.generated[-1] for r in rows], np.int32),
+                np.asarray([0 if k >= 0 else r.generated[-1]
+                            for k, r in zip(src, rows)], np.int32),
                 np.asarray([r.length for r in rows], np.int32),
-                self._table_rows(rows))
+                self._table_rows(rows), src)
             if do_sample:
                 host += (
                     np.asarray([r.temperature for r in rows], np.float32),
@@ -1965,39 +2061,88 @@ class ContinuousBatchingEngine:
             # the same device constants every tick (the host's serial
             # work is what a decode tick of a few ms waits on)
             if do_sample:
-                tokens, lens, tables, temps, top_ks, top_ps = (
+                tokens, lens, tables, src, temps, top_ks, top_ps = (
                     jax.device_put(host))
                 self._key, sub = jax.random.split(self._key)
             else:
-                tokens, lens, tables = jax.device_put(host)
+                tokens, lens, tables, src = jax.device_put(host)
                 temps, top_ks, top_ps, sub = self._greedy_operands()
         with _trace.span("decode_tick",
-                         {"live": len(live)} if _trace.enabled() else None,
+                         {"live": len(live), "ahead": int(ahead),
+                          "ticks": 1, "discarded": 0}
+                         if _trace.enabled() else None,
                          cat="serve") as tick_span:
             with _trace.span("decode_launch", cat="serve"):
                 nxt, self.cache = self._decode_jit(
                     self._weights, tokens, lens, tables, self.cache,
-                    temps, top_ks, top_ps, sub, do_sample)
-            # the host fetch is the tick's real sync point — inside the
-            # decode_tick span so its wall time includes device work
-            with _trace.span("decode_fetch", cat="serve"):
-                nxt = np.asarray(nxt)
-            if len(nxt) > pad_to:
-                # the model kind's counts rode behind the tokens
-                self._note_stats(tick_span, nxt[pad_to:])
-                self._drain_stats()
+                    temps, top_ks, top_ps, sub,
+                    before.nxt if ahead else self._no_tick, src,
+                    do_sample)
+        # what the next tick needs of this one the host knows without
+        # its tokens: every row is one token longer
+        for _, r in live:
+            r.length += 1
+        self._in_flight = _Tick(
+            nxt, live, {id(r): j for j, (_, r) in enumerate(live)},
+            tick_span)
+        mode = "ahead" if ahead else "settled"
+        self.decode_ticks[mode] += 1
+        _DECODE_TICKS.inc(labels=(mode,))
+        if ahead:
+            # the device finished the tick before this one before it
+            # began this one: the wait is what was left of that tick
+            self._fetch(before)
         if self._draft is not None:
             # fallback tick under a draft: mirror the carry token into
             # the draft's KV (proposal discarded) so the draft cache
             # stays hole-free — without this, every sampled tick leaves
             # a permanently stale draft row and speculative acceptance
-            # silently collapses once greedy ticks resume
+            # silently collapses once greedy ticks resume. A draft
+            # engine's next tick may be a speculative one, which builds
+            # its window from the host's tokens: nothing stays in flight
             self._draft.catch_up(tokens, lens, tables)
-        with _trace.span("emit", cat="serve"):
-            for j, (i, r) in enumerate(live):
-                r.length += 1
-                self._emit(r, int(nxt[j]))
+            self._settle()
         return newly
+
+    def _settle(self):
+        """Bring the decode tick in flight, if any, to the host and emit
+        its tokens (docs/SERVING.md "The step's order"). Runs before
+        anything that moves a request's host state out of its slot
+        (preemption, ``extract``), before the weights change, when a
+        step has nothing to launch, and after a plain tick under a
+        draft model."""
+        if self._in_flight is not None:
+            tick, self._in_flight = self._in_flight, None
+            self._fetch(tick)
+
+    def _fetch(self, tick):
+        """Fetch one launched tick's tokens and emit them. A row that
+        the tick before showed to be finished (eos, a lowered cap:
+        'ended'), or that left its slot while the tick was in flight
+        (cancelled: 'withdrawn'), has its token discarded: the tick was
+        launched before the host could know."""
+        # the host fetch is the tick's real sync point
+        with _trace.span("decode_fetch", cat="serve"):
+            nxt = np.asarray(tick.nxt)
+        if len(nxt) > self.max_slots:
+            # the model kind's counts rode behind the tokens
+            self._note_stats(tick.span, nxt[self.max_slots:])
+            self._drain_stats()
+        ended = withdrawn = 0
+        with _trace.span("emit", cat="serve"):
+            for j, (i, r) in enumerate(tick.live):
+                if self._finished(r):
+                    ended += 1
+                elif self._slots[i] is not r:
+                    withdrawn += 1
+                else:
+                    self._emit(r, int(nxt[j]))
+        for reason, n in (("ended", ended), ("withdrawn", withdrawn)):
+            if n:
+                self.discarded_tokens[reason] += n
+                _DISCARDED.inc(n, labels=(reason,))
+        if ended and _trace.enabled():
+            tick.span.annotate(discarded=ended)
 
     def _first_token_step(self, weights, last, temps, top_ks, top_ps, key,
                           do_sample=False):
@@ -2265,7 +2410,8 @@ class ContinuousBatchingEngine:
                 jnp.zeros((b,), jnp.int32),
                 jnp.full((b, self.pages_per_seq), self._trash_page,
                          jnp.int32),
-                self.cache, *self._greedy_operands(), do_sample)
+                self.cache, *self._greedy_operands(), self._no_tick,
+                jnp.full((b,), -1, jnp.int32), do_sample)
 
     def decode_program_text(self):
         """StableHLO text of the greedy decode tick (trace + lower, no

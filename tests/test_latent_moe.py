@@ -77,7 +77,8 @@ class TestAgainstTheReference:
                                jnp.int32)
             out, eng.cache = eng._decode_step(
                 w, toks, jnp.asarray(lens), tables, eng.cache, zeros,
-                jnp.zeros((2,), jnp.int32), zeros + 1, jax.random.PRNGKey(0))
+                jnp.zeros((2,), jnp.int32), zeros + 1, jax.random.PRNGKey(0),
+                eng._no_tick, jnp.full((2,), -1, jnp.int32))
             got = np.asarray(seen[-1])
             for b in range(2):
                 np.testing.assert_allclose(got[b], want[b][lens[b]],
@@ -436,6 +437,83 @@ class TestEngineFeaturesOnTheLatentModel:
         kinds = snap["gauges"]["serving_cache_bytes"]
         assert len(kinds) == 2
         assert eng.program_bytes == {}          # no warmup here
+
+
+class TestLaunchAhead:
+    """ISSUE 36 on the latent kind: a tick's tokens go from program to
+    program on the device, and its expert counts still ride behind them
+    to the host, one tick later."""
+
+    def test_tokens_carried_on_the_device_are_the_references_argmax(self):
+        """Requests that join a running batch mid-flight: each served
+        token is the float32 reference's first choice at its position,
+        whether the host or the device carried it into the next tick."""
+        cfg = tiny_cfg()
+        eng = _engine(latent_model(5), max_slots=3)
+        rng = np.random.default_rng(36)
+        prompts = [rng.integers(1, 96, n).tolist() for n in (6, 13, 4, 10)]
+        rids = [eng.submit(p) for p in prompts[:2]]
+        for _ in range(3):
+            eng.step()
+        rids.append(eng.submit(prompts[2]))        # joins mid-flight
+        eng.step()
+        rids.append(eng.submit(prompts[3]))        # waits for a slot
+        done = eng.run_until_complete()
+        assert eng.decode_ticks["ahead"] > eng.decode_ticks["settled"]
+        for rid, p in zip(rids, prompts):
+            ids = done[rid]
+            assert len(ids) == len(p) + 8
+            ref = _ref_logits(cfg, 5, ids[:-1])
+            gap = ref[len(p) - 1:].max(-1) - np.take_along_axis(
+                ref[len(p) - 1:], np.asarray(ids[len(p):])[:, None], 1)[:, 0]
+            assert gap.max() <= TOL, gap
+
+    def test_a_ticks_expert_counts_land_on_the_span_that_launched_it(self):
+        """The counts come to the host with the tokens, a step after the
+        tick's span closed: every ``decode_tick`` span still gets its
+        own, the last one at the drain."""
+        from paddle_tpu.telemetry import trace
+
+        trace.enable()
+        trace.reset()
+        try:
+            eng = _engine(latent_model(2))
+            for p in ([3, 4, 5, 6, 7], [9, 8, 7]):
+                eng.submit(p)
+            eng.run_until_complete()
+            events = trace.events()
+        finally:
+            trace.disable()
+        ticks = [e["attrs"] for e in events
+                 if e.get("ph") == "X" and e["name"] == "decode_tick"]
+        assert len(ticks) == sum(eng.decode_ticks.values()) == 7
+        assert [t["ahead"] for t in ticks] == [0] + [1] * 6
+        # two rows x four pairs a token x two expert layers, less what
+        # went to experts this chip does not hold
+        assert all(0 < t["local_pairs"] <= 16 and t["dropped_tokens"] == 0
+                   for t in ticks)
+
+    @pytest.mark.parametrize("policy", ("recompute", "swap"))
+    def test_preemption_streams_lose_and_repeat_nothing(self, policy):
+        """The latent pool under pressure with ticks in flight: every
+        request's stream is its roomy run's, token for token."""
+        rng = np.random.default_rng(11)
+        prompts = [rng.integers(1, 96, n).tolist() for n in (9, 14, 6, 17)]
+
+        def serve(**kw):
+            eng = _engine(latent_model(2), max_slots=4, **kw)
+            seen = {}
+            rids = [eng.submit(p, on_token=lambda r, t: seen.setdefault(
+                r, []).append(t)) for p in prompts]
+            done = eng.run_until_complete()
+            assert all(done[r] == p + seen[r]
+                       for r, p in zip(rids, prompts))
+            return eng, [seen[r] for r in rids]
+
+        _, want = serve()
+        eng, got = serve(num_pages=6, preempt_policy=policy)
+        assert eng.preemptions > 0 and got == want
+        assert eng.discarded_tokens == {"ended": 0, "withdrawn": 0}
 
 
 class TestPrefillPassWidth:

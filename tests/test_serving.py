@@ -422,6 +422,319 @@ class TestDeadlinesAndCancel:
         assert eng.prefix_cache_hits > 0
 
 
+def _ahead_engine(kind, seed=0, **kw):
+    """A small engine of either model kind (the latent kind prefills by
+    chunks only, so both do here unless a test says otherwise)."""
+    args = dict(max_slots=3, page_size=8, max_seq_len=64, prefill_chunk=8,
+                max_new_tokens=6)
+    args.update(kw)
+    return ContinuousBatchingEngine(_model_of(kind, seed), **args)
+
+
+def _alone(kind, prompt, seed=0, **kw):
+    """What the tests here take as a request's truth: its generated
+    tokens from a one-slot engine that serves nothing else."""
+    eng = _ahead_engine(kind, seed, max_slots=1, **kw)
+    rid = eng.submit(prompt)
+    return eng.run_until_complete()[rid][len(prompt):]
+
+
+def _streams(eng, prompts, **kw):
+    """Submit ``prompts`` with a recording ``on_token``: ({rid: [tokens]},
+    [rids])."""
+    seen = {}
+    rids = [eng.submit(p, on_token=lambda r, t: seen.setdefault(
+        r, []).append(t), **kw) for p in prompts]
+    return seen, rids
+
+
+def _step_until_in_flight(eng, rids, max_steps=50):
+    """Step until a decode tick that carries every one of ``rids`` has
+    been launched and not fetched."""
+    for _ in range(max_steps):
+        eng.step()
+        t = eng._in_flight
+        if t is not None and {r.rid for _, r in t.live} >= set(rids):
+            return
+    raise AssertionError("no tick in flight carried " + repr(rids))
+
+
+class TestLaunchAhead:
+    """ISSUE 36: tick N+1 is launched from the device's own next tokens
+    before tick N's are fetched (docs/SERVING.md "The step's order").
+    Every request still gets the tokens it gets when served alone."""
+
+    def _prompts(self, seed=36, sizes=(5, 11, 3, 9)):
+        rng = np.random.default_rng(seed)
+        return [rng.integers(1, 96, (n,)).tolist() for n in sizes]
+
+    @pytest.mark.parametrize("kind", MODEL_KINDS)
+    def test_staggered_arrivals_join_a_running_batch(self, kind):
+        prompts = self._prompts()
+        eng = _ahead_engine(kind)
+        seen, rids = _streams(eng, prompts[:2])
+        eng.step()
+        eng.step()
+        eng.step()
+        more, rid2 = _streams(eng, prompts[2:3])   # joins mid-flight
+        rids += rid2
+        eng.step()
+        last, rid3 = _streams(eng, prompts[3:])    # waits for a slot
+        rids += rid3
+        done = eng.run_until_complete()
+        seen.update(more)
+        seen.update(last)
+        for rid, p in zip(rids, prompts):
+            want = _alone(kind, p)
+            assert done[rid] == p + want, (rid, done[rid], want)
+            assert seen[rid] == want          # streamed once, in order
+        assert eng.decode_ticks["ahead"] > eng.decode_ticks["settled"] >= 1
+        assert eng.discarded_tokens == {"ended": 0, "withdrawn": 0}
+
+    def test_dense_tokens_are_generates(self):
+        """The same staggered batch against the model's own greedy
+        ``generate``, under group prefill."""
+        model = _tiny_model()
+        prompts = self._prompts(sizes=(5, 9, 3))
+        eng = ContinuousBatchingEngine(model, max_slots=2, page_size=16,
+                                       max_seq_len=64, max_new_tokens=6)
+        rids = [eng.submit(p) for p in prompts[:2]]
+        eng.step()
+        eng.step()
+        rids.append(eng.submit(prompts[2]))
+        done = eng.run_until_complete()
+        for rid, p in zip(rids, prompts):
+            out = model.generate(paddle.to_tensor(
+                np.asarray([p], np.int32)), max_new_tokens=6)
+            assert done[rid] == np.asarray(out.numpy())[0].tolist()
+        assert eng.decode_ticks["ahead"] > 0
+
+    @pytest.mark.parametrize("kind", MODEL_KINDS)
+    def test_max_new_reached_in_flight_emits_exactly_max_new(self, kind):
+        """A row's last tick is known by count when it is launched: the
+        row is left out of the next launch, not cut after the fact."""
+        prompts = self._prompts(seed=37, sizes=(4, 13, 7))
+        eng = _ahead_engine(kind, max_new_tokens=5)
+        seen, rids = _streams(eng, prompts[:1])
+        eng.step()
+        eng.step()
+        more, rids2 = _streams(eng, prompts[1:])   # these end later
+        done = eng.run_until_complete()
+        seen.update(more)
+        for rid, p in zip(rids + rids2, prompts):
+            assert len(seen[rid]) == 5 and done[rid] == p + seen[rid]
+            assert seen[rid] == _alone(kind, p, max_new_tokens=5)
+        assert eng.discarded_tokens == {"ended": 0, "withdrawn": 0}
+        assert eng.pool.available == eng.pool.num_pages
+
+    @pytest.mark.parametrize("kind", MODEL_KINDS)
+    def test_eos_overshoot_is_discarded_and_pages_release_once(self, kind):
+        """Whether a row ended at tick N-1 is not known when N is
+        launched: the row rides in N, N's token for it is discarded,
+        and it retires a step later. With the prefix cache on, a second
+        release of a page would raise (refcount underflow)."""
+        prompts = self._prompts(seed=38, sizes=(6, 10))
+        full = _alone(kind, prompts[0], max_new_tokens=8)
+        eos = full[3]
+        first = full.index(eos)
+        eng = _ahead_engine(kind, max_new_tokens=8, eos_token_id=int(eos),
+                            enable_prefix_cache=True)
+        seen, rids = _streams(eng, prompts)
+        done = eng.run_until_complete()
+        assert seen[rids[0]] == full[:first + 1]       # nothing past eos
+        assert done[rids[0]] == prompts[0] + full[:first + 1]
+        other = _alone(kind, prompts[1], max_new_tokens=8,
+                       eos_token_id=int(eos))
+        assert seen[rids[1]] == other
+        if first > 0:                    # eos came out of a decode tick
+            assert eng.discarded_tokens["ended"] >= 1
+        assert eng.discarded_tokens["withdrawn"] == 0
+        assert all(s is None for s in eng._slots)
+        assert all(v == 0 for v in eng._page_ref.values())
+        cached = len(eng._cached_pages)
+        assert eng.pool.available == eng.pool.num_pages - cached
+
+    @pytest.mark.parametrize("kind", MODEL_KINDS)
+    def test_cancel_with_a_tick_in_flight(self, kind):
+        """The cancelled row's token of the tick in flight is dropped at
+        the fetch (nothing is waited for); its neighbour loses nothing;
+        the same rid, replayed at once as a fleet does, starts clean."""
+        prompts = self._prompts(seed=39, sizes=(7, 5))
+        eng = _ahead_engine(kind, max_new_tokens=8)
+        seen, (keep, drop) = _streams(eng, prompts)
+        _step_until_in_flight(eng, [keep, drop])
+        had = list(seen[drop])
+        assert eng.cancel(drop)
+        assert eng._in_flight is not None             # nothing settled
+        replay, _ = _streams(eng, prompts[1:], rid=drop)
+        done = eng.run_until_complete()
+        want = _alone(kind, prompts[1], max_new_tokens=8)
+        # the token in flight at the cancel was never emitted ...
+        assert seen[drop] == had == want[:len(had)]
+        # ... and the replay owes nothing to the old request's row
+        assert replay[drop] == want
+        assert done[drop] == prompts[1] + want
+        assert seen[keep] == _alone(kind, prompts[0], max_new_tokens=8)
+        assert eng.discarded_tokens == {"ended": 0, "withdrawn": 1}
+        assert eng.pool.available == eng.pool.num_pages
+
+    @pytest.mark.parametrize("kind", MODEL_KINDS)
+    def test_extract_inject_with_a_tick_in_flight(self, kind):
+        """``extract`` settles the tick in flight first: the request
+        leaves with every token the device made for it, and the engine
+        it is injected into goes on from there."""
+        prompts = self._prompts(seed=40, sizes=(6, 9))
+        src = _ahead_engine(kind, max_new_tokens=8)
+        dst = _ahead_engine(kind, max_new_tokens=8)
+        seen, (moved, stays) = _streams(src, prompts)
+        _step_until_in_flight(src, [moved, stays])
+        before = len(seen[moved])
+        slot = next(i for i, r in enumerate(src._slots)
+                    if r is not None and r.rid == moved)
+        req = src.extract(slot)
+        assert src._in_flight is None
+        assert len(seen[moved]) == before + 1          # settled, emitted
+        assert req.length == len(prompts[0]) + len(req.generated) - 1
+        dst.inject(req)
+        done = {**src.run_until_complete(), **dst.run_until_complete()}
+        for rid, p in ((moved, prompts[0]), (stays, prompts[1])):
+            want = _alone(kind, p, max_new_tokens=8)
+            assert seen[rid] == want and done[rid] == p + want
+        assert src.discarded_tokens == {"ended": 0, "withdrawn": 0}
+
+    @pytest.mark.parametrize("policy", ("recompute", "swap"))
+    @pytest.mark.parametrize("kind", MODEL_KINDS)
+    def test_forced_preemption_with_a_tick_in_flight(self, kind, policy):
+        """A pool one page short of what the rows grow into: a victim is
+        chosen while a tick is in flight, which is settled first, so the
+        victim's token of that tick is neither lost nor served twice."""
+        prompts = self._prompts(seed=41, sizes=(7, 7, 7))
+        kw = dict(max_new_tokens=12, page_size=4, max_seq_len=32)
+        want = [_alone(kind, p, **kw) for p in prompts]
+        # each row ends with 7 + 11 tokens cached, on 5 pages: 15 in all
+        eng = _ahead_engine(kind, num_pages=14, preempt_policy=policy, **kw)
+        in_flight, grow = [], eng._grow_pages
+
+        def watched(newly):
+            was, n = eng._in_flight is not None, eng.preemptions
+            grow(newly)
+            if eng.preemptions > n:
+                in_flight.append(was)
+                assert eng._in_flight is None
+
+        eng._grow_pages = watched
+        seen, rids = _streams(eng, prompts)
+        done = eng.run_until_complete()
+        assert eng.preemptions > 0 and any(in_flight)
+        for rid, p, w in zip(rids, prompts, want):
+            assert seen[rid] == w and done[rid] == p + w
+        assert eng.pool.available == eng.pool.num_pages
+
+    @pytest.mark.parametrize("kind", MODEL_KINDS)
+    def test_sampled_engine_equals_a_settled_every_tick_run(self, kind):
+        """A sampled tick is launched ahead like a greedy one: its key
+        is split on the host, in launch order, and depends on no
+        token."""
+        prompts = self._prompts(seed=42, sizes=(5, 8, 4))
+        outs = []
+        for settle in (False, True):
+            eng = _ahead_engine(kind, seed=3, max_new_tokens=7)
+            seen, rids = _streams(eng, prompts[:2], temperature=0.9,
+                                  top_k=20)
+            for tick in range(200):
+                if tick == 2:
+                    more, rid2 = _streams(eng, prompts[2:],
+                                          temperature=0.7, top_p=0.9)
+                eng.step()
+                if settle:
+                    eng._settle()
+                if tick > 2 and not eng._waiting and all(
+                        s is None for s in eng._slots):
+                    break
+            seen.update(more)
+            assert eng.decode_ticks["ahead"] == 0 if settle else \
+                eng.decode_ticks["ahead"] > 0
+            outs.append([seen[r] for r in rids + rid2])
+        assert outs[0] == outs[1]
+        assert all(len(t) == 7 for t in outs[0])
+
+    @pytest.mark.parametrize("kind", MODEL_KINDS)
+    def test_an_idle_engine_holds_no_token_back(self, kind):
+        """The step that finds nothing to launch fetches what is in
+        flight, emits it and retires what it finished, all in that one
+        call."""
+        prompts = self._prompts(seed=43, sizes=(6, 6))
+        eng = _ahead_engine(kind, max_new_tokens=4)
+        seen, rids = _streams(eng, prompts)
+        done = {}
+        while not done:
+            assert sum(map(len, seen.values())) < 8 or eng._in_flight
+            done = eng.step()
+        assert sorted(done) == sorted(rids)            # both, in one call
+        assert [len(seen[r]) for r in rids] == [4, 4]
+        assert eng._in_flight is None
+        assert all(s is None for s in eng._slots)
+        assert eng.step() == {}                        # and stays idle
+
+    def test_counter_reads_ahead_for_every_tick_but_the_first(self):
+        import paddle_tpu.telemetry as telemetry
+        from paddle_tpu.telemetry import trace
+
+        telemetry.enable()
+        trace.enable()
+        trace.reset()
+        try:
+            eng = _ahead_engine("dense", max_new_tokens=7)
+            c0 = dict(telemetry.snapshot()["counters"].get(
+                "serving_decode_ticks_total", {}))
+            for p in self._prompts(seed=44, sizes=(6, 6)):
+                eng.submit(p)
+            eng.run_until_complete()
+            events = trace.events()
+        finally:
+            trace.disable()
+        # 7 tokens a row: one from the prefill pass, six ticks
+        assert eng.decode_ticks == {"settled": 1, "ahead": 5}
+        c1 = telemetry.snapshot()["counters"]["serving_decode_ticks_total"]
+        delta = {k: v - c0.get(k, 0) for k, v in c1.items()}
+        assert sorted(delta.values()) == [1, 5], delta
+        assert {k for k, v in delta.items() if v == 5} == {
+            k for k in delta if "ahead" in k}
+        ticks = [e["attrs"] for e in events
+                 if e.get("ph") == "X" and e["name"] == "decode_tick"]
+        assert [t["ahead"] for t in ticks] == [0, 1, 1, 1, 1, 1]
+        assert all(t["ticks"] == 1 and t["live"] == 2
+                   and t["discarded"] == 0 for t in ticks)
+
+    def test_a_draft_engine_and_a_prefill_only_one_stay_settled(self):
+        """What stays synchronous is read off the engine's own state: a
+        plain tick under a draft model is settled at once (the next
+        speculative window is built from the host's tokens), and a
+        ``prefill_only`` engine never decodes."""
+        model = _tiny_model()
+        prompts = self._prompts(seed=45, sizes=(5, 7))
+        spec = ContinuousBatchingEngine(
+            model, max_slots=2, page_size=8, max_seq_len=64,
+            max_new_tokens=6, prefill_chunk=8, draft_model=model,
+            spec_tokens=2, seed=1)
+        seen, _ = _streams(spec, prompts, temperature=0.8)  # fallback ticks
+        for _ in range(40):
+            spec.step()
+            assert spec._in_flight is None
+        assert all(len(t) == 6 for t in seen.values())
+        assert spec.decode_ticks["ahead"] == 0
+        assert spec.decode_ticks["settled"] > 0
+        half = ContinuousBatchingEngine(
+            model, max_slots=2, page_size=8, max_seq_len=64,
+            max_new_tokens=6, prefill_chunk=8, prefill_only=True)
+        for p in prompts:
+            half.submit(p)
+        for _ in range(6):
+            half.step()
+        assert half._in_flight is None
+        assert half.decode_ticks == {"ahead": 0, "settled": 0}
+
+
 class TestScanDecode:
     """ISSUE 12 satellite: the serving forward compiles through the
     scan-over-layers body (depth-flat replica cold start); the

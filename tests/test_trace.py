@@ -660,11 +660,15 @@ def test_xla_compile_span_only_while_the_tracer_is_on():
     assert len(trace.events()) == n
 
 
+# a step launches its tick and THEN fetches and emits the tick before it
+# (docs/SERVING.md "The step's order"); one with nothing to launch fetches
+# what is in flight and retires once more what that finished
 TICK_PHASES = ["retire", "admission", "prefill_tick", "grow_pages",
-               "decode_build", "decode_upload", "decode_tick", "emit"]
+               "decode_build", "decode_upload", "decode_tick",
+               "decode_fetch", "emit"]
 NESTED = {"prefill_tick": ["prefill_build", "prefill_launch",
                            "first_token_fetch"],
-          "decode_tick": ["decode_launch", "decode_fetch"]}
+          "decode_tick": ["decode_launch"]}
 
 
 def _chunked_engine():
@@ -717,9 +721,15 @@ def test_engine_step_spans_hold_the_named_phases_in_order():
     for step in steps:
         kids = _children(events, step)
         names = [k["name"] for k in kids]
+        if "decode_tick" not in names and "decode_fetch" in names:
+            # the pipeline drained: the last tokens' requests retire now
+            assert names[-1] == "retire", names
+            names = names[:-1]
         # the phases that ran this tick, in the tick's order, none twice
         assert names == [p for p in TICK_PHASES if p in names], names
         assert names[:2] == ["retire", "admission"]
+        # a fetch is of a tick launched in an EARLIER step
+        assert ("decode_fetch" in names) == ("emit" in names)
         full += names == TICK_PHASES
         # together they cover the tick but for a few statements between
         assert sum(k["dur"] for k in kids) <= step["dur"]
@@ -729,7 +739,11 @@ def test_engine_step_spans_hold_the_named_phases_in_order():
             assert inner == [p for p in want if p in inner], (kid, inner)
             if kid["name"] == "decode_tick":
                 assert inner == NESTED["decode_tick"]
-                assert kid["attrs"]["live"] >= 1
+                attrs = kid["attrs"]
+                assert attrs["live"] >= 1 and attrs["ticks"] == 1
+                assert attrs["discarded"] == 0
+                # launched ahead exactly when this step also fetches
+                assert attrs["ahead"] == ("decode_fetch" in names)
     assert full >= 1, "no tick both prefilled and decoded"
     launched = [e for e in events if e["name"] == "prefill_tick"
                 and e["attrs"]]

@@ -187,6 +187,39 @@ class TestLoopbackRpc:
         assert load["queue_depth"] == 0 and load["occupied_slots"] == 0
 
 
+    def test_drain_ships_the_tokens_of_the_tick_in_flight(self):
+        """``extract()`` settles the decode tick the engine launched
+        ahead (docs/SERVING.md "The step's order"); no step follows a
+        drain, so its tokens ride in the drain's own reply, and the
+        peer that is injected the request streams the rest."""
+        prompts = [[1, 5, 9, 2], [3, 3, 7]]
+        local = _engine(_tiny_model(), max_new_tokens=6)
+        rids = [local.submit(list(p)) for p in prompts]
+        want = local.run_until_complete()
+
+        src, server = _remote(_engine(_tiny_model(), max_new_tokens=6))
+        dst, _ = _remote(_engine(_tiny_model(), max_new_tokens=6,
+                                 rid_base=100))
+        toks = {}
+        cb = lambda r, t: toks.setdefault(r, []).append(t)  # noqa: E731
+        rr = [src.submit(list(p), on_token=cb) for p in prompts]
+        for _ in range(3):
+            src.step()
+        assert server.engine._in_flight is not None
+        had = {r: len(toks[r]) for r in rr}
+        data = src.drain_requests()
+        assert server.engine._in_flight is None
+        assert all(len(toks[r]) == had[r] + 1 for r in rr)
+        for req in data["running"]:
+            rid = int(req["rid"])
+            dst.inject_wire(req)
+            dst.adopt_stream(rid, src.release_stream(rid))
+        done = dst.run_until_complete()
+        for rl, r, p in zip(rids, rr, prompts):
+            assert done[r] == want[rl]
+            assert toks[r] == want[rl][len(p):]    # none lost, none twice
+
+
 class TestChaos:
     def test_drop_retries_exactly_once(self):
         eng = _engine(_tiny_model())
