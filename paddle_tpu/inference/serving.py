@@ -121,9 +121,12 @@ _WEIGHT_BYTES = _telemetry.gauge(
     labelnames=("dtype",))
 _CACHE_BYTES = _telemetry.gauge(
     "serving_cache_bytes",
-    "bytes of the paged cache: 'pool' is what its pools take on the "
+    "bytes of the cache: 'pool' is what the paged pools take on the "
     "device, 'algorithm' what the model kind must keep for as many "
-    "tokens (a latent row of 576 values lies in 640 lanes)",
+    "tokens (a latent row of 576 values lies in 640 lanes); for a kind "
+    "that keeps a state by SLOT beside its pages, 'slot_store' is what "
+    "that store takes and 'slot_algorithm' what the algorithm must keep "
+    "for as many slots",
     labelnames=("kind",))
 _PREFILL_PASSES = _telemetry.counter(
     "serving_prefill_passes_total",
@@ -576,6 +579,10 @@ class DenseDecoderServing:
     (codes, scales) pair is the int8 page format."""
 
     cache_names = ("k", "v")
+    #: the cache leaves addressed by SLOT, not by page: none
+    slot_cache_names = ()
+    #: why a request cannot leave this engine for another: it can
+    no_handoff = None
     #: engine features this model kind refuses at construction, by name
     refuses = {}
 
@@ -700,9 +707,10 @@ class DenseDecoderServing:
         o = jnp.einsum("bhs,hbsd->bhd", probs, cv.astype(jnp.float32))
         return o.astype(q.dtype)
 
-    def decode_attend(self, tables, lens):
+    def decode_attend(self, tables, lens, slots=None):
         """A decode tick's attention: this token's KV row written, then
-        read back with the rest."""
+        read back with the rest (``slots`` is for a kind that keeps a
+        state by slot: unused here)."""
         def attend(li, q, k, v, cache):
             kc, vc = cache
             kc = _kv_write_run(kc, li, tables, lens, 1, k)
@@ -730,7 +738,7 @@ class DenseDecoderServing:
 
         return attend
 
-    def chunk_attend(self, hist, pos0, nvalid, chunk, page):
+    def chunk_attend(self, hist, pos0, nvalid, chunk, page, slots=None):
         """A prefill chunk's attention ([B, chunk] positions from
         ``pos0``): chunk rows attend to [cached prefix + own chunk]
         causally, against the gathered history."""
@@ -849,7 +857,8 @@ class ContinuousBatchingEngine:
         asked = {"int8_kv": int8_kv, "int8_weights": int8_weights,
                  "draft_model": draft_model is not None,
                  "group prefill (prefill_chunk=None)":
-                     prefill_chunk is None}
+                     prefill_chunk is None,
+                 "enable_prefix_cache": enable_prefix_cache}
         for name, why in self._arch.refuses.items():
             if asked.get(name):
                 raise ValueError(
@@ -895,9 +904,22 @@ class ContinuousBatchingEngine:
         # each [L, *, num_pages + 1, page, *]. Every pool is addressed
         # by the same page tables, and every consumer (programs, swap,
         # handoff, prefix export) goes through the tuple. int8 pools are
-        # (codes, fp32 per-row scales) pairs.
+        # (codes, fp32 per-row scales) pairs. A kind that keeps a state
+        # of fixed size a request (a recurrent layer's) names the leaves
+        # that hold it in ``slot_cache_names``: they come LAST in the
+        # tuple, each [L, max_slots + 1, ...], addressed by a request's
+        # SLOT; the last slot is the trash slot, which padded rows name
+        # (docs/SERVING.md "Caches by page and by slot").
         dt = self._weights["embed"].dtype
         self.cache_names = tuple(self._arch.cache_names)
+        self._slot_names = tuple(self._arch.slot_cache_names)
+        self._n_paged = len(self.cache_names) - len(self._slot_names)
+        if self.cache_names[self._n_paged:] != self._slot_names:
+            raise ValueError(
+                f"{type(self._arch).__name__}: the leaves addressed by slot "
+                f"{self._slot_names} come last in cache_names "
+                f"{self.cache_names}")
+        self._trash_slot = max_slots
         shapes = self._arch.cache_shapes(num_pages, page_size)
         if self.int8_kv:
             self.cache = tuple(
@@ -915,6 +937,25 @@ class ContinuousBatchingEngine:
                          labels=("pool",))
         _CACHE_BYTES.set(float(token_bytes * (num_pages + 1) * page_size),
                          labels=("algorithm",))
+        # padding of a decode batch: a copy of its first live row for a
+        # kind whose cache is all pages (rewriting a K/V row is
+        # idempotent); a row of its own on the trash slot and the trash
+        # page for a kind that keeps a state (stepping a live row's
+        # state twice is not)
+        self._pad_row = None
+        if self._slot_names:
+            store = tuple(
+                jnp.zeros(shape, dtype) for shape, dtype in
+                self._arch.slot_cache_shapes(max_slots, dt))
+            self.cache += store
+            _CACHE_BYTES.set(float(sum(_kv_nbytes(c) for c in store)),
+                             labels=("slot_store",))
+            _CACHE_BYTES.set(
+                float(self._arch.slot_cache_bytes(dt.itemsize)
+                      * (max_slots + 1)), labels=("slot_algorithm",))
+            self._pad_row = _Request(-1, [0])
+            self._pad_row.generated = [0]
+            self._pad_row.pages = [self._trash_page] * self.pages_per_seq
 
         # prefill_only: this engine is the PREFILL half of a
         # disaggregated pair (fleet.disagg) — step() admits and prefills
@@ -1252,21 +1293,23 @@ class ContinuousBatchingEngine:
 
     def _decode_step(self, weights, tokens, lens, tables, cache,
                      temps, top_ks, top_ps, key, prev, src,
-                     do_sample=False):
+                     do_sample=False, slots=None):
         """ONE batched decode: tokens [B] (last emitted), lens [B] tokens
         already cached, tables [B, pages_per_seq]. A row whose last
         token the host has not read yet takes it from ``prev``, the
         ``next`` of the tick before, at row ``src`` [B] (-1: the host's
-        ``tokens`` holds it). Returns (next [B], the pools). What the
-        model kind counts of a tick (``carry_out``: an expert layer's
-        routed pairs) is appended to ``next``: it comes to the host in
-        the tokens' own fetch."""
+        ``tokens`` holds it). ``slots`` [B] is each row's slot, for a
+        kind that keeps a state by slot (None, and no operand of the
+        program, for every other kind). Returns (next [B], the pools).
+        What the model kind counts of a tick (``carry_out``: an expert
+        layer's routed pairs) is appended to ``next``: it comes to the
+        host in the tokens' own fetch."""
         jnp = self._jnp
         from ..models.gpt import _rms_pure
 
         tokens = jnp.where(src >= 0, prev[src], tokens)
         x = weights["embed"][tokens][:, None]                # [B, 1, H]
-        attend = self._arch.decode_attend(tables, lens)
+        attend = self._arch.decode_attend(tables, lens, slots)
         x, cache = self._run_layers(weights, self._arch.carry_in(x), lens,
                                     cache, attend)
         x, stats = self._arch.carry_out(x)
@@ -1477,10 +1520,12 @@ class ContinuousBatchingEngine:
                 staged = tuple(
                     _kv_map(self._jnp.asarray,
                             self._swap_stage(snap[name], n))
-                    for name in self.cache_names)
+                    for name in self.cache_names[:self._n_paged]) + tuple(
+                    self._jnp.asarray(snap[name])
+                    for name in self._slot_names)
                 self.cache = self._swap_in_jit(
                     self.cache, self._padded_page_vec(req.pages[:n]),
-                    staged)
+                    staged, i if self._slot_names else None)
                 req.prefill_pos = snap["prefill_pos"]
                 req.length = snap["length"]
                 req.swapped = None
@@ -1564,12 +1609,15 @@ class ContinuousBatchingEngine:
                 self._emit(req, tok)
         # chunked mode: KV fills incrementally in step()
 
-    def _prefill_chunk_step(self, weights, ids, pos0, nvalid, hist, cache):
+    def _prefill_chunk_step(self, weights, ids, pos0, nvalid, hist, cache,
+                            slots=None):
         """ONE jitted chunk pass over the B rows it is handed, the
         prefilling slots packed into the first of them: ids [B, c] chunk
         tokens (zero-padded), pos0 [B] absolute start, nvalid [B] real
         tokens this chunk (0 for a row with none: its writes go to the
-        scratch page), hist [B, pages_per_seq] page tables. Returns
+        scratch page), hist [B, pages_per_seq] page tables, slots [B]
+        each row's slot for a kind that keeps a state by slot (the trash
+        slot for a row with no chunk; None for every other kind). Returns
         (final-normed last-valid hidden [max_slots, H], the pools), and
         after the hidden rows the model kind's counts if it keeps any.
         B is a step of the engine's row ladder (``_pass_rows``), chunk
@@ -1582,7 +1630,8 @@ class ContinuousBatchingEngine:
 
         B, c = ids.shape
         x = weights["embed"][ids]                            # [B, c, H]
-        attend = self._arch.chunk_attend(hist, pos0, nvalid, c, self.page)
+        attend = self._arch.chunk_attend(hist, pos0, nvalid, c, self.page,
+                                         slots)
         x, cache = self._run_layers(weights, self._arch.carry_in(x), pos0,
                                     cache, attend)
         x, stats = self._arch.carry_out(x)
@@ -1606,10 +1655,11 @@ class ContinuousBatchingEngine:
         launches annotates it with its width and with how many of the
         positions it computes are real."""
         jnp = self._jnp
-        reqs = [r for r in self._slots
-                if r is not None and r.prefill_pos < len(r.seq_tokens)]
-        if not reqs:
+        at = [i for i, r in enumerate(self._slots)
+              if r is not None and r.prefill_pos < len(r.seq_tokens)]
+        if not at:
             return
+        reqs = [self._slots[i] for i in at]
         B = next(n for n in self._pass_rows if n >= len(reqs))
         c = self.prefill_chunk
         with _trace.span("prefill_build", cat="serve"):
@@ -1622,6 +1672,7 @@ class ContinuousBatchingEngine:
             pos0 = np.zeros(B, np.int32)
             nvalid = np.zeros(B, np.int32)
             hist = np.zeros((B, self.pages_per_seq), np.int32)
+            slots = self._slot_vec(at, B)
             for i, r in enumerate(reqs):
                 pos = r.prefill_pos
                 n = min(c_eff, len(r.seq_tokens) - pos)
@@ -1636,7 +1687,7 @@ class ContinuousBatchingEngine:
         with _trace.span("prefill_launch", cat="serve"):
             last, *stats, self.cache = self._prefill_jit(
                 self._weights, jnp.asarray(ids_np), jnp.asarray(pos0),
-                jnp.asarray(nvalid), jnp.asarray(hist), self.cache)
+                jnp.asarray(nvalid), jnp.asarray(hist), self.cache, slots)
         if stats:
             # the model kind's counts of a pass are read once the pass
             # has ended, at a later fetch: no tick waits for them
@@ -1660,20 +1711,38 @@ class ContinuousBatchingEngine:
                 self._draft.prefill(done_reqs,
                                     [r.seq_tokens for r in done_reqs])
 
-    def _swap_gather(self, cache, pages):
+    def _swap_gather(self, cache, pages, slot=None):
         """Every layer's rows for `pages`, of every pool -> a tuple of
         [L, Hkv, P, page, D] (P = pages_per_seq, trash-padded; int8
-        caches yield a (codes, scales) leaf pair). One jitted dispatch
-        per swap-out, then a single host transfer."""
+        caches yield a (codes, scales) leaf pair), then every layer's
+        entry of ``slot`` of each leaf addressed by slot ([L, ...]). One
+        jitted dispatch per swap-out, then a single host transfer."""
         g = lambda c: c[:, :, pages]
-        return tuple(_kv_map(g, c) for c in cache)
+        n = self._n_paged
+        return (tuple(_kv_map(g, c) for c in cache[:n])
+                + tuple(c[:, slot] for c in cache[n:]))
 
-    def _swap_scatter(self, cache, pages, snap):
+    def _swap_scatter(self, cache, pages, snap, slot=None):
         """Scatter a host snapshot (one entry a pool) back into the
         pools at `pages` (trash-padded rows land in the scratch page —
-        harmless by definition). Donates the pools."""
+        harmless by definition), and the entries of the leaves addressed
+        by slot into ``slot``. Donates the pools."""
         sc = lambda c, s: c.at[:, :, pages].set(s)
-        return tuple(_kv_map2(sc, c, s) for c, s in zip(cache, snap))
+        n = self._n_paged
+        return (tuple(_kv_map2(sc, c, s)
+                      for c, s in zip(cache[:n], snap[:n]))
+                + tuple(c.at[:, slot].set(s)
+                        for c, s in zip(cache[n:], snap[n:])))
+
+    def _slot_vec(self, at, width):
+        """[width] int32: the slots ``at`` of a program's first rows,
+        the trash slot for the rest; None for a kind that keeps nothing
+        by slot (its programs have no such operand)."""
+        if not self._slot_names:
+            return None
+        slots = np.full(width, self._trash_slot, np.int32)
+        slots[:len(at)] = at
+        return slots
 
     def _padded_page_vec(self, pages):
         pad = np.full(self.pages_per_seq, self._trash_page, np.int32)
@@ -1691,14 +1760,20 @@ class ContinuousBatchingEngine:
         decode pages never leave the device; restore re-allocates the
         full reservation from prefill_pos/length bookkeeping)."""
         self._settle()
-        got = self._swap_out_jit(self.cache, self._padded_page_vec(r.pages))
+        got = self._swap_out_jit(
+            self.cache, self._padded_page_vec(r.pages),
+            self._slots.index(r) if self._slot_names else None)
         written = max(r.length, r.prefill_pos)
         n = min((written + self.page - 1) // self.page, len(r.pages))
         cut = lambda c: np.asarray(c[:, :, :n])
         # one entry a pool, under the pool's name ("k" and "v" for the
-        # dense decoder, "latent" for a latent model)
+        # dense decoder, "latent" for a latent model); a leaf addressed
+        # by slot is snapshotted whole (its size does not grow)
+        k = self._n_paged
         r.swapped = {name: _kv_map(cut, g)
-                     for name, g in zip(self.cache_names, got)}
+                     for name, g in zip(self.cache_names[:k], got)}
+        r.swapped.update((name, np.asarray(g))
+                         for name, g in zip(self._slot_names, got[k:]))
         r.swapped.update(n=n, prefill_pos=r.prefill_pos, length=r.length)
         return r.swapped
 
@@ -2036,20 +2111,26 @@ class ContinuousBatchingEngine:
         before = self._in_flight
         ahead = before is not None
         with _trace.span("decode_build", cat="serve"):
-            # fixed-width batch: pad with slot 0's state (results
-            # discarded). A row of the tick in flight takes its token
-            # from that tick's row on the device (``src``); a row that
-            # joined since has it on the host
+            # fixed-width batch, its results past the live rows
+            # discarded. What a padded row may be depends on what a row
+            # writes: a copy of the first live row rewrites that row's
+            # K/V values where they lie (idempotent), but would step a
+            # recurrent state TWICE, so a kind that keeps a state by
+            # slot pads with ``_pad_row``: length 0, every page the trash
+            # page, the trash slot. A row of the tick in flight takes
+            # its token from that tick's row on the device (``src``); a
+            # row that joined since has it on the host
             pad_to = self.max_slots
-            rows = ([r for _, r in live]
-                    + [live[0][1]] * (pad_to - len(live)))
+            pad = live[0][1] if self._pad_row is None else self._pad_row
+            rows = [r for _, r in live] + [pad] * (pad_to - len(live))
+            slots = self._slot_vec([i for i, _ in live], pad_to)
             came = before.row_of if ahead else {}
             src = np.asarray([came.get(id(r), -1) for r in rows], np.int32)
             host = (
                 np.asarray([0 if k >= 0 else r.generated[-1]
                             for k, r in zip(src, rows)], np.int32),
                 np.asarray([r.length for r in rows], np.int32),
-                self._table_rows(rows), src)
+                self._table_rows(rows), src, slots)
             if do_sample:
                 host += (
                     np.asarray([r.temperature for r in rows], np.float32),
@@ -2061,11 +2142,11 @@ class ContinuousBatchingEngine:
             # the same device constants every tick (the host's serial
             # work is what a decode tick of a few ms waits on)
             if do_sample:
-                tokens, lens, tables, src, temps, top_ks, top_ps = (
+                tokens, lens, tables, src, slots, temps, top_ks, top_ps = (
                     jax.device_put(host))
                 self._key, sub = jax.random.split(self._key)
             else:
-                tokens, lens, tables, src = jax.device_put(host)
+                tokens, lens, tables, src, slots = jax.device_put(host)
                 temps, top_ks, top_ps, sub = self._greedy_operands()
         with _trace.span("decode_tick",
                          {"live": len(live), "ahead": int(ahead),
@@ -2077,7 +2158,7 @@ class ContinuousBatchingEngine:
                     self._weights, tokens, lens, tables, self.cache,
                     temps, top_ks, top_ps, sub,
                     before.nxt if ahead else self._no_tick, src,
-                    do_sample)
+                    do_sample, slots)
         # what the next tick needs of this one the host knows without
         # its tokens: every row is one token longer
         for _, r in live:
@@ -2317,6 +2398,7 @@ class ContinuousBatchingEngine:
         Unlike `_preempt(policy="swap")`, this works with ANY preempt
         policy and registers completed prefix pages into this engine's
         prefix cache (the prefill worker keeps the warm prefix)."""
+        self._refuse_handoff("extract")
         r = self._slots[slot_idx]
         if r is None:
             raise ValueError(f"slot {slot_idx} is empty")
@@ -2331,9 +2413,17 @@ class ContinuousBatchingEngine:
         through the standard swap-restore admission path. Both engines
         must share the page geometry (page_size, pages_per_seq) and KV
         mode; the disagg wrapper enforces this."""
+        self._refuse_handoff("inject")
         self._next_rid = max(self._next_rid, req.rid + 1)
         req.queued_t = time.perf_counter()
         self._waiting.append(req)
+
+    def _refuse_handoff(self, what):
+        why = self._arch.no_handoff
+        if why:
+            raise NotImplementedError(
+                f"{type(self._model).__name__} does not {what} a request "
+                f"between engines: {why}")
 
     def export_prefix_pages(self, max_pages=None):
         """Serialize prefix-cache entries — (chain key, one-page KV
@@ -2411,7 +2501,8 @@ class ContinuousBatchingEngine:
                 jnp.full((b, self.pages_per_seq), self._trash_page,
                          jnp.int32),
                 self.cache, *self._greedy_operands(), self._no_tick,
-                jnp.full((b,), -1, jnp.int32), do_sample)
+                jnp.full((b,), -1, jnp.int32), do_sample,
+                self._slot_vec([], b))
 
     def decode_program_text(self):
         """StableHLO text of the greedy decode tick (trace + lower, no
@@ -2474,7 +2565,7 @@ class ContinuousBatchingEngine:
                     self._prefill_jit,
                     self._weights, jnp.zeros((B, c), jnp.int32),
                     jnp.zeros((B,), jnp.int32), jnp.zeros((B,), jnp.int32),
-                    tables[:B], self.cache)
+                    tables[:B], self.cache, self._slot_vec([], B))
                 np.asarray(last)
             # the first-token program holds no pool: compiled and run, not
             # among ``program_bytes``

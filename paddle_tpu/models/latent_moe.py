@@ -243,6 +243,10 @@ class LatentMoEServing:
     and cache geometry" in docs/SERVING.md)."""
 
     cache_names = ("latent",)
+    #: the cache leaves addressed by SLOT, not by page: none
+    slot_cache_names = ()
+    #: why a request cannot leave this engine for another: it can
+    no_handoff = None
     #: the prefill chunk's attention through the Pallas kernel (True), the
     #: gather path (False), or by the device (None: the kernel on a TPU)
     prefill_kernel = None
@@ -414,7 +418,7 @@ class LatentMoEServing:
                         q_lat.dtype)
         return jnp.concatenate([q_lat, q_r, pad], -1)
 
-    def decode_attend(self, tables, lens):
+    def decode_attend(self, tables, lens, slots=None):
         """A decode tick's attention: the token's row written, then every
         head attends the latent rows themselves (absorbed form) through
         the paged kernel; ``W_kvb,V`` after."""
@@ -437,7 +441,7 @@ class LatentMoEServing:
 
         return attend
 
-    def chunk_attend(self, hist, pos0, nvalid, chunk, page):
+    def chunk_attend(self, hist, pos0, nvalid, chunk, page, slots=None):
         """A prefill chunk's attention, absorbed too (at chunk 128 the
         absorbed scores cost less than up-projecting the history, and the
         history stays one 640-wide row a token): the chunk's rows
