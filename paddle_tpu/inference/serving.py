@@ -133,6 +133,11 @@ _PREFILL_PASSES = _telemetry.counter(
     "chunked prefill passes launched, by the row count the pass was "
     "compiled at (a step of the engine's ladder, docs/SERVING.md)",
     labelnames=("rows",))
+_DECODE_ROWS = _telemetry.counter(
+    "serving_decode_rows_total",
+    "plain decode ticks launched, by the row count the tick was compiled "
+    "at (a step of the engine's decode ladder, docs/SERVING.md)",
+    labelnames=("rows",))
 _PROGRAM_TEMP_BYTES = _telemetry.gauge(
     "serving_program_temp_bytes",
     "temporary device bytes of each compiled serving program, from its "
@@ -274,7 +279,7 @@ def _kv_write_run(cache, li, tables, pos0, nvalid, vals):
     page, the scratch page nothing reads; positions past the table are
     dropped. Two slots never touch one page with different rows (pages
     are exclusively owned past a shared prefix; a padded decode row
-    repeats another slot's write exactly). int8 caches quantize each
+    writes the scratch page). int8 caches quantize each
     row (one fp32 scale per head_dim row — the block the page table
     addresses) at the write."""
     import jax.numpy as jnp
@@ -483,6 +488,20 @@ def _pass_row_ladder(max_slots):
     while n < max_slots:
         steps.append(n)
         n *= 2
+    return (*steps, max_slots)
+
+
+def _decode_row_ladder(max_slots):
+    """The row counts a decode tick is compiled at: the powers of eight
+    below ``max_slots``, then ``max_slots`` itself (1, 8, 32 for 32
+    slots; 1, 6 for six). A tick runs at the smallest step that holds
+    its live rows. Sparser than the prefill pass's ladder: a padded
+    decode row costs only its per-row fusions, while each step is one
+    more program to compile and warm."""
+    steps, n = [], 1
+    while n < max_slots:
+        steps.append(n)
+        n *= 8
     return (*steps, max_slots)
 
 
@@ -937,12 +956,13 @@ class ContinuousBatchingEngine:
                          labels=("pool",))
         _CACHE_BYTES.set(float(token_bytes * (num_pages + 1) * page_size),
                          labels=("algorithm",))
-        # padding of a decode batch: a copy of its first live row for a
-        # kind whose cache is all pages (rewriting a K/V row is
-        # idempotent); a row of its own on the trash slot and the trash
-        # page for a kind that keeps a state (stepping a live row's
-        # state twice is not)
-        self._pad_row = None
+        # padding of a decode batch, for every kind: a row of its own,
+        # length 0, every page the trash page, on the trash slot. It
+        # writes and reads the trash page alone, steps no live state,
+        # and costs the attention kernels one page
+        self._pad_row = _Request(-1, [0])
+        self._pad_row.generated = [0]
+        self._pad_row.pages = [self._trash_page] * self.pages_per_seq
         if self._slot_names:
             store = tuple(
                 jnp.zeros(shape, dtype) for shape, dtype in
@@ -953,9 +973,6 @@ class ContinuousBatchingEngine:
             _CACHE_BYTES.set(
                 float(self._arch.slot_cache_bytes(dt.itemsize)
                       * (max_slots + 1)), labels=("slot_algorithm",))
-            self._pad_row = _Request(-1, [0])
-            self._pad_row.generated = [0]
-            self._pad_row.pages = [self._trash_page] * self.pages_per_seq
 
         # prefill_only: this engine is the PREFILL half of a
         # disaggregated pair (fleet.disagg) — step() admits and prefills
@@ -973,6 +990,9 @@ class ContinuousBatchingEngine:
         # reload on a live engine feeds the already-compiled step
         self._decode_jit = jax.jit(self._decode_step, donate_argnums=(4,),
                                    static_argnums=(11,))
+        # a decode tick is as wide as the step of this ladder that holds
+        # its live rows: one compiled program a step
+        self._decode_rows = _decode_row_ladder(max_slots)
         # the decode tick launched and not yet fetched, a ``_Tick``
         # (docs/SERVING.md "The step's order"). The next tick takes its
         # tokens from that tick's ``nxt`` on the device; ``_settle()``
@@ -1070,7 +1090,11 @@ class ContinuousBatchingEngine:
                                     donate_argnums=(5,))
         self._pass_rows = _pass_row_ladder(max_slots)
         self.prefill_chunk_steps = 0  # observability: jitted pass count
-        self._greedy_consts = None
+        # row count -> a greedy program's sampling operands, made here for
+        # every step of the decode ladder: no tick makes an array
+        self._greedy_consts = {}
+        for b in self._decode_rows:
+            self._greedy_operands(b)
         self._first_token_jit = jax.jit(self._first_token_step,
                                         static_argnums=(6,))
         self._stats_pending = deque()  # (prefill_tick span, its counts)
@@ -1300,10 +1324,13 @@ class ContinuousBatchingEngine:
         ``next`` of the tick before, at row ``src`` [B] (-1: the host's
         ``tokens`` holds it). ``slots`` [B] is each row's slot, for a
         kind that keeps a state by slot (None, and no operand of the
-        program, for every other kind). Returns (next [B], the pools).
-        What the model kind counts of a tick (``carry_out``: an expert
-        layer's routed pairs) is appended to ``next``: it comes to the
-        host in the tokens' own fetch."""
+        program, for every other kind). Returns (next, the pools): next
+        is [max_slots] (zeros past B, so that ``prev`` has one shape
+        whatever the width of either tick), and what the model kind
+        counts of a tick (``carry_out``: an expert layer's routed pairs)
+        is appended to it: it comes to the host in the tokens' own
+        fetch. B is a step of the engine's decode ladder
+        (``_decode_rows``)."""
         jnp = self._jnp
         from ..models.gpt import _rms_pure
 
@@ -1316,6 +1343,8 @@ class ContinuousBatchingEngine:
         x = _rms_pure(x, weights["fnorm"])[:, 0]
         nxt = self._choose(self._head_logits(weights, x), temps, top_ks,
                            top_ps, key, do_sample)
+        if nxt.shape[0] < self.max_slots:
+            nxt = jnp.pad(nxt, (0, self.max_slots - nxt.shape[0]))
         if stats is not None:
             nxt = jnp.concatenate([nxt, stats])
         return nxt, cache
@@ -2111,19 +2140,15 @@ class ContinuousBatchingEngine:
         before = self._in_flight
         ahead = before is not None
         with _trace.span("decode_build", cat="serve"):
-            # fixed-width batch, its results past the live rows
-            # discarded. What a padded row may be depends on what a row
-            # writes: a copy of the first live row rewrites that row's
-            # K/V values where they lie (idempotent), but would step a
-            # recurrent state TWICE, so a kind that keeps a state by
-            # slot pads with ``_pad_row``: length 0, every page the trash
-            # page, the trash slot. A row of the tick in flight takes
-            # its token from that tick's row on the device (``src``); a
-            # row that joined since has it on the host
-            pad_to = self.max_slots
-            pad = live[0][1] if self._pad_row is None else self._pad_row
-            rows = [r for _, r in live] + [pad] * (pad_to - len(live))
-            slots = self._slot_vec([i for i, _ in live], pad_to)
+            # as wide as the smallest step of the decode ladder that
+            # holds the live rows, padded with ``_pad_row`` (length 0,
+            # the trash page, the trash slot), its results past the live
+            # rows discarded. A row of the tick in flight takes its
+            # token from that tick's row on the device (``src``); a row
+            # that joined since has it on the host
+            B = next(n for n in self._decode_rows if n >= len(live))
+            rows = [r for _, r in live] + [self._pad_row] * (B - len(live))
+            slots = self._slot_vec([i for i, _ in live], B)
             came = before.row_of if ahead else {}
             src = np.asarray([came.get(id(r), -1) for r in rows], np.int32)
             host = (
@@ -2147,9 +2172,10 @@ class ContinuousBatchingEngine:
                 self._key, sub = jax.random.split(self._key)
             else:
                 tokens, lens, tables, src, slots = jax.device_put(host)
-                temps, top_ks, top_ps, sub = self._greedy_operands()
+                temps, top_ks, top_ps, sub = self._greedy_operands(B)
+        _DECODE_ROWS.inc(labels=(str(B),))
         with _trace.span("decode_tick",
-                         {"live": len(live), "ahead": int(ahead),
+                         {"live": len(live), "rows": B, "ahead": int(ahead),
                           "ticks": 1, "discarded": 0}
                          if _trace.enabled() else None,
                          cat="serve") as tick_span:
@@ -2479,28 +2505,30 @@ class ContinuousBatchingEngine:
         self.prefix_pages_imported += n
         return n
 
-    def _greedy_operands(self):
-        """The sampling operands of a greedy tick (unread by its
-        program): device constants, made once."""
-        if self._greedy_consts is None:
+    def _greedy_operands(self, b=None):
+        """The sampling operands of a greedy program of ``b`` rows
+        (``max_slots`` by default; unread by the program): device
+        constants, made once a row count."""
+        b = b or self.max_slots
+        if b not in self._greedy_consts:
             jax, jnp = self._jax, self._jnp
-            b = self.max_slots
-            self._greedy_consts = (
+            self._greedy_consts[b] = (
                 jnp.zeros((b,), jnp.float32), jnp.zeros((b,), jnp.int32),
                 jnp.ones((b,), jnp.float32),
                 jax.random.PRNGKey(0))  # never touches self._key's stream
-        return self._greedy_consts
+        return self._greedy_consts[b]
 
-    def _dummy_decode_operands(self, do_sample=False):
-        """Full-width decode-tick operands whose cache writes land in the
-        scratch page — what :meth:`warmup` compiles against."""
+    def _dummy_decode_operands(self, do_sample=False, b=None):
+        """Decode-tick operands of ``b`` rows (``max_slots`` by default)
+        whose cache writes land in the scratch page — what
+        :meth:`warmup` compiles against."""
         jnp = self._jnp
-        b = self.max_slots
+        b = b or self.max_slots
         return (self._weights, jnp.zeros((b,), jnp.int32),
                 jnp.zeros((b,), jnp.int32),
                 jnp.full((b, self.pages_per_seq), self._trash_page,
                          jnp.int32),
-                self.cache, *self._greedy_operands(), self._no_tick,
+                self.cache, *self._greedy_operands(b), self._no_tick,
                 jnp.full((b,), -1, jnp.int32), do_sample,
                 self._slot_vec([], b))
 
@@ -2535,9 +2563,10 @@ class ContinuousBatchingEngine:
         ``self.build_seconds`` — the replica cold-start number the
         serving bench records and bench_gate gates (docs/SERVING.md) —
         and each program's temporary and aliased bytes in
-        ``self.program_bytes``. The chunked prefill pass is compiled
-        and run at EVERY step of the row ladder (``prefill`` at
-        ``max_slots`` rows, ``prefill_r<N>`` below it), so that no
+        ``self.program_bytes``. The decode tick and the chunked prefill
+        pass are compiled and run at EVERY step of their row ladders
+        (``decode`` and ``prefill`` at ``max_slots`` rows,
+        ``decode_r<N>`` and ``prefill_r<N>`` below it), so that no
         arrival pattern compiles later. Greedy programs only unless
         ``sample=True`` (the first sampled tick otherwise pays its own
         compile). A ``prefill_only`` engine compiles only its prefill
@@ -2553,10 +2582,12 @@ class ContinuousBatchingEngine:
         modes = () if self.prefill_only else (
             (False, True) if sample else (False,))
         for do_sample in modes:
-            nxt, self.cache = self._warm(
-                "decode_sample" if do_sample else "decode",
-                self._decode_jit, *self._dummy_decode_operands(do_sample))
-            np.asarray(nxt)           # block: compile + first dispatch
+            name = "decode_sample" if do_sample else "decode"
+            for B in self._decode_rows:
+                nxt, self.cache = self._warm(
+                    name if B == b else f"{name}_r{B}", self._decode_jit,
+                    *self._dummy_decode_operands(do_sample, B))
+                np.asarray(nxt)       # block: compile + first dispatch
         if self.prefill_chunk is not None:
             c = self.prefill_chunk
             for B in self._pass_rows:

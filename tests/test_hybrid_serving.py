@@ -117,7 +117,9 @@ class TestAgainstTheReference:
             for b in range(2):
                 np.testing.assert_allclose(got[b], want[b][lens[b]],
                                            atol=TOL, rtol=0)
-            assert [int(v) // 6 for v in out[2:]] == [2, 2, 2, 0]
+            # the tokens come back padded to max_slots, the counts after
+            assert out.shape == (eng.max_slots + 4,)
+            assert [int(v) // 6 for v in out[eng.max_slots:]] == [2, 2, 2, 0]
             lens = lens + 1
 
     def test_served_tokens_are_the_references_argmax(self):
@@ -297,6 +299,7 @@ def test_the_old_padding_would_have_stepped_a_state_twice(monkeypatch):
 
     monkeypatch.setattr(ContinuousBatchingEngine, "_slot_vec", copies)
     eng = _engine(hybrid_model(4), max_slots=4)
+    eng._decode_rows = (4,)        # a lone row's tick padded to four
     rid = eng.submit(prompt)
     assert eng.run_until_complete()[rid] != prompt + want
 
@@ -377,13 +380,16 @@ class TestTheEngine:
         with pytest.raises(AttributeError, match="latent"):
             eng._pool("latent")
 
-    def test_the_dense_kind_pads_as_before_and_has_no_slot_operand(self):
+    def test_the_dense_kind_pads_with_the_trash_row_and_no_slot_operand(
+            self):
         from test_serving import _tiny_model
 
         eng = ContinuousBatchingEngine(_tiny_model(), max_slots=2,
                                        page_size=8, max_seq_len=32,
                                        prefill_chunk=8, max_new_tokens=4)
-        assert eng._pad_row is None and eng._slot_vec([0], 2) is None
+        pad = eng._pad_row
+        assert pad.length == 0 and set(pad.pages) == {eng._trash_page}
+        assert eng._slot_vec([0], 2) is None
         assert len(eng.cache) == 2 and eng._n_paged == 2
 
     def test_counts_reach_the_spans_the_counter_and_the_gauges(self):
@@ -410,9 +416,11 @@ class TestTheEngine:
                               and "state_rows" in (e.get("attrs") or {})]
         decode, prefill = spans("decode_tick"), spans("prefill_tick")
         assert decode and prefill
-        # 3 slots: two live rows and a padded one a tick, then one
+        # 3 slots, a ladder of (1, 3): two live rows and a padded one a
+        # tick, then one alone
         assert {(t["state_rows"], t["state_rows_launched"])
-                for t in decode} == {(2, 3), (1, 3)}
+                for t in decode} == {(2, 3), (1, 1)}
+        assert {(t["live"], t["rows"]) for t in decode} == {(2, 3), (1, 1)}
         assert sum(t["state_rows"] for t in decode) == 2 * 5
         assert sum(t["state_tokens"] for t in prefill) == 13 + 5
         assert [t["state_rows"] for t in prefill] == [2, 1]

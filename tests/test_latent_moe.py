@@ -375,7 +375,7 @@ class TestEngineFeaturesOnTheLatentModel:
         eng = _engine(latent_model(2), max_slots=4)
         eng.warmup()
         pool = eng.cache[0].nbytes
-        assert set(eng.program_bytes) == {"decode", "prefill",
+        assert set(eng.program_bytes) == {"decode", "decode_r1", "prefill",
                                           "prefill_r1", "prefill_r2"}
         for name, nb in eng.program_bytes.items():
             assert nb["alias"] >= pool, (name, nb, pool)

@@ -868,7 +868,7 @@ class TestKvWriteRun:
             want_s[:, :, :-1].tobytes()
 
 
-_POOL_PROGRAMS = ("decode", "prefill", "prefill_r1", "verify",
+_POOL_PROGRAMS = ("decode", "decode_r1", "prefill", "prefill_r1", "verify",
                   "draft_window_c2")
 
 
@@ -1606,10 +1606,11 @@ class TestPrefillPassWidth:
             max_seq_len=64, max_new_tokens=3, prefill_chunk=4)
         eng.warmup()
         assert set(eng.program_bytes) == {
-            "decode", "prefill", "prefill_r1", "prefill_r2", "prefill_r4"}
+            "decode", "decode_r1", "prefill", "prefill_r1", "prefill_r2",
+            "prefill_r4"}
         jits = (eng._prefill_jit, eng._first_token_jit, eng._decode_jit)
         before = [j._cache_size() for j in jits]
-        assert before == [4, 1, 1]
+        assert before == [4, 1, 2]
         telemetry.enable()
         trace.enable()
         trace.reset()
