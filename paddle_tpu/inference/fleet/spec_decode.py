@@ -225,8 +225,7 @@ class DraftRunner:
         b = self.engine.max_slots
         pos0 = jnp.zeros((b,), jnp.int32)
         for C in (2, 1)[:min(self.engine.spec_tokens, 2)]:
-            d, self.kc, self.vc = self.engine._warm(
+            _, self.kc, self.vc = self.engine._warm(
                 f"draft_window_c{C}", self._window_jit, self._weights,
                 jnp.zeros((b, C), jnp.int32), pos0, tables,
                 self.kc, self.vc)
-            np.asarray(d)

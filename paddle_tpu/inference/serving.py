@@ -1125,6 +1125,12 @@ class ContinuousBatchingEngine:
         self._lookahead = (self.spec_tokens + 1 if self._draft is not None
                            else 1)
         self.build_seconds = None     # set by warmup() (cold-start gate)
+        # each program warmup() builds: its trace, lowering, load and
+        # first-run seconds, published into the trace by the first
+        # traced step() after warmup() (docs/TELEMETRY.md)
+        from ..jit import SetupRecord
+
+        self.setup_record = SetupRecord("engine")
         # program name -> {"temp", "alias"} bytes, read off each compiled
         # program's memory_analysis() at warmup(): temp under one layer's
         # slab says no pool-sized buffer is left in the program
@@ -2080,9 +2086,11 @@ class ContinuousBatchingEngine:
         on, the call is one ``engine_step`` span whose children are its
         phases (docs/TELEMETRY.md Tracing)."""
         self._tick += 1
-        with _trace.span("engine_step",
-                         {"tick": self._tick} if _trace.enabled() else None,
-                         cat="serve"):
+        traced = _trace.enabled()
+        if traced:
+            self.setup_record.publish()
+        with _trace.span("engine_step", {"tick": self._tick} if traced
+                         else None, cat="serve"):
             return self._step()
 
     def _step(self):
@@ -2541,13 +2549,24 @@ class ContinuousBatchingEngine:
         return self._decode_jit.lower(
             *self._dummy_decode_operands()).as_text()
 
+    def _build(self, name, jitted, *operands):
+        """Build one program on dummy operands through the jit layer's
+        timed build, its phases into ``setup_record`` under ``name``
+        (no ``hlo_program_bytes``: nothing here reads a program's
+        size). Returns the executable."""
+        from ..jit import timed_lower_compile
+
+        return timed_lower_compile(jitted, name, *operands, hlo_bytes=False,
+                                   record=self.setup_record)
+
     def _warm(self, name, jitted, *operands):
-        """Compile one program on dummy operands, keep what its
-        ``memory_analysis()`` says under ``program_bytes[name]`` (and in
-        the ``serving_program_temp_bytes`` gauge and a ``program_memory``
-        instant of the trace), and run it once. The run finds the
-        executable the analysis compiled: one compile a program."""
-        mem = jitted.lower(*operands).compile().memory_analysis()
+        """Build one program that holds the pool (:meth:`_build`), keep
+        what its ``memory_analysis()`` says under ``program_bytes[name]``
+        (and in the ``serving_program_temp_bytes`` gauge and a
+        ``program_memory`` instant of the trace), and run it once, timed
+        through to its results. The run finds the executable the build
+        compiled: one compile a program."""
+        mem = self._build(name, jitted, *operands).memory_analysis()
         nb = {"temp": int(mem.temp_size_in_bytes),
               "alias": int(mem.alias_size_in_bytes)}
         self.program_bytes[name] = nb
@@ -2555,16 +2574,18 @@ class ContinuousBatchingEngine:
         if _trace.enabled():
             _trace.instant("program_memory", {"program": name, **nb},
                            cat="serve")
-        return jitted(*operands)
+        return self.setup_record.first_run(jitted, *operands)
 
     def warmup(self, sample=False):
         """Compile the engine's programs on dummy operands (cache writes
         land in the scratch page) and record the wall time in
         ``self.build_seconds`` — the replica cold-start number the
         serving bench records and bench_gate gates (docs/SERVING.md) —
-        and each program's temporary and aliased bytes in
-        ``self.program_bytes``. The decode tick and the chunked prefill
-        pass are compiled and run at EVERY step of their row ladders
+        each program's temporary and aliased bytes in
+        ``self.program_bytes``, and its trace, lowering, load and first-run
+        seconds in ``self.setup_record`` (the first-token program's too).
+        The decode tick and the chunked prefill pass are compiled and run
+        at EVERY step of their row ladders
         (``decode`` and ``prefill`` at ``max_slots`` rows,
         ``decode_r<N>`` and ``prefill_r<N>`` below it), so that no
         arrival pattern compiles later. Greedy programs only unless
@@ -2584,10 +2605,9 @@ class ContinuousBatchingEngine:
         for do_sample in modes:
             name = "decode_sample" if do_sample else "decode"
             for B in self._decode_rows:
-                nxt, self.cache = self._warm(
+                _, self.cache = self._warm(
                     name if B == b else f"{name}_r{B}", self._decode_jit,
                     *self._dummy_decode_operands(do_sample, B))
-                np.asarray(nxt)       # block: compile + first dispatch
         if self.prefill_chunk is not None:
             c = self.prefill_chunk
             for B in self._pass_rows:
@@ -2597,18 +2617,18 @@ class ContinuousBatchingEngine:
                     self._weights, jnp.zeros((B, c), jnp.int32),
                     jnp.zeros((B,), jnp.int32), jnp.zeros((B,), jnp.int32),
                     tables[:B], self.cache, self._slot_vec([], B))
-                np.asarray(last)
-            # the first-token program holds no pool: compiled and run, not
-            # among ``program_bytes``
-            np.asarray(self._first_token_jit(
-                self._weights, last, *self._greedy_operands(), False))
+            # the first-token program holds no pool: built and run as
+            # the others are, not among ``program_bytes``
+            ops = (self._weights, last, *self._greedy_operands(), False)
+            self._build("first_token", self._first_token_jit, *ops)
+            self.setup_record.first_run(self._first_token_jit, *ops)
         if self._draft is not None and not self.prefill_only:
-            t_out, self.cache = self._warm(
+            _, self.cache = self._warm(
                 "verify", self._verify_jit,
                 self._weights,
                 jnp.zeros((b, self.spec_tokens + 1), jnp.int32),
                 lens, tables, self.cache)
-            np.asarray(t_out)
             self._draft.warmup(tables)
-        self.build_seconds = time.perf_counter() - t0
+        self.setup_record.warm_end = time.perf_counter()
+        self.build_seconds = self.setup_record.warm_end - t0
         return self.build_seconds

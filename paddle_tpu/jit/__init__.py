@@ -56,10 +56,9 @@ _LAST_COMPILE = {}
 
 def _device_peaks():
     """(peak_flops, peak_bytes_per_sec, placeholder?) for device 0 —
-    the roofline denominators behind the dispatch-span cost attrs and
-    the bench anatomy's cost-analysis MFU, from the one chip table
-    (``paddle_tpu.device.CHIP_PEAKS``). CPU runs get the flagged
-    placeholder row; an unknown TPU kind raises."""
+    the roofline denominators of :func:`compiled_cost_summary`, from the
+    one chip table (``paddle_tpu.device.CHIP_PEAKS``). CPU runs get the
+    flagged placeholder row; an unknown TPU kind raises."""
     from ..device import chip_peaks
 
     peaks, placeholder = chip_peaks()
@@ -67,7 +66,8 @@ def _device_peaks():
 
 
 def compiled_cost_summary(compiled):
-    """``compiled.cost_analysis()`` distilled to the anatomy contract:
+    """``compiled.cost_analysis()`` distilled to the layout autotuner's
+    pricing record (memory/autotune.py):
     {"flops", "bytes_accessed", "device_seconds_est" (roofline:
     max(flops/peak_flops, bytes/peak_bw)), "peak_flops",
     "peak_bytes_per_sec", "peak_model_placeholder"} — or None when the
@@ -115,26 +115,18 @@ def _memory_record(compiled):
     }
 
 
-def _traced_dispatch(ex, label, cost, op_args):
-    """Run one compiled dispatch, recording a ``dispatch`` span with the
-    program's cost-analysis attrs when tracing is on: flops, bytes and
-    the roofline device-seconds estimate. Under async dispatch the
-    span's wall time is enqueue time, so nothing per call is derived
-    from it (``last_dispatch_cost()`` over a step's wall is the MFU
-    figure). Plain call when the tracer is disabled — the hot path pays
-    one attribute check."""
+def _traced_dispatch(ex, label, op_args):
+    """Run one compiled dispatch, recording a ``dispatch`` span (attr
+    ``function``) when tracing is on. Under async dispatch the span's
+    wall time is enqueue time. Plain call when the tracer is disabled —
+    the hot path pays one attribute check."""
     tr = _telemetry.trace
     if not tr.enabled():
         return ex(*op_args)
     t0 = _time.perf_counter()
     out = ex(*op_args)
-    dt = _time.perf_counter() - t0
-    attrs = {"function": label}
-    if cost:
-        attrs.update(
-            flops=cost["flops"], bytes_accessed=cost["bytes_accessed"],
-            device_seconds_est=round(cost["device_seconds_est"], 6))
-    tr.complete("dispatch", t0, dt, attrs, cat="jit")
+    tr.complete("dispatch", t0, _time.perf_counter() - t0,
+                {"function": label}, cat="jit")
     return out
 
 
@@ -149,7 +141,8 @@ def _record_compile_phases(label, trace_s, lower_s, compile_s, hlo_bytes):
     _TRACE_SECONDS.set(trace_s, labels=labels)
     _LOWER_SECONDS.set(lower_s, labels=labels)
     _COMPILE_SECONDS.set(compile_s, labels=labels)
-    _HLO_PROGRAM_BYTES.set(hlo_bytes, labels=labels)
+    if hlo_bytes is not None:
+        _HLO_PROGRAM_BYTES.set(hlo_bytes, labels=labels)
     _LAST_COMPILE[label] = {
         "trace_seconds": trace_s, "lower_seconds": lower_s,
         "compile_seconds": compile_s, "hlo_program_bytes": hlo_bytes}
@@ -165,10 +158,83 @@ def compile_summary(label=None):
     return dict(rec) if rec is not None else None
 
 
-def timed_lower_compile(jitfn, label, *args, **kwargs):
+class SetupRecord:
+    """Where one object's set-up went, kept whether the tracer is on or
+    off (docs/TELEMETRY.md "The set-up record"). ``programs`` maps the
+    name of each program the owner built to ``[trace_s, lower_s,
+    load_s]``, the phases :func:`timed_lower_compile` times (``load_s``
+    is the ``.compile()`` call: a persistent-cache read, or a backend
+    compile on a miss), and :meth:`first_run` appends ``run_s``.
+    ``warm_end`` is the ``time.perf_counter()`` value at which the
+    owner finished warming (None until then).
+
+    The owner calls :meth:`publish` while the tracer is on: the first
+    call after ``warm_end`` records one ``setup`` instant, and no call
+    after it records anything. So a set-up that ran before the tracer
+    was enabled reaches a trace that starts later, as the
+    ``first_token`` mark's ``queue_ms`` does."""
+
+    __slots__ = ("owner", "programs", "warm_end", "published")
+
+    def __init__(self, owner):
+        self.owner = owner
+        self.programs = {}
+        self.warm_end = None
+        self.published = False
+
+    def add(self, name, trace_s, lower_s, load_s):
+        """A new row; a second build under one name is kept as
+        ``name#2``, and so on."""
+        key, n = name, 1
+        while key in self.programs:
+            n += 1
+            key = f"{name}#{n}"
+        self.programs[key] = [trace_s, lower_s, load_s]
+
+    def first_run(self, fn, *args):
+        """Run the newest program's first call, ``fn(*args)``, through
+        to its results, and keep the seconds as that row's ``run_s``
+        (with the tracer on, a ``warm_run`` span, attr ``function``).
+        Returns the results, ready."""
+        t0 = _time.perf_counter()
+        out = jax.block_until_ready(fn(*args))
+        run_s = _time.perf_counter() - t0
+        name = next(reversed(self.programs))
+        self.programs[name].append(run_s)
+        _telemetry.trace.complete("warm_run", t0, run_s,
+                                  {"function": name}, cat="jit")
+        return out
+
+    def publish(self):
+        """Record the ``setup`` instant (cat ``setup``) once the owner has
+        warmed, if it has not been recorded: ``owner``, ``programs`` (the
+        count), the sums ``trace_s``, ``lower_s``, ``load_s`` and, where
+        first runs were timed, ``run_s``; ``after_warm_s`` (now less
+        ``warm_end``) and ``by_program`` (the rows). Call it only with
+        the tracer on: the attrs are built here."""
+        if self.published or self.warm_end is None:
+            return
+        self.published = True
+        rows = self.programs.values()
+        attrs = {"owner": self.owner, "programs": len(rows)}
+        for i, key in enumerate(("trace_s", "lower_s", "load_s", "run_s")):
+            phase = [r[i] for r in rows if len(r) > i]
+            if phase:
+                attrs[key] = sum(phase)
+        attrs["after_warm_s"] = _time.perf_counter() - self.warm_end
+        attrs["by_program"] = {k: list(v) for k, v in self.programs.items()}
+        _telemetry.trace.instant("setup", attrs, cat="setup")
+
+
+def timed_lower_compile(jitfn, label, *args, hlo_bytes=True, record=None,
+                        **kwargs):
     """AOT trace -> lower -> compile of a ``jax.jit`` function, feeding
     the per-phase gauges. Returns the Compiled executable (same program
-    jit dispatch would build — donation and shardings preserved)."""
+    jit dispatch would build — donation and shardings preserved: a
+    ``jitfn(*args)`` call after it runs this executable). With
+    ``record`` (a :class:`SetupRecord`) the three phases join it under
+    ``label``. ``hlo_bytes=False`` leaves out ``hlo_program_bytes``:
+    measuring it converts and serializes the whole module again."""
     t0 = _time.perf_counter()
     traced = jitfn.trace(*args, **kwargs)
     t1 = _time.perf_counter()
@@ -176,8 +242,10 @@ def timed_lower_compile(jitfn, label, *args, **kwargs):
     t2 = _time.perf_counter()
     compiled = lowered.compile()
     t3 = _time.perf_counter()
-    hlo_bytes = _serialized_hlo_bytes(lowered)
-    _record_compile_phases(label, t1 - t0, t2 - t1, t3 - t2, hlo_bytes)
+    hlo = _serialized_hlo_bytes(lowered) if hlo_bytes else None
+    _record_compile_phases(label, t1 - t0, t2 - t1, t3 - t2, hlo)
+    if record is not None:
+        record.add(label, t1 - t0, t2 - t1, t3 - t2)
     tr = _telemetry.trace
     if tr.enabled():
         # the three build phases as spans so a trace shows WHERE a cold
@@ -185,8 +253,9 @@ def timed_lower_compile(jitfn, label, *args, **kwargs):
         attrs = {"function": label}
         tr.complete("jit:trace", t0, t1 - t0, dict(attrs), cat="jit")
         tr.complete("jit:lower", t1, t2 - t1, dict(attrs), cat="jit")
-        tr.complete("jit:compile", t2, t3 - t2,
-                    dict(attrs, hlo_program_bytes=hlo_bytes), cat="jit")
+        if hlo is not None:
+            attrs["hlo_program_bytes"] = hlo
+        tr.complete("jit:compile", t2, t3 - t2, attrs, cat="jit")
     return compiled
 
 
@@ -425,38 +494,34 @@ class StaticFunction:
                                 out = layer(*wa, **wk)
                     return _unwrap_tensors(out), dict(mutated)
 
-                self._compiled[key] = [jax.jit(pure), None, None]
+                self._compiled[key] = [jax.jit(pure), None]
             else:
                 def pure_fn(key_arr, args, kwargs):
                     with framework.no_grad(), framework.rng_key_scope(key_arr):
                         out = fn(*_wrap_arrays(args), **_wrap_arrays(kwargs))
                     return _unwrap_tensors(out)
 
-                self._compiled[key] = [jax.jit(pure_fn), None, None]
+                self._compiled[key] = [jax.jit(pure_fn), None]
         return self._compiled[key]
 
     def _run_slot(self, slot, *args):
-        """Run a compiled-program slot ([jit fn, executable|None, cost]):
-        the first call builds the executable through timed_lower_compile
-        so the compile-phase gauges (trace/lower/compile seconds +
+        """Run a compiled-program slot ([jit fn, executable|None]): the
+        first call builds the executable through timed_lower_compile so
+        the compile-phase gauges (trace/lower/compile seconds +
         hlo_program_bytes, labeled by function) cover to_static programs
-        too, and caches the program's cost_analysis summary for the
-        dispatch trace span. A failed build propagates: graph-break
-        tracer errors reach __call__'s eager fallback, anything else is
-        the caller's error — never a second, untimed compile."""
-        jitfn, ex = slot[0], slot[1]
+        too. A failed build propagates: graph-break tracer errors reach
+        __call__'s eager fallback, anything else is the caller's error —
+        never a second, untimed compile."""
+        jitfn, ex = slot
         label = self._dispatch_label
         if ex is None:
-            ex = timed_lower_compile(jitfn, label, *args)
-            slot[2] = compiled_cost_summary(ex)
-            slot[1] = ex
+            ex = slot[1] = timed_lower_compile(jitfn, label, *args)
         try:
-            return _traced_dispatch(ex, label, slot[2], args)
+            return _traced_dispatch(ex, label, args)
         except (TypeError, ValueError):
             if ex is jitfn:
                 raise
             slot[1] = jitfn
-            slot[2] = None
             return jitfn(*args)
 
     _GRAPH_BREAK_ERRORS = (
@@ -693,8 +758,9 @@ class TrainStep:
         self.optimizer = optimizer
         self._compiled = None
         self._execs = {}  # input-signature -> AOT executable (or jit fn)
-        self._exec_costs = {}  # input-signature -> cost_analysis summary
-        self._last_cost = None  # newest executable's cost summary
+        # each executable's build phases, published into the trace once
+        # (SetupRecord); warm_end: when the first was built
+        self.setup_record = SetupRecord("train_step")
         self._param_names = None
         self._buffer_names = None
         self._opt_state = None
@@ -813,16 +879,14 @@ class TrainStep:
         key = self._exec_sig(op_args)
         ex = self._execs.get(key)
         if ex is None:
-            ex = timed_lower_compile(self._compiled,
-                                     self._compile_label(), *op_args)
-            cost = compiled_cost_summary(ex)
-            self._exec_costs[key] = cost
-            if cost is not None:
-                self._last_cost = cost
+            rec = self.setup_record
+            ex = timed_lower_compile(self._compiled, self._compile_label(),
+                                     *op_args, record=rec)
+            if rec.warm_end is None:
+                rec.warm_end = _time.perf_counter()
             self._execs[key] = ex
         try:
-            return _traced_dispatch(ex, self._compile_label(),
-                                    self._exec_costs.get(key), op_args)
+            return _traced_dispatch(ex, self._compile_label(), op_args)
         except (TypeError, ValueError):
             # AOT argument check rejected the operands BEFORE execution
             # (an aval/layout property the signature key didn't capture):
@@ -831,7 +895,6 @@ class TrainStep:
             if ex is self._compiled:
                 raise
             self._execs[key] = self._compiled
-            self._exec_costs.pop(key, None)
             return self._compiled(*op_args)
 
     def _value_and_grads(self, make_loss_of, params, buffers, key_arr,
@@ -846,19 +909,13 @@ class TrainStep:
         loss_of = make_loss_of(buffers, key_arr, batch)
         return jax.value_and_grad(loss_of, has_aux=True)(params)
 
-    def last_dispatch_cost(self):
-        """cost_analysis summary of the newest compiled step executable
-        (compiled_cost_summary shape), or None before the first build /
-        when the program exposes no cost analysis — the bench anatomy
-        block's device-side estimate."""
-        return self._last_cost
-
     def __call__(self, *batch):
         model_label = (type(self.model).__name__,)
         _TRAIN_STEPS.inc(labels=model_label)
         with _telemetry.timer(_TRAIN_STEP_SECONDS, labels=model_label):
             tr = _telemetry.trace
             if tr.enabled():
+                self.setup_record.publish()
                 with tr.span("train_step",
                              attrs={"model": model_label[0]}, cat="step"):
                     return self._call_impl(*batch)

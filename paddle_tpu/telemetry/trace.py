@@ -5,10 +5,11 @@ timeline: thread-aware spans (``trace.span("bwd")`` context manager,
 ``trace.traced`` decorator), instants, and async request events, ring-
 buffered per thread and exported as Chrome/Perfetto trace-event JSON or
 a compact JSONL. One flag (``PTPU_TRACE=1``, or ``trace.enable()``)
-turns a step from one opaque ``train_step_seconds`` sample into a
-step anatomy: jit trace/lower/compile phases, per-call dispatch with a
-``cost_analysis()`` roofline estimate, the collectives a plan issues,
-checkpoint save/restore phases, and serving request span trees.
+turns a step from one opaque ``train_step_seconds`` sample into its
+phases: jit trace/lower/compile phases and per-call dispatch, the
+collectives a plan issues, checkpoint save/restore phases, serving
+request span trees, and the ``setup`` instant of where a warm set-up
+went.
 
 Design constraints (same discipline as the registry):
 
@@ -32,8 +33,7 @@ Design constraints (same discipline as the registry):
   of docs/TELEMETRY.md: started by ``enable()``, stopped by
   ``disable()``, never alive while the tracer was never enabled.
 
-Span-name / attrs contract and the bench ``"anatomy"`` schema:
-docs/TELEMETRY.md (Tracing section).
+Span-name / attrs contract: docs/TELEMETRY.md (Tracing section).
 """
 from __future__ import annotations
 
@@ -54,7 +54,7 @@ __all__ = [
     "span", "traced", "instant", "complete",
     "async_begin", "async_end", "async_instant",
     "events", "live_spans", "to_perfetto", "dump_jsonl",
-    "step_anatomy", "request_trees", "SpanTracer",
+    "request_trees", "SpanTracer",
 ]
 
 DEFAULT_CAPACITY = int(os.environ.get("PTPU_TRACE_BUFFER", "65536"))
@@ -450,60 +450,6 @@ class SpanTracer:
         return len(evs) + 1
 
     # -- aggregation --------------------------------------------------------
-    def step_anatomy(self, step_span="step"):
-        """Decompose the ``step_span`` spans into their contained
-        phases: the data behind the bench ``"anatomy"`` block.
-
-        Returns ``{"steps", "step_seconds_total", "step_seconds_mean",
-        "phases": {name: {count, seconds, seconds_per_step}},
-        "coverage"}`` where ``phases`` aggregates every span that ran
-        INSIDE a step span (same thread, time-contained) and
-        ``coverage`` is the fraction of step wall time covered by the
-        DIRECT children (depth = step depth + 1) — the "per-phase
-        seconds sum to within X of step time" check. None when no step
-        spans were recorded."""
-        by_thread = {}
-        for e in self.events():
-            if e["ph"] == "X":
-                by_thread.setdefault(e["tid"], []).append(e)
-        steps = []
-        step_tid = None
-        for tid, evs in by_thread.items():
-            mine = [e for e in evs if e["name"] == step_span]
-            if mine:
-                steps = mine
-                step_tid = tid
-                break
-        if not steps:
-            return None
-        total = sum(e["dur"] for e in steps)
-        n = len(steps)
-        windows = [(e["ts"], e["ts"] + e["dur"], e["depth"]) for e in steps]
-        phases = {}
-        direct = 0.0
-        for e in by_thread[step_tid]:
-            if e["name"] == step_span:
-                continue
-            for w0, w1, wd in windows:
-                if e["ts"] >= w0 and e["ts"] + e["dur"] <= w1:
-                    row = phases.setdefault(e["name"],
-                                            {"count": 0, "seconds": 0.0})
-                    row["count"] += 1
-                    row["seconds"] += e["dur"]
-                    if e["depth"] == wd + 1:
-                        direct += e["dur"]
-                    break
-        for row in phases.values():
-            row["seconds"] = round(row["seconds"], 6)
-            row["seconds_per_step"] = round(row["seconds"] / n, 6)
-        return {
-            "steps": n,
-            "step_seconds_total": round(total, 6),
-            "step_seconds_mean": round(total / n, 6),
-            "phases": phases,
-            "coverage": round(direct / total, 4) if total else 0.0,
-        }
-
     def request_trees(self, cat="request"):
         """Reassemble async events into per-id span trees:
         ``{id: {"name", "start", "end", "attrs", "children": [...],
@@ -685,10 +631,6 @@ def to_perfetto(path=None):
 
 def dump_jsonl(path, mode="w"):
     return _TRACER.dump_jsonl(path, mode)
-
-
-def step_anatomy(step_span="step"):
-    return _TRACER.step_anatomy(step_span)
 
 
 def request_trees(cat="request"):

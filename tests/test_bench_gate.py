@@ -262,49 +262,6 @@ def test_compile_gate_skips_missing_block_and_subsecond(tmp_path):
     assert bg.main([tiny_new, "--against", tiny_old]) == 0
 
 
-# ---------------------------------------------------------------------------
-# host-overhead gate (ISSUE 11: docs/TELEMETRY.md Tracing)
-# ---------------------------------------------------------------------------
-def _round_with_anatomy(tmp_path, name, anatomy):
-    rec = {"metric": "m", "value": 100.0, "unit": "tokens/sec/chip",
-           "anatomy": anatomy}
-    p = tmp_path / name
-    p.write_text(json.dumps({"tail": json.dumps(rec)}))
-    return str(p)
-
-
-def test_host_gate_fails_over_threshold(tmp_path, capsys):
-    """A traced round whose host gap eats >25% of step time is
-    dispatch-bound — it must not land silently."""
-    bad = _round_with_anatomy(tmp_path, "bad.json", {
-        "enabled": True,
-        "device": {"host_gap_fraction": 0.4,
-                   "host_gap_seconds_per_step": 0.12}})
-    assert bg.main([bad, "--against", bad]) == 1
-    assert "HOST" in capsys.readouterr().out
-    # a looser threshold lets the same record pass
-    assert bg.main([bad, "--against", bad,
-                    "--host-threshold", "0.5"]) == 0
-
-
-def test_host_gate_passes_under_threshold(tmp_path):
-    ok = _round_with_anatomy(tmp_path, "ok.json", {
-        "enabled": True, "device": {"host_gap_fraction": 0.1}})
-    assert bg.main([ok, "--against", ok]) == 0
-
-
-def test_host_gate_skips_untraced_and_placeholder_rounds(tmp_path):
-    # no --trace: {"enabled": false}; CPU dev runs: fraction null
-    # (placeholder roofline peaks) — neither is gated
-    off = _round_with_anatomy(tmp_path, "off.json", {"enabled": False})
-    assert bg.main([off, "--against", off]) == 0
-    cpu = _round_with_anatomy(tmp_path, "cpu.json", {
-        "enabled": True, "device": {"host_gap_fraction": None}})
-    assert bg.main([cpu, "--against", cpu]) == 0
-    plain = _round(tmp_path, "plain.json", {"m": 100.0})
-    assert bg.main([plain, "--against", plain]) == 0
-
-
 def test_default_refs_bridge_a_gap_round(tmp_path, capsys, monkeypatch):
     """Metric continuity: when the previous round lacks a tracked
     metric (a CPU-only gap round like BENCH_r06), the default gate

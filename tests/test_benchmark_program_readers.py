@@ -1,8 +1,10 @@
-"""The readers of benchmark/harness/program.py (ISSUE 27) on synthetic
-spans, marks and beats: what the per-layer metrics engine.host_ms.*,
+"""The readers of benchmark/harness/program.py and
+benchmark/harness/startup.py on synthetic spans, marks, beats and
+instants: what the per-layer metrics engine.host_ms.*,
 engine.queue_wait_ms.chat, engine.prefill_ms.chat,
-engine.prefill_fill_share.chat and host.beat_late_max_ms.train compute,
-events on the window's edge included."""
+engine.prefill_fill_share.chat, host.beat_late_max_ms.train and the
+setup.* metrics of the program's set-up record compute, events on the
+window's edge included."""
 import importlib
 import json
 import os
@@ -13,12 +15,14 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "benchmark"))
 
-from harness import program  # noqa: E402
+from harness import program, startup  # noqa: E402
 from harness.trace import Spans  # noqa: E402
 
 NEW = ("engine.host_ms.decode", "engine.host_ms.chat",
        "engine.queue_wait_ms.chat", "engine.prefill_ms.chat",
        "engine.prefill_fill_share.chat", "host.beat_late_max_ms.train")
+SETUP = ("setup.trace_s", "setup.lower_s", "setup.load_s",
+         "setup.first_run_s", "setup.after_warm_s")
 EPOCH = 1000.0   # the tracer's epoch on perf_counter; the window is 10 s
 T0, T1 = 1010.0, 1020.0
 
@@ -31,6 +35,10 @@ def X(name, t, dur, **attrs):
 def mark(name, t, **attrs):
     return {"ph": "n", "name": name, "ts": t - EPOCH, "id": 1,
             "attrs": attrs or None}
+
+
+def instant(name, t, **attrs):
+    return {"ph": "i", "name": name, "ts": t - EPOCH, "attrs": attrs or None}
 
 
 def ctx_of(events=(), beats=(), items=()):
@@ -173,14 +181,68 @@ def test_readers_read_the_live_tracer():
         trace.reset()
 
 
-@pytest.mark.parametrize("name", NEW)
+def test_setup_sum_adds_the_windows_setup_instants_only():
+    engine = dict(owner="engine", programs=10, trace_s=3.0, lower_s=2.0,
+                  load_s=4.0, run_s=1.5, after_warm_s=6.0)
+    train = dict(owner="train_step", programs=1, trace_s=0.5, lower_s=0.25,
+                 load_s=1.0, after_warm_s=4.0)
+    events = [
+        instant("setup", T0 - 0.001, **dict(engine, trace_s=9e9)),  # before
+        instant("setup", T0, **engine),                        # edge: in
+        instant("setup", 1015.0, **train),
+        mark("setup", 1016.0, trace_s=9e9),            # not an instant
+        instant("program_memory", 1016.0, trace_s=9e9),
+        instant("setup", T1, **dict(engine, trace_s=9e9)),     # edge: out
+    ]
+    ctx = ctx_of(events)
+    assert startup.setup_sum(ctx, "trace_s") == 3.5
+    assert startup.setup_sum(ctx, "load_s") == 5.0
+    assert startup.setup_sum(ctx, "after_warm_s") == 10.0
+    # the train step keeps no run_s: the engine's alone
+    assert startup.setup_sum(ctx, "run_s") == 1.5
+    assert startup.setup_sum(ctx_of(events[2:3]), "run_s") is None
+    assert startup.setup_sum(ctx_of(), "trace_s") is None
+
+
+def test_setup_sum_reads_what_an_engine_publishes():
+    """The instant a SetupRecord publishes in the window, read back as
+    run.py reads it: from the live tracer."""
+    import time
+
+    from paddle_tpu.jit import SetupRecord
+    from paddle_tpu.telemetry import trace
+
+    rec = SetupRecord("engine")
+    rec.add("decode", 0.5, 0.25, 1.0)
+    rec.programs["decode"].append(0.125)
+    rec.add("prefill", 0.25, 0.125, 0.5)
+    rec.programs["prefill"].append(0.0625)
+    rec.warm_end = time.perf_counter()
+    trace.enable()
+    trace.reset()
+    try:
+        t0 = time.perf_counter()
+        rec.publish()
+        ctx = {"t0": t0, "t1": time.perf_counter() + 1e-6, "spans": Spans()}
+        assert startup.setup_sum(ctx, "trace_s") == 0.75
+        assert startup.setup_sum(ctx, "lower_s") == 0.375
+        assert startup.setup_sum(ctx, "load_s") == 1.5
+        assert startup.setup_sum(ctx, "run_s") == 0.1875
+        assert 0.0 <= startup.setup_sum(ctx, "after_warm_s") < 60.0
+    finally:
+        trace.disable()
+        trace.reset()
+
+
+@pytest.mark.parametrize("name", NEW + SETUP)
 def test_each_new_metric_names_a_reader_and_its_cell(name):
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
     (entry,) = [m for m in bench["per_layer"] if m["name"] == name]
     e2e = {m["name"]: m for m in bench["end_to_end"]}
-    for cell in entry["workloads"]:
-        assert cell in e2e[entry["moves"]]["workloads"]
+    cells = {w["name"] for w in bench["workloads"]}
+    for cell in entry.get("workloads", ()):
+        assert cell in e2e[entry["moves"]].get("workloads", cells)
     with open(os.path.join(REPO, "benchmark", "layer_metrics",
                            f"{name}.json")) as f:
         spec = json.load(f)
@@ -189,6 +251,7 @@ def test_each_new_metric_names_a_reader_and_its_cell(name):
     # its arguments fit the reader, and an empty window reads as nothing
     assert reader(ctx_of(), **spec["args"]) is None
     # appended together, in this order (later PRs append after them)
+    group = NEW if name in NEW else SETUP
     names = [m["name"] for m in bench["per_layer"]]
-    at = names.index(NEW[0])
-    assert names[at:at + len(NEW)] == list(NEW)
+    at = names.index(group[0])
+    assert names[at:at + len(group)] == list(group)

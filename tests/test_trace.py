@@ -1,6 +1,6 @@
 """Span tracer: nesting, thread safety, disabled-path cost, Perfetto
-export, step anatomy, serving request trees, watchdog debris, the
-trace_report tool, and the bench_gate host-overhead gate (ISSUE 11)."""
+export, serving request trees, watchdog debris and the trace_report
+tool."""
 import json
 import threading
 import time
@@ -280,38 +280,7 @@ def test_span_seconds_mirror_into_registry_and_report():
 
 
 # ---------------------------------------------------------------------------
-# step anatomy
-# ---------------------------------------------------------------------------
-def test_step_anatomy_schema_and_coverage():
-    trace.enable()
-    for i in range(3):
-        with trace.span("step", attrs={"step": i}, cat="step"):
-            with trace.span("train_step", cat="step"):
-                with trace.span("dispatch", cat="jit"):
-                    time.sleep(0.002)
-            time.sleep(0.0005)
-    anat = trace.step_anatomy()
-    assert anat["steps"] == 3
-    assert set(anat["phases"]) == {"train_step", "dispatch"}
-    assert anat["phases"]["train_step"]["count"] == 3
-    tsps = anat["phases"]["train_step"]["seconds_per_step"]
-    assert tsps == pytest.approx(
-        anat["phases"]["train_step"]["seconds"] / 3, rel=1e-3)
-    # the acceptance bound: direct-child coverage of step wall time —
-    # train_step covers all but the trailing sleep
-    assert 0.5 < anat["coverage"] <= 1.0
-    assert anat["step_seconds_mean"] >= tsps
-
-
-def test_step_anatomy_none_without_steps():
-    trace.enable()
-    with trace.span("not_a_step"):
-        pass
-    assert trace.step_anatomy() is None
-
-
-# ---------------------------------------------------------------------------
-# jit integration: build-phase + dispatch spans with cost attrs
+# jit integration: build-phase + dispatch spans
 # ---------------------------------------------------------------------------
 def _tiny_step(seed=7):
     from paddle_tpu import nn
@@ -341,16 +310,8 @@ def test_train_step_trace_has_build_phases_and_dispatch_cost():
                    "train_step", "dispatch"):
         assert expect in names, (expect, names)
     disp = [e for e in trace.events() if e["name"] == "dispatch"][-1]
-    assert disp["attrs"]["function"].startswith("TrainStep[")
-    # cost-analysis attrs ride the span when the executable exposes them
-    cost = step.last_dispatch_cost()
-    if cost is not None:
-        assert disp["attrs"]["flops"] == cost["flops"]
-        assert disp["attrs"]["device_seconds_est"] >= 0
-        assert cost["device_seconds_est"] >= 0
-    # anatomy decomposes the wrapping step span
-    anat = trace.step_anatomy()
-    assert "train_step" in anat["phases"]
+    assert disp["attrs"] == {
+        "function": step._compile_label()}
 
 
 # ---------------------------------------------------------------------------
@@ -515,10 +476,6 @@ def test_sharded_step_emits_collective_instants_per_step():
             if e["name"] == "collective:grad_reduce"]
     assert len(evs2) == plan.calls
     assert "train_step" in {e["name"] for e in trace.events()}
-
-
-# (the bench_gate host-overhead gate is covered in
-# tests/test_bench_gate.py next to the other gate tests)
 
 
 # ---------------------------------------------------------------------------

@@ -326,31 +326,6 @@ def quant_violations(rec):
     return out
 
 
-def host_overhead_violations(rec, threshold=0.25):
-    """Violation strings from one bench record's "anatomy" block: a
-    traced run whose host gap (measured step wall − cost-analysis
-    device estimate) exceeds ``threshold`` as a fraction of step time
-    is dispatch-bound, not device-bound — the step got slower for a
-    reason no kernel profile will show (docs/TELEMETRY.md Tracing).
-    Reference-free, like the comms parity gate. Runs without --trace
-    ({"enabled": false}) and runs whose roofline peaks are placeholders
-    (host_gap_fraction null, e.g. CPU dev) are not gated."""
-    anat = rec.get("anatomy") if isinstance(rec, dict) else None
-    if not isinstance(anat, dict) or not anat.get("enabled"):
-        return []
-    frac = (anat.get("device") or {}).get("host_gap_fraction")
-    if frac is None:
-        return []
-    out = []
-    if float(frac) > float(threshold):
-        gap = (anat.get("device") or {}).get("host_gap_seconds_per_step")
-        out.append(
-            f"host gap {float(frac):.1%} of step time > threshold "
-            f"{float(threshold):.0%}"
-            + (f" ({gap}s/step)" if gap is not None else ""))
-    return out
-
-
 def serving_violations(rec):
     """Reference-free violation strings from one record's "serving"
     block (docs/SERVING.md; emitted by tools/serve_bench.py and
@@ -718,10 +693,6 @@ def main(argv=None):
     ap.add_argument("--compile-threshold", type=float, default=0.25,
                     help="allowed fractional compile-time increase at "
                     "the same depth/scan mode (default 0.25; docs/SCAN.md)")
-    ap.add_argument("--host-threshold", type=float, default=0.25,
-                    help="allowed host-gap fraction of step time for "
-                    "traced runs carrying an 'anatomy' block (default "
-                    "0.25; docs/TELEMETRY.md Tracing)")
     ap.add_argument("--root", default=os.path.dirname(
         os.path.dirname(os.path.abspath(__file__))),
         help="repo root for BENCH_r*.json discovery")
@@ -791,11 +762,6 @@ def main(argv=None):
         # loss-drift A/B + no silent request-without-engagement
         for v in quant_violations(rec):
             print(f"  QUANT {metric}: {v}", flush=True)
-            failed = True
-        # host-overhead gate (reference-free): a traced round must stay
-        # device-bound at the same metric
-        for v in host_overhead_violations(rec, args.host_threshold):
-            print(f"  HOST  {metric}: {v}", flush=True)
             failed = True
         # serving gate (reference-free): p99-TTFT bound + goodput
         # scaling target + no lost requests (docs/SERVING.md)
